@@ -60,9 +60,6 @@ def main() -> None:
     # program — for measuring the eval tail the fold harvests (the full
     # fedavg/admm schedules issue 180/300 standalone eval launches)
     ap.add_argument("--no-fold-eval", action="store_true")
-    # JAX persistent compilation cache: warm reruns of the same schedule
-    # skip XLA backend compilation (config.compile_cache)
-    ap.add_argument("--compile-cache", metavar="DIR", default=None)
     # multi-alpha line-search fan width (config.linesearch_probes,
     # docs/PERF.md): 1 = the sequential bitwise-identical search; 4 = the
     # widened probe fan (same accepted alpha per step up to ulp ties,
@@ -94,16 +91,17 @@ def main() -> None:
 
     from federated_pytorch_test_tpu.data import synthetic_cifar
     from federated_pytorch_test_tpu.engine import Trainer, get_preset
+    from federated_pytorch_test_tpu.utils import enable_compile_cache
 
     assert jax.default_backend() == "tpu", jax.default_backend()
+    # warm reruns of the same schedule skip XLA backend compilation
+    enable_compile_cache()
 
     over = {"nloop": args.nloop} if args.nloop is not None else {}
     if args.no_fuse_rounds:
         over["fuse_rounds"] = False
     if args.no_fold_eval:
         over["fold_eval"] = False
-    if args.compile_cache:
-        over["compile_cache"] = args.compile_cache
     if args.linesearch_probes is not None:
         over["linesearch_probes"] = args.linesearch_probes
     if args.exchange_dtype is not None:
@@ -215,7 +213,6 @@ def main() -> None:
             r["value"].get("eval", 0)
             for r in rec.series.get("dispatch_count", [])
         ),
-        "compile_cache": args.compile_cache,
         # the roofline knobs this schedule ran under (docs/PERF.md)
         "linesearch_probes": cfg.linesearch_probes,
         "client_fold": cfg.client_fold,

@@ -33,11 +33,6 @@ import numpy as np
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
-if os.environ.get("JAX_PLATFORMS", "").startswith("cpu"):
-    from federated_pytorch_test_tpu.utils import force_host_cpu
-
-    force_host_cpu()
-
 import jax
 import jax.numpy as jnp
 import optax

@@ -17,8 +17,11 @@ sweeps) is one jitted dispatch (engine/steps.py build_round_fn);
 bit-identical trajectories, more dispatch latency). Evals outside a
 fused program are enqueued asynchronously and harvested at round
 boundaries (`--no-async-eval` restores the blocking per-eval fetch).
-`--compile-cache DIR` persists XLA executables so warm reruns skip
-backend compilation.
+Compiled programs persist in `$JAX_COMPILATION_CACHE_DIR` when the
+environment sets it, else in `<checkout>/.cache/xla`
+(utils/hostcpu.py), so warm reruns skip backend compilation; the run
+opens with a `# device:` line naming the backend it executes on, the
+client mesh it spans and how many devices that leaves idle.
 
 Roofline levers (docs/PERF.md): `--linesearch-probes P` batches the
 L-BFGS Armijo search's sequential halving ladder into widened P-rung
@@ -438,6 +441,25 @@ def main(argv=None) -> int:
     }
     cfg = get_preset(args.preset, **overrides)
     print(f"# running preset={args.preset} cfg={cfg}")
+    import jax
+
+    from federated_pytorch_test_tpu.parallel import (
+        largest_feasible_mesh,
+        mesh_size,
+    )
+    from federated_pytorch_test_tpu.utils import enable_compile_cache
+
+    cache = enable_compile_cache()
+    dev = jax.devices()[0]
+    # the clients axis must divide n_clients, so a device count that
+    # does not (3 clients on 4 chips) leaves devices idle — say so
+    mesh = mesh_size(largest_feasible_mesh(cfg.n_clients, cfg.max_devices))
+    print(
+        f"# device: platform={dev.platform} kind={dev.device_kind} "
+        f"count={jax.device_count()} client_mesh={mesh} "
+        f"idle={jax.device_count() - mesh} jax={jax.__version__} "
+        f"compile_cache={cache}"
+    )
     recorder = run_experiment(cfg, verbose=not args.quiet)
     if args.metrics_out:
         recorder.save(args.metrics_out)

@@ -39,9 +39,9 @@ On top of these per-dispatch builders, `build_round_fn` fuses a whole
 partition round — `nadmm x (nepoch epochs + consensus)` — into ONE jitted
 donated-carry program by scanning the same epoch body and consensus
 collective over the round's precomputed shuffle schedule and fault masks.
-One dispatch per round instead of `nadmm*(nepoch+1)` harvests the flat
-~0.1 s dispatch floor that dominates the dispatch-latency-bound schedules
-(benchmarks/epoch_attribution.json); the per-dispatch builders remain the
+One dispatch per round instead of `nadmm*(nepoch+1)` removes that many
+per-program dispatch floors from the round (their share of the wall on
+the chip: not measured); the per-dispatch builders remain the
 `--no-fuse-rounds` escape hatch and serve the cases fusion cannot
 (streaming, per-batch eval, per-epoch eval cadence, over-cap scans).
 With `fold_eval=True` (the default when `check_results` is on) the
@@ -88,6 +88,7 @@ from federated_pytorch_test_tpu.consensus import (
 from federated_pytorch_test_tpu.data import normalize
 from federated_pytorch_test_tpu.exchange import make_codec
 from federated_pytorch_test_tpu.models.base import active_leaf_mask, fold_params
+from federated_pytorch_test_tpu.ops import _interpret
 from federated_pytorch_test_tpu.parallel.diagnostics import group_distances
 from federated_pytorch_test_tpu.optim import (
     LBFGSConfig,
@@ -122,7 +123,7 @@ def _check_vma(ctx: Optional["GroupContext"] = None) -> bool:
     this check — and build_eval_fn's hard-coded True — to cover it.
     """
     uses_pallas = ctx is not None and ctx.lbfgs.direction == "pallas"
-    return not (uses_pallas and jax.default_backend() != "tpu")
+    return not (uses_pallas and _interpret())
 
 
 class GroupContext(NamedTuple):
@@ -978,9 +979,8 @@ def build_round_fn(
     """One partition group's FULL averaging round as ONE jitted program.
 
     The unfused round is `nadmm * (nepoch + 1)` separately dispatched XLA
-    programs (epochs + consensus), and on dispatch-latency-bound runtimes
-    each dispatch pays a flat ~0.1 s floor (benchmarks/
-    epoch_attribution.json) — the wall for the batch-32 flagship. Here the
+    programs (epochs + consensus), each paying its own dispatch floor
+    (size on the chip: not measured). Here the
     whole round is one `lax.scan` over the `nadmm` consensus iterations,
     each scan step running the epoch minibatch scan (`nepoch * S` steps of
     the SAME body `build_epoch_fn` scans) followed by the consensus body

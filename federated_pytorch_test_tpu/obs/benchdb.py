@@ -1,7 +1,9 @@
 """The bench trend database behind the `trend` CLI verb.
 
-The BENCH_r0N trajectory has been unqueryable prose: five wrapper files
-at the repo root, one torn payload (round 3's ~3KB headline truncated
+The BENCH_r0N trajectory was unqueryable prose: five wrapper files at
+the repo root (four removed in PR 21 with the runtime they were taken
+on; the torn r03 stays as the ingestion test case), one torn payload
+(round 3's ~3KB headline truncated
 mid-JSON and recorded as `parsed: null`), and no machine anywhere that
 notices a regression — or a CPU number masquerading as a TPU result —
 before it lands. This module is obs/ part 4's data layer:

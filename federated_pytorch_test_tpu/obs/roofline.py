@@ -47,11 +47,22 @@ CHIP_PEAKS = {
 
 
 def chip_peaks(device_kind: str):
-    """`(peak_tflops_bf16, peak_hbm_gbps)` for a device kind, or
-    `(None, None)` when unknown (CPU hosts, new chips)."""
+    """`(peak_tflops_bf16, peak_hbm_gbps)` for a device kind.
+
+    A TPU kind the table does not list raises: a roofline share against
+    a guessed or absent peak is worse than none, and a new chip is one
+    table row. Non-TPU kinds (the CPU host the tests run on) return
+    `(None, None)` — such runs record no roofline fractions at all.
+    """
     for prefix, peaks in CHIP_PEAKS.items():
         if device_kind.startswith(prefix):
             return peaks
+    if device_kind.upper().startswith("TPU"):
+        raise ValueError(
+            f"no peak FLOP/s and HBM bandwidth on record for device kind "
+            f"{device_kind!r}; add its spec-sheet row to "
+            f"obs/roofline.py CHIP_PEAKS (have {sorted(CHIP_PEAKS)})"
+        )
     return None, None
 
 
@@ -170,7 +181,7 @@ def roofline_record(
     `flops`/`hbm_bytes` come from XLA's `cost_analysis()` of the
     measured program (preferred) or `lbfgs_round_cost` (analytic);
     `wall_s` is the measured wall the work actually took. Peaks default
-    to `chip_peaks(device_kind)`; on unknown chips the achieved rates
+    to `chip_peaks(device_kind)`; on a non-TPU host the achieved rates
     are still reported, only the fractions are omitted. `provenance`
     (an obs/provenance.py stamp) is attached verbatim when given —
     passed explicitly by callers that already hold one, never probed

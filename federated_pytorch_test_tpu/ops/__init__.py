@@ -7,8 +7,20 @@ over the `[m, N]` buffers but a fused pair of kernels does in 2
 (see `ops/compact_pallas.py`).
 
 All kernels run in interpret mode off-TPU (CPU tests / the virtual
-8-device mesh) and compiled on real TPU chips.
+8-device mesh) and compiled on real TPU chips; `_interpret` is the one
+place that choice is made (chip_smoke.py asserts it False on the chip
+before it trusts a kernel result).
 """
+
+import jax
+
+
+def _interpret() -> bool:
+    """Whether `pallas_call`s run in Pallas interpret mode: everywhere
+    but on a TPU backend. Defined before the kernel imports below, which
+    read it from this package."""
+    return jax.default_backend() != "tpu"
+
 
 from federated_pytorch_test_tpu.ops.compact_pallas import (
     compact_direction_pallas,

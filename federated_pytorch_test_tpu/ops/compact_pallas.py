@@ -39,21 +39,27 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from federated_pytorch_test_tpu.ops import _interpret
 from federated_pytorch_test_tpu.optim.compact import compact_solves
 
-# Tile width along N. Swept on a real chip at ResNet18 scale
-# (N ≈ 11.2M, m = 10): 1024 is badly grid-overhead-bound (~10x slower),
-# >=16384 matches XLA's schedule. Under `vmap` (the engine maps the
-# direction over each device's local client block) the batch axis lands in
-# the BLOCK, not the grid, so VMEM holds K_local tiles at once: at 16384,
-# 2 arrays x [K, 10, T] f32 double-buffered is ~5.2 MB x K/2 — safe for
-# the realistic on-chip K_local (1 on pods, 3 for the single-chip bench).
+# Tile width along N: wide enough that grid-step overhead does not
+# dominate a [m, T] tile (a pre-round sweep put the knee well above 1024;
+# not re-measured), while the two [m, T] history tiles (m pads to 16
+# sublanes) stay at 1 MiB each, 4 MiB double-buffered. `vmap` (the
+# engine maps the direction over each device's local client block)
+# prepends the batch axis to the GRID with a squeezed block dimension, so
+# VMEM per step does not grow with K_local.
 # The tail tile is masked inside the kernels, so any N works.
 _TILE_N = 16384
 
 
-def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
+
+def _vma(*operands) -> frozenset:
+    """The mesh axes a kernel's outputs vary over: the union of its
+    operands'. `shard_map(check_vma=True)` (the engine maps the direction
+    over the client axis) requires it declared on every `out_shape`;
+    outside a `shard_map` it is empty."""
+    return frozenset().union(*(jax.typeof(x).vma for x in operands))
 
 
 def _masks(i, n: int, m: int, count):
@@ -112,6 +118,8 @@ def fused_gram_projections(s, y, g, count=None):
     grid = (pl.cdiv(n, _TILE_N),)
     mm = pl.BlockSpec((m, m), lambda i: (0, 0))
     m1 = pl.BlockSpec((m, 1), lambda i: (0, 0))
+    count = jnp.asarray(count, jnp.int32).reshape(1, 1)
+    vma = _vma(count, s, y, g)
     sy, yy, p, q = pl.pallas_call(
         functools.partial(_gram_kernel, n=n),
         grid=grid,
@@ -123,13 +131,13 @@ def fused_gram_projections(s, y, g, count=None):
         ],
         out_specs=[mm, mm, m1, m1],
         out_shape=[
-            jax.ShapeDtypeStruct((m, m), jnp.float32),
-            jax.ShapeDtypeStruct((m, m), jnp.float32),
-            jax.ShapeDtypeStruct((m, 1), jnp.float32),
-            jax.ShapeDtypeStruct((m, 1), jnp.float32),
+            jax.ShapeDtypeStruct((m, m), jnp.float32, vma=vma),
+            jax.ShapeDtypeStruct((m, m), jnp.float32, vma=vma),
+            jax.ShapeDtypeStruct((m, 1), jnp.float32, vma=vma),
+            jax.ShapeDtypeStruct((m, 1), jnp.float32, vma=vma),
         ],
         interpret=_interpret(),
-    )(jnp.asarray(count, jnp.int32).reshape(1, 1), s, y, g[None, :])
+    )(count, s, y, g[None, :])
     return sy, yy, p[:, 0], q[:, 0]
 
 
@@ -168,6 +176,8 @@ def fused_direction_assembly(s, y, g, w, u, h_diag, count=None):
         count = m
     grid = (pl.cdiv(n, _TILE_N),)
     smem11 = pl.BlockSpec((1, 1), lambda i: (0, 0), memory_space=pltpu.SMEM)
+    count = jnp.asarray(count, jnp.int32).reshape(1, 1)
+    h_diag = jnp.asarray(h_diag, jnp.float32).reshape(1, 1)
     hg = pl.pallas_call(
         functools.partial(_assembly_kernel, n=n),
         grid=grid,
@@ -181,11 +191,13 @@ def fused_direction_assembly(s, y, g, w, u, h_diag, count=None):
             pl.BlockSpec((m, 1), lambda i: (0, 0)),
         ],
         out_specs=pl.BlockSpec((1, _TILE_N), lambda i: (0, i)),
-        out_shape=jax.ShapeDtypeStruct((1, n), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct(
+            (1, n), jnp.float32, vma=_vma(count, h_diag, s, y, g, w, u)
+        ),
         interpret=_interpret(),
     )(
-        jnp.asarray(count, jnp.int32).reshape(1, 1),
-        jnp.asarray(h_diag, jnp.float32).reshape(1, 1),
+        count,
+        h_diag,
         s,
         y,
         g[None, :],

@@ -76,6 +76,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from federated_pytorch_test_tpu.ops import _interpret
+
 _NEG_BIG = -1e30
 
 _LOG2E = 1.4426950408889634  # 1/ln 2: exp(x) == exp2(x * _LOG2E)
@@ -95,9 +97,6 @@ _BK = 512
 
 _HI = jax.lax.Precision.HIGHEST
 
-
-def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
 
 
 # dot_general contracting specs: last-with-last ([M,D]x[N,D] -> [M,N]),

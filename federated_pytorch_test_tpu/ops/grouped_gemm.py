@@ -29,15 +29,14 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from federated_pytorch_test_tpu.ops import _interpret
+
 # MXU-friendly tiles; f32 minimum tile is (8, 128) so both are multiples.
 # M tiles sized for the fold's realistic per-group rows (B·P = 128..1024);
 # the tail tile is block-padded, any M/N works.
 _TILE_M = 256
 _TILE_N = 256
 
-
-def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
 
 
 def _grouped_kernel(lhs_ref, rhs_ref, out_ref):

@@ -21,6 +21,7 @@ from __future__ import annotations
 
 import jax.numpy as jnp
 from jax import lax
+from jax._src.lax.parallel import all_gather_invariant
 
 from federated_pytorch_test_tpu.parallel.mesh import CLIENT_AXIS
 
@@ -80,5 +81,12 @@ def all_clients(x_local: jnp.ndarray, axis_name: str = CLIENT_AXIS) -> jnp.ndarr
     DELIBERATELY spend a full [K, N] gather on integrity. The mean path
     keeps its psum — the reference's bandwidth-saving contract holds
     exactly when `robust_agg='mean'` (the default).
+
+    The result is identical on every device, and typed so: jax 0.9.0's
+    `lax.all_gather` returns a value `shard_map` still treats as varying
+    over `axis_name`, which a replicated scan carry (the consensus z) and
+    `out_specs=P()` both reject. `all_gather_invariant` is the same XLA
+    all-gather with the varying -> invariant type; 0.9.0 ships it without
+    a `jax.lax` export.
     """
-    return lax.all_gather(x_local, axis_name, axis=0, tiled=True)
+    return all_gather_invariant(x_local, axis_name, axis=0, tiled=True)

@@ -38,8 +38,8 @@ def _env_signals_multihost() -> bool:
     """True when the environment describes MORE than this one process.
 
     A coordinator address always does; `TPU_WORKER_HOSTNAMES` only when
-    it lists several workers — single-worker setups (including tunneled
-    dev chips) carry a one-entry list and are NOT multi-host.
+    it lists several workers — single-worker setups carry a one-entry
+    list and are NOT multi-host.
     """
     if any(
         v in os.environ

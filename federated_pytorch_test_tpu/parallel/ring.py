@@ -42,7 +42,7 @@ _NEG_BIG = -1e30  # large-negative instead of -inf: keeps exp() at exact 0
 
 
 def mark_varying(x, axis_name):
-    """Mark `x` as varying over `axis_name` (no-op on older JAX).
+    """Mark `x` as varying over `axis_name`.
 
     Used for constant-initialized accumulators that a loop will overwrite
     with varying values, and for replicated operands (e.g. the consensus
@@ -50,11 +50,7 @@ def mark_varying(x, axis_name):
     fixpoint re-applies recorded pvary insertions when loop carries get
     promoted, which errors on an unvarying closed-over constant.
     """
-    if hasattr(lax, "pcast"):
-        return lax.pcast(x, (axis_name,), to="varying")
-    if hasattr(lax, "pvary"):  # pre-pcast JAX
-        return lax.pvary(x, (axis_name,))
-    return x
+    return lax.pcast(x, (axis_name,), to="varying")
 
 
 _pvary = mark_varying  # internal alias used below

@@ -1,54 +1,11 @@
-"""`shard_map` across JAX versions — the one import the whole engine rides.
+"""`shard_map` — the one import the whole engine rides.
 
-The framework is developed against JAX >= 0.9, where `shard_map` is a
-top-level export and its replication-checking knob is `check_vma`
-(varying-manual-axes). Older runtimes (0.4.x) ship it under
-`jax.experimental.shard_map` with the same semantics behind the
-`check_rep` keyword. A hard `from jax import shard_map` made that
-difference fatal at *import* time: every engine/consensus/parallel module
-— and every test transitively touching them — died on older
-environments before a single line ran. Robustness starts at import:
-resolve the symbol and the keyword once here, and let everything else
-spell `check_vma` uniformly.
+The installed jax (0.9.0) exports `shard_map` at top level with its
+replication check behind `check_vma`; every engine/consensus/parallel
+module imports it from here, so the name the repo maps through is
+stated once.
 """
 
-from __future__ import annotations
+from jax import shard_map
 
-import inspect
-
-try:  # JAX >= 0.6: top-level export
-    from jax import shard_map as _shard_map
-except ImportError:  # older JAX: experimental module
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-# The check keyword follows the SIGNATURE, not the import location: there
-# are versions where the top-level export exists but still takes the
-# legacy `check_rep` (the rename to `check_vma` landed later), and keying
-# on where the symbol imported from would pass the wrong keyword there.
-try:
-    _PARAMS = inspect.signature(_shard_map).parameters
-except (TypeError, ValueError):  # unsignaturable wrapper: assume modern
-    _PARAMS = {"check_vma": None}
-_CHECK_KW = "check_vma" if "check_vma" in _PARAMS else "check_rep"
-
-
-def shard_map(f, *, mesh, in_specs, out_specs, check_vma: bool = True, **kwargs):
-    """Version-portable `jax.shard_map` (keyword-only, like the modern API).
-
-    `check_vma` is a static developer-time consistency check, never a
-    numerics knob. The legacy `check_rep` machinery predates replication
-    rules for `while`/`scan` bodies (it raises NotImplementedError on the
-    L-BFGS line-search loop), so on the legacy path the check is forced
-    off — the modern environment keeps it on everywhere. Extra keywords
-    (e.g. `axis_names`) pass straight through to the underlying API.
-    """
-    if _CHECK_KW == "check_rep":
-        check_vma = False
-    return _shard_map(
-        f,
-        mesh=mesh,
-        in_specs=in_specs,
-        out_specs=out_specs,
-        **{_CHECK_KW: check_vma},
-        **kwargs,
-    )
+__all__ = ["shard_map"]

@@ -10,6 +10,7 @@ copy loop. All downstream consensus math operates on these flat vectors.
 
 from __future__ import annotations
 
+import math
 from typing import Any, Callable, Tuple
 
 import jax
@@ -49,14 +50,14 @@ def leaf_offsets(template: PyTree):
     out = []
     start = 0
     for path, leaf in leaves:
-        size = int(jnp.size(leaf))
+        size = math.prod(leaf.shape)
         out.append((_path_keys(path), start, size))
         start += size
     return out
 
 
 def total_size(template: PyTree) -> int:
-    return sum(int(jnp.size(l)) for l in jax.tree_util.tree_leaves(template))
+    return sum(math.prod(l.shape) for l in jax.tree_util.tree_leaves(template))
 
 
 def _path_keys(path) -> Tuple[str, ...]:

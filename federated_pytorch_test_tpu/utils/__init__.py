@@ -8,12 +8,14 @@ from federated_pytorch_test_tpu.utils.checkpoint import (
 )
 from federated_pytorch_test_tpu.utils.hostcpu import (
     compile_cache_dir,
+    enable_compile_cache,
     force_host_cpu,
     set_host_device_count,
 )
 
 __all__ = [
     "compile_cache_dir",
+    "enable_compile_cache",
     "Deferred",
     "MetricsRecorder",
     "checkpoint_path",

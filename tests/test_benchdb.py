@@ -439,7 +439,7 @@ def test_committed_debt_ledger_covers_perf_md(tmp_path):
 
 @smoke
 def test_rel_noise_floor_matches_committed_history():
-    # the committed BENCH_r01-r05 trajectory (mfu dips 12% between
-    # rounds) must sit inside the floor — the no-false-positives
+    # the pre-round BENCH_r01-r05 trajectory (records removed in PR 21;
+    # mfu dipped 12% between rounds) must sit inside the floor — the no-false-positives
     # acceptance criterion pins the constant
     assert REL_NOISE_FLOOR >= 0.15

@@ -16,12 +16,12 @@ ENV = dict(
     os.environ,
     JAX_PLATFORMS="cpu",
     XLA_FLAGS="--xla_force_host_platform_device_count=8",
-    # the CLI subprocess is a fresh interpreter with no conftest: point
-    # it at the same persistent compile cache so repeat CI runs skip the
-    # XLA compiles (the CLI honors the standard jax env var)
-    JAX_COMPILATION_CACHE_DIR=compile_cache_dir(),
     TF_CPP_MIN_LOG_LEVEL="3",
 )
+# the CLI subprocess is a fresh interpreter with no conftest: hand it
+# the same persistent compile cache so repeat CI runs skip the XLA
+# compiles — without overriding a cache the environment already placed
+ENV.setdefault("JAX_COMPILATION_CACHE_DIR", compile_cache_dir())
 
 
 def _run(*args, timeout=600):

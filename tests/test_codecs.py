@@ -16,8 +16,8 @@ Middle (default) tier: the trainer-level contracts —
   family's smoke assertions and ci.sh codec_smoke);
 * the PR-5 corruption acceptance gate (1 liar/round, trimmed(1),
   quarantine) holds under the top-k codec with error feedback AND the
-  adaptive scheduler in the program — zero rollbacks, within 2 points
-  of fault-free, folded dispatch {round: 1, round_init: 1};
+  adaptive scheduler in the program — zero rollbacks, within the
+  acceptance band of fault-free, folded dispatch {round: 1, round_init: 1};
 * every zoo/scheduler knob is trajectory-changing: stream-tag member,
   refused splice (mirroring the PR-9 bf16 regressions).
 
@@ -407,13 +407,13 @@ def test_topk_comm_bytes_hand_checked(_src):
 
 
 def test_topk_robust_gate_with_ef_and_adaptive(
-    src_hard_accept, fault_free_accept, accept_cfg
+    src_hard_accept, fault_free_accept, accept_cfg, accept_band
 ):
     """The PR-5 corruption acceptance gate UNDER the sparse codec with
     error feedback and the adaptive scheduler all in the program: 1
     client corrupted per round (scale λ=10, garbling the sparse wire in
     transit), trimmed(1) + z-score quarantine on the DECODED views —
-    zero rollbacks, within 2 points of fault-free, folded dispatch
+    zero rollbacks, within `accept_band` of fault-free, folded dispatch
     budget {round: 1, round_init: 1} with the drift signal in-scan and
     the slot decision memoized at round start. (The q8 mirror runs in
     the slow tier; the ≤25%-bytes frontier acceptance runs through the
@@ -435,7 +435,7 @@ def test_topk_robust_gate_with_ef_and_adaptive(
     acc_free = float(
         np.mean(fault_free_accept.recorder.latest("test_accuracy"))
     )
-    assert abs(acc - acc_free) <= 0.02, (acc, acc_free)
+    assert abs(acc - acc_free) <= accept_band, (acc, acc_free)
     for r in tr.recorder.series["dispatch_count"]:
         assert r["value"] == {"round": 1, "round_init": 1, "total": 2}
     # the scheduler decided every slot and streamed the evidence

@@ -22,18 +22,21 @@ pytestmark = pytest.mark.slow  # heavy tier (jit-compile dominated)
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
+def _example_env(**over):
+    """A fresh-interpreter environment (no conftest) that reuses the
+    persistent compile cache, so repeat CI runs skip the example's XLA
+    compiles — without overriding a cache the environment placed."""
+    env = dict(os.environ, JAX_PLATFORMS="cpu", TF_CPP_MIN_LOG_LEVEL="3", **over)
+    env.setdefault("JAX_COMPILATION_CACHE_DIR", compile_cache_dir())
+    return env
+
+
 def test_federated_lm_example_learns():
-    env = dict(
-        os.environ,
+    env = _example_env(
         XLA_FLAGS="--xla_force_host_platform_device_count=4",
-        JAX_PLATFORMS="cpu",
         NLOOP="1",
         K="4",
         SEQ="32",
-        # fresh interpreter, no conftest: reuse the persistent compile
-        # cache so repeat CI runs skip the example's XLA compiles
-        JAX_COMPILATION_CACHE_DIR=compile_cache_dir(),
-        TF_CPP_MIN_LOG_LEVEL="3",
     )
     proc = subprocess.run(
         [sys.executable, os.path.join(REPO, "examples", "federated_lm.py")],
@@ -50,14 +53,10 @@ def test_long_context_lm_example_runs_and_matches_dense():
     # the sequence-parallel recipe as a user runs it: 8-device virtual
     # ring, the script's own ring==dense loss identity, and two L-BFGS
     # steps on the copy task (tiny SEQ keeps compiles in seconds)
-    env = dict(
-        os.environ,
+    env = _example_env(
         XLA_FLAGS="--xla_force_host_platform_device_count=8",
-        JAX_PLATFORMS="cpu",
         SEQ="64",
         STEPS="2",
-        JAX_COMPILATION_CACHE_DIR=compile_cache_dir(),
-        TF_CPP_MIN_LOG_LEVEL="3",
     )
     proc = subprocess.run(
         [sys.executable, os.path.join(REPO, "examples", "long_context_lm.py")],
@@ -83,10 +82,8 @@ def test_pod_scale64_example_smoke(tmp_path):
     # recorder.save path a pod runs, shrunk via the script's env
     # overrides (K=8 simple-CNN clients, one group, one round)
     out = tmp_path / "scale64_metrics.json"
-    env = dict(
-        os.environ,
+    env = _example_env(
         XLA_FLAGS="--xla_force_host_platform_device_count=8",
-        JAX_PLATFORMS="cpu",
         # NTRAIN/NTEST only shrink the SYNTHETIC fallback; point the data
         # root at an empty dir so a real archive on the host can't turn
         # the smoke test into a full-CIFAR run
@@ -100,8 +97,6 @@ def test_pod_scale64_example_smoke(tmp_path):
         NTEST="16",
         MAX_GROUPS="1",
         METRICS_OUT=str(out),
-        JAX_COMPILATION_CACHE_DIR=compile_cache_dir(),
-        TF_CPP_MIN_LOG_LEVEL="3",
     )
     proc = subprocess.run(
         [sys.executable, os.path.join(REPO, "examples", "pod_scale64.py")],

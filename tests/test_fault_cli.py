@@ -23,9 +23,9 @@ ENV = dict(
     os.environ,
     JAX_PLATFORMS="cpu",
     XLA_FLAGS="--xla_force_host_platform_device_count=8",
-    JAX_COMPILATION_CACHE_DIR=compile_cache_dir(),
     TF_CPP_MIN_LOG_LEVEL="3",
 )
+ENV.setdefault("JAX_COMPILATION_CACHE_DIR", compile_cache_dir())
 
 
 def _run(*args, timeout=600):

@@ -1,10 +1,11 @@
 """Straggler-adaptive deadline rounds: speed-axis purity, strict plan
 loading, ragged step budgets, and the acceptance contract — with one 3x
 slow client per round and the deadline at the median client time, the
-deadline run finishes within 2 accuracy points of the fault-free run
-while the total simulated round wall-clock drops >= 2x vs the stall
-path; a deadline no client misses reproduces the lockstep trajectory
-BITWISE and the folded dispatch stays `{round: 1, round_init: 1}`.
+deadline run finishes within the acceptance band (conftest.py
+`accept_band`) of the fault-free run while the total simulated round
+wall-clock drops >= 2x vs the stall path; a deadline no client misses
+reproduces the lockstep trajectory BITWISE and the folded dispatch
+stays `{round: 1, round_init: 1}`.
 
 Smoke tier: plan/loader/injector units. Unmarked (middle) tier: the
 tier-1 gates above (fused path — the tier-1 wall sits near its
@@ -351,8 +352,8 @@ def test_ragged_streaming_path(_src):
 def _accept_cfg(**over):
     # nloop=1, nadmm=2 (not the robust suite's 2x3): the probe's cost
     # rides the tier-1 wall, two exchanges already cross a mask re-draw,
-    # and the measured accuracy delta at this size is 0.000 vs the
-    # 2-point gate — ample margin
+    # and the delta against fault-free is gated by conftest's
+    # `accept_band` (its docstring holds the measured spread)
     base = dict(
         batch=20, nloop=1, nadmm=2, max_groups=1, model="net",
         check_results=True, eval_batch=80, synthetic_ok=True,
@@ -370,10 +371,10 @@ def _sim_round_walls(tr):
     return [r["value"]["round"] for r in tr.recorder.series["client_time"]]
 
 
-def test_deadline_rounds_degrade_gracefully(_src_hard):
+def test_deadline_rounds_degrade_gracefully(_src_hard, accept_band):
     """THE acceptance gate: one 3x slow client per round, deadline at the
     median client time (= the nominal full-work time). The deadline run
-    finishes within 2 accuracy points of the fault-free run while the
+    finishes within `accept_band` of the fault-free run while the
     total simulated round wall-clock drops >= 2x vs the stall path, and
     the folded dispatch budget holds with the ragged machinery in the
     program."""
@@ -399,7 +400,9 @@ def test_deadline_rounds_degrade_gracefully(_src_hard):
         _accept_cfg(fault_plan=plan, round_deadline=4.0), _src_hard
     )
     acc = _final_acc(tr)
-    assert acc is not None and abs(acc - acc_free) <= 0.02, (acc, acc_free)
+    assert acc is not None and abs(acc - acc_free) <= accept_band, (
+        acc, acc_free
+    )
     # every round one client missed the deadline with a PARTIAL (not
     # zero) budget — the FedADMM inexact-local-work regime
     for r in tr.recorder.series["step_budget"]:

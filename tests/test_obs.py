@@ -214,6 +214,9 @@ def test_chip_peaks_lookup():
     assert chip_peaks("TPU v5 lite") == (197.0, 819.0)
     assert chip_peaks("TPU v4 (something)") == (275.0, 1228.0)
     assert chip_peaks("cpu") == (None, None)
+    # a TPU the table does not list is an error, never a silent default
+    with pytest.raises(ValueError, match="TPU v9"):
+        chip_peaks("TPU v9")
 
 
 @smoke
