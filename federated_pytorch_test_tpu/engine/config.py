@@ -364,7 +364,15 @@ class ExperimentConfig:
     # the length of any single program's scan. None = never chunk.
     max_scan_steps: int | None = 256
 
-    # write a jax.profiler trace of each epoch here (TPU/host timelines)
+    # device profiling of the steady state: every round of the run's
+    # SECOND outer loop (the first compiles; the only one if nloop == 1)
+    # runs in a jax.profiler window of its own under this directory
+    # (`round-<nloop>-<group>/`, TPU + host timelines), and each fused
+    # round's window is reduced to device seconds by phase of the round
+    # program: `phases.json` here and the process-local `device_phase`
+    # series (obs/phases.py, docs/OBSERVABILITY.md §Phase scopes). Needs
+    # an empty compile cache: a cached executable keeps the metadata it
+    # was stored with, and a table without phase scopes is refused.
     profile_dir: str | None = None
 
     # --- observability (obs/, docs/OBSERVABILITY.md) ---
@@ -421,9 +429,11 @@ class ExperimentConfig:
     # anomaly-triggered device profiling: the round AFTER a health alert
     # runs under a jax.profiler trace window written beneath this
     # directory (`round-<nloop>-<group>/`) — profiling that costs
-    # nothing until something is wrong. Bounded by `profile_budget`
-    # captures per process. Mutually exclusive with `profile_dir` (the
-    # whole-run trace — jax.profiler windows cannot nest). None = off.
+    # nothing until something is wrong; the capture is kept raw, not
+    # reduced. Bounded by `profile_budget` captures per process.
+    # Mutually exclusive with `profile_dir` (both open per-round
+    # windows through `Trainer._profile_window`, and jax.profiler
+    # windows cannot nest). None = off.
     profile_on_anomaly: str | None = None
     # per-process cap on anomaly-triggered profiler captures
     profile_budget: int = 3
@@ -788,8 +798,8 @@ class ExperimentConfig:
         if self.profile_on_anomaly is not None and self.profile_dir is not None:
             raise ValueError(
                 "profile_on_anomaly and profile_dir are mutually "
-                "exclusive: the whole-run jax.profiler trace cannot nest "
-                "an anomaly-triggered capture window inside itself"
+                "exclusive: both open one jax.profiler window per round, "
+                "and such windows cannot nest"
             )
         if self.profile_on_anomaly is not None and not self.health_monitor:
             raise ValueError(

@@ -88,6 +88,7 @@ from federated_pytorch_test_tpu.consensus import (
 from federated_pytorch_test_tpu.data import normalize
 from federated_pytorch_test_tpu.exchange import make_codec
 from federated_pytorch_test_tpu.models.base import active_leaf_mask, fold_params
+from federated_pytorch_test_tpu.obs.phases import scope, scoped
 from federated_pytorch_test_tpu.ops import _interpret
 from federated_pytorch_test_tpu.parallel.diagnostics import group_distances
 from federated_pytorch_test_tpu.optim import (
@@ -469,6 +470,18 @@ def _client_train_step(ctx: GroupContext):
     return step
 
 
+def _gather_batch(shard_imgs, shard_labels, idx_t):
+    """One lockstep step's minibatches, gathered on device from the
+    resident uint8 shards: `idx_t [K_loc, B]` -> images `[K_loc, B, H, W,
+    C]`, labels `[K_loc, B]`. Shared by the epoch and the round program."""
+    with scope("fedtpu.batch_gather"):
+        images = jnp.take_along_axis(
+            shard_imgs, idx_t[:, :, None, None, None], axis=1
+        )
+        labels = jnp.take_along_axis(shard_labels, idx_t, axis=1)
+    return images, labels
+
+
 def _ragged_select(keep):
     """Per-client select for one `[K_loc, ...]` carry leaf.
 
@@ -566,10 +579,7 @@ def build_epoch_fn(ctx: GroupContext, mesh, counter=None):
         z = mark_varying(z, CLIENT_AXIS)
 
         def step_all(flat, lstate, stats, idx_t):
-            images = jnp.take_along_axis(
-                shard_imgs, idx_t[:, :, None, None, None], axis=1
-            )
-            labels = jnp.take_along_axis(shard_labels, idx_t, axis=1)
+            images, labels = _gather_batch(shard_imgs, shard_labels, idx_t)
             return jax.vmap(
                 client_step,
                 in_axes=(0, 0, 0, 0, 0, 0, 0, 0, None, 0),
@@ -873,7 +883,7 @@ def _consensus_local(ctx: GroupContext):
                 met.survivors,
             ), qstats_of(x_send, z, mask), ef_new
 
-    return local
+    return scoped("fedtpu.exchange", local)
 
 
 def build_consensus_fn(ctx: GroupContext, mesh, counter=None):
@@ -963,7 +973,7 @@ def _client_eval_fn(model, unravel, has_stats: bool):
         )
         return correct
 
-    return client_eval
+    return scoped("fedtpu.eval", client_eval)
 
 
 def build_round_fn(
@@ -1136,10 +1146,9 @@ def build_round_fn(
             zv = mark_varying(z, CLIENT_AXIS)
 
             def step_all(flat, lstate, stats, idx_t):
-                images = jnp.take_along_axis(
-                    shard_imgs, idx_t[:, :, None, None, None], axis=1
+                images, labels = _gather_batch(
+                    shard_imgs, shard_labels, idx_t
                 )
-                labels = jnp.take_along_axis(shard_labels, idx_t, axis=1)
                 return jax.vmap(
                     client_step,
                     in_axes=(0, 0, 0, 0, 0, 0, 0, 0, None, 0),
@@ -1209,7 +1218,10 @@ def build_round_fn(
                 zeros = jnp.zeros((), flat.dtype)
                 met = (zeros, zeros, zeros, zeros)
                 qstats = ()
-            param_ok = jnp.isfinite(flat).all(axis=tuple(range(1, flat.ndim)))
+            with scope("fedtpu.round_tail"):
+                param_ok = jnp.isfinite(flat).all(
+                    axis=tuple(range(1, flat.ndim))
+                )
 
             ys = (losses, met, param_ok)
             if quarantine:
@@ -1257,7 +1269,8 @@ def build_round_fn(
         # the adaptive scheduler's in-scan signal: the SHARED
         # group_distances body on the round's final parameters — one
         # psum, replicated [num_groups] output, same dispatch
-        drift = group_distances(flat, ctx.partition) if drift_on else ()
+        with scope("fedtpu.round_tail"):
+            drift = group_distances(flat, ctx.partition) if drift_on else ()
         return (flat, lstate, stats, y, z, rho, extra,
                 losses, met, param_ok, qstats, snaps, correct,
                 ef_out, drift)

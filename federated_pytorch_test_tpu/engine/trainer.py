@@ -90,6 +90,7 @@ from federated_pytorch_test_tpu.obs import (
     memory_record,
     roofline_record,
 )
+from federated_pytorch_test_tpu.obs import phases as obs_phases
 from federated_pytorch_test_tpu.obs.sinks import jsonable
 from jax.sharding import NamedSharding, PartitionSpec
 
@@ -142,7 +143,11 @@ class Trainer:
         `parallel.multihost_client_mesh(K)` on pods (its `clients` axis
         size must divide `cfg.n_clients`)."""
         self.cfg = cfg
-        self.recorder = MetricsRecorder(verbose=verbose)
+        # every `phase()` span is also a `fedtpu:<phase>` host event on the
+        # profiler's clock (the recorder itself stays free of jax)
+        self.recorder = MetricsRecorder(
+            verbose=verbose, annotate=jax.profiler.TraceAnnotation
+        )
         # run-lifecycle flags (obs/flight.py crash dumps): `close()` only
         # writes a crash bundle for a run that ENTERED `run()` and never
         # completed — benchmarks driving `run_round` by hand and then
@@ -636,6 +641,15 @@ class Trainer:
         # under a jax.profiler window, bounded per process
         self._profile_pending = False
         self._profile_captures = 0
+        # `--profile-dir`: the outer loop whose rounds `run()` captures,
+        # one window per round (set by `_run_impl`; driving `run_round`
+        # by hand captures nothing), the compiled round programs'
+        # {instruction: phase} tables (own metadata, inferred) and the
+        # per-group reductions that `phases.json` holds
+        self._profile_loop: Optional[int] = None
+        self._profile_compiles = False
+        self._phase_tables: Dict[int, tuple] = {}
+        self._phase_report: Dict[str, dict] = {}
         # storage_fault incident rising edge: detections + repairs the
         # store has surfaced that a previous round already reported
         self._storage_fault_seen = 0
@@ -2048,47 +2062,14 @@ class Trainer:
                 # the hot program of a fused run IS the round program:
                 # lower it against the real round arguments and stop —
                 # the epoch / consensus programs would never be dispatched
-                round_fn = self._round_fn(gid)
-                lstate, y, z, rho, extra = self._init_fn(gid)(self.flat)
-                idx = self._round_indices(0, gid)
-                sh = NamedSharding(self.mesh, PartitionSpec(None, CLIENT_AXIS))
-                masks = self._put(
-                    np.ones((self.cfg.nadmm, self.cfg.n_clients), np.float32),
-                    sh,
-                )
-                ef_args = (self._ef_for(gid),) if self._ef_enabled() else ()
-                budget_args = ()
-                if self._ragged_enabled():
-                    budget_args = (
-                        self._put(
-                            np.full(
-                                (self.cfg.nadmm, self.cfg.n_clients),
-                                self._round_total_steps(),
-                                np.int32,
-                            ),
-                            sh,
-                        ),
-                    )
-                corr_args = ()
-                if ctx_corrupt:
-                    shape = (self.cfg.nadmm, self.cfg.n_clients)
-                    corr_args = (
-                        self._put(np.zeros(shape, np.int32), sh),
-                        self._put(np.ones(shape, np.float32), sh),
-                        self._put(np.zeros(shape, np.int32), sh),
-                    )
-                eval_args = (
-                    (self.test_imgs, self.test_labels, self.test_mask)
-                    if self._fold_eval_enabled()
-                    else ()
-                )
-                compiled = round_fn.lower(
-                    self.flat, lstate, self.stats, self.shard_imgs,
-                    self.shard_labels, idx, self.mean, self.std,
-                    y, z, rho, extra, masks, *ef_args, *budget_args,
-                    *corr_args, *eval_args,
-                ).compile()
+                compiled = self._lower_round(gid).compile()
                 self._stash_round_cost(gid, compiled)
+                if self.cfg.profile_dir:
+                    text = compiled.as_text()
+                    table = obs_phases.op_phase_table(text)
+                    self._phase_tables[gid] = (
+                        table, obs_phases.inferred_phases(text, table)
+                    )
                 return time.perf_counter() - t0
             epoch_fn, consensus_fn, init_fn = self._fns(gid)
             lstate, y, z, rho, extra = init_fn(self.flat)
@@ -2134,6 +2115,44 @@ class Trainer:
                     self._full_mask, *ef_args, *corr_args,
                 ).compile()
             return time.perf_counter() - t0
+
+    def _lower_round(self, gid: int):
+        """The fused round program of `gid` lowered against arguments of
+        a real round's shapes (`jax.stages.Lowered`; nothing but the
+        cheap round-init program executes)."""
+        cfg = self.cfg
+        round_fn = self._round_fn(gid)
+        lstate, y, z, rho, extra = self._init_fn(gid)(self.flat)
+        idx = self._round_indices(0, gid)
+        sh = NamedSharding(self.mesh, PartitionSpec(None, CLIENT_AXIS))
+        shape = (cfg.nadmm, cfg.n_clients)
+        masks = self._put(np.ones(shape, np.float32), sh)
+        ef_args = (self._ef_for(gid),) if self._ef_enabled() else ()
+        budget_args = ()
+        if self._ragged_enabled():
+            budget_args = (
+                self._put(
+                    np.full(shape, self._round_total_steps(), np.int32), sh
+                ),
+            )
+        corr_args = ()
+        if self._corruption_enabled():
+            corr_args = (
+                self._put(np.zeros(shape, np.int32), sh),
+                self._put(np.ones(shape, np.float32), sh),
+                self._put(np.zeros(shape, np.int32), sh),
+            )
+        eval_args = (
+            (self.test_imgs, self.test_labels, self.test_mask)
+            if self._fold_eval_enabled()
+            else ()
+        )
+        return round_fn.lower(
+            self.flat, lstate, self.stats, self.shard_imgs,
+            self.shard_labels, idx, self.mean, self.std,
+            y, z, rho, extra, masks, *ef_args, *budget_args,
+            *corr_args, *eval_args,
+        )
 
     def _stash_round_cost(self, gid: int, compiled) -> None:
         """Record the AOT-compiled round program's exact XLA FLOP/byte
@@ -2227,8 +2246,9 @@ class Trainer:
         trace span covering the round, per-round `dispatch_count` /
         `recompile_count` deltas, the `--diagnostics-every` cadence, the
         health digest + `memory` record, the flight recorder's incident
-        dump, the anomaly-armed profiler window, the `watch` status
-        sidecar, and the per-round sink flush. The `health` record is
+        dump, the profiler window of either profiler knob
+        (`_profile_window`), the `watch` status sidecar, and the
+        per-round sink flush. The `health` record is
         logged BEFORE `dispatch_count`, which is therefore the round's
         FINAL streamed record in both trainer paths — the flight ring's
         segmentation boundary (obs/flight.py). An injected crash skips
@@ -2236,6 +2256,10 @@ class Trainer:
         run re-records it) but still flushes, so the crashed stream
         holds everything the round logged.
         """
+        # one jax.profiler window around this round, if either profiler
+        # knob asks for one; decided before the counter snapshots, so
+        # what `_profile_window` dispatches is not counted as the round's
+        prof_dir = self._profile_window(nloop, gid)
         before = self._dispatch.snapshot()
         compiled_before = self._dispatch.compiled_programs()
         if self._ragged_enabled():
@@ -2245,21 +2269,11 @@ class Trainer:
             # same position in both trainer paths, so fused and unfused
             # runs decide from the identical prefix
             self._decide_deadline(nloop, gid)
-        # anomaly-armed profiler window (`--profile-on-anomaly DIR`): the
-        # PREVIOUS round's health alert armed it; capture this round
-        # under a jax.profiler trace, bounded by the per-process budget —
-        # profiling that costs nothing until something is wrong
-        prof_cm = contextlib.nullcontext()
-        prof_dir = None
-        if self._profile_pending:
-            self._profile_pending = False
-            if self._profile_captures < self.cfg.profile_budget:
-                prof_dir = os.path.join(
-                    self.cfg.profile_on_anomaly, f"round-{nloop}-{gid}"
-                )
-                os.makedirs(prof_dir, exist_ok=True)
-                prof_cm = jax.profiler.trace(prof_dir)
-                self._profile_captures += 1
+        prof_cm = (
+            jax.profiler.trace(prof_dir)
+            if prof_dir is not None
+            else contextlib.nullcontext()
+        )
         try:
             with prof_cm:
                 with self.recorder.phase(
@@ -2272,12 +2286,15 @@ class Trainer:
         finally:
             self.recorder.flush()
         if prof_dir is not None:
-            # a capture path is a fact about THIS process (a resumed run
+            # a capture is a fact about THIS process (a resumed run
             # re-arms from its own alerts): stream=False, like roofline
-            self.recorder.log(
-                "profile_capture", {"dir": prof_dir}, stream=False,
-                nloop=nloop, group=gid,
-            )
+            if self.cfg.profile_dir:
+                self._record_device_phase(prof_dir, nloop, gid)
+            else:
+                self.recorder.log(
+                    "profile_capture", {"dir": prof_dir}, stream=False,
+                    nloop=nloop, group=gid,
+                )
         self._rounds_done += 1
         # the diagnostics sample runs BEFORE the delta is taken, so its
         # dispatch (and first-use compile) land in THIS round's
@@ -2410,6 +2427,81 @@ class Trainer:
         if self._status_path is not None:
             self._write_status(nloop, gid)
 
+    def _profile_window(self, nloop: int, gid: int) -> Optional[str]:
+        """The directory of the profiler window round `(nloop, gid)`
+        runs under, or None: `<root>/round-<nloop>-<gid>/`.
+
+        `--profile-on-anomaly DIR`: the PREVIOUS round's health alert
+        armed it; this round is captured, bounded by the per-process
+        budget — profiling that costs nothing until something is wrong.
+        `--profile-dir DIR`: every round of `_profile_loop`, one window
+        each, so a window holds ONE round program's launch and
+        instruction names that repeat across groups' programs cannot
+        mix. A fused round's window is reduced afterwards
+        (`_record_device_phase`); its {instruction: phase} table is made
+        here, BEFORE the window opens, from one AOT lowering of the
+        round program (a compile-cache load) — nothing of this runs in
+        an unprofiled round."""
+        cfg = self.cfg
+        if self._profile_pending:
+            self._profile_pending = False
+            if self._profile_captures >= cfg.profile_budget:
+                return None
+            self._profile_captures += 1
+            root = cfg.profile_on_anomaly
+        elif cfg.profile_dir and nloop == self._profile_loop:
+            root = cfg.profile_dir
+            if self._fused_enabled() and gid not in self._phase_tables:
+                self.compile_round(gid)
+        else:
+            return None
+        prof_dir = os.path.join(root, f"round-{nloop}-{gid}")
+        os.makedirs(prof_dir, exist_ok=True)
+        return prof_dir
+
+    def _record_device_phase(self, prof_dir: str, nloop: int, gid: int) -> None:
+        """Reduce one `--profile-dir` window to device seconds by phase
+        (obs/phases.py), log it as `device_phase` and rewrite
+        `<profile_dir>/phases.json` (per group: seconds and share by
+        phase, `unattributed`, `busy_s`, the round's wall, the costliest
+        instructions with their phase). An unfused round's window is
+        kept unreduced: its programs are many, and the join by
+        instruction name needs one program per window."""
+        rec = {
+            "dir": prof_dir,
+            "nloop": int(nloop),
+            # a run of one loop has no second loop to capture
+            "compilation_inside": self._profile_compiles,
+            "round_wall_s": next(
+                (
+                    r["value"]["seconds"]
+                    for r in reversed(self.recorder.series.get("step_time", []))
+                    if r["value"]["phase"] == "fused_round"
+                    and (r.get("nloop"), r.get("group")) == (nloop, gid)
+                ),
+                None,
+            ),
+        }
+        if gid in self._phase_tables:
+            t0 = time.perf_counter()
+            events = obs_phases.load_device_events(
+                obs_phases.find_xplane(prof_dir)
+            )
+            rec.update(
+                obs_phases.device_seconds_by_phase(
+                    events, *self._phase_tables[gid]
+                )
+            )
+            rec["reduce_s"] = time.perf_counter() - t0
+        self.recorder.log(
+            "device_phase", rec, stream=False, nloop=nloop, group=gid
+        )
+        self._phase_report[str(gid)] = rec
+        path = os.path.join(self.cfg.profile_dir, "phases.json")
+        with open(path + ".tmp", "w") as f:
+            json.dump({"groups": self._phase_report}, f, indent=1)
+        os.replace(path + ".tmp", path)
+
     def _incident_extra(self) -> dict:
         """The non-ring half of an incident bundle (obs/flight.py): the
         deadline/schedule decision memos, the fault plan's slice over
@@ -2529,6 +2621,18 @@ class Trainer:
             )
         dists = self._fetch(self._diag_fn(self.flat))
         self.recorder.group_distance(dists, nloop=nloop, group=gid)
+
+    def _solver_work(self, lstate) -> dict:
+        """The solver's work counters of one round, per client, off the
+        L-BFGS state the round's last epoch left: `lstate` is made fresh
+        by the round-init program, so they are the round's totals.
+        `func_evals` counts gradient evaluations (entry + re-evaluations),
+        `ls_evals` the forward-only Armijo probes, `n_iter` the inner
+        iterations (optim/lbfgs.py LBFGSState)."""
+        return {
+            name: [int(v) for v in self._fetch(getattr(lstate, name))]
+            for name in ("n_iter", "func_evals", "ls_evals")
+        }
 
     def _run_round_unfused(self, nloop: int, gid: int) -> None:
         """`run_round`'s per-dispatch path (see its docstring)."""
@@ -2739,7 +2843,7 @@ class Trainer:
                 ef_args = (ef,) if ef_on else ()
                 with self.recorder.phase(
                     "consensus", nloop=nloop, group=gid, nadmm=nadmm
-                ), jax.profiler.TraceAnnotation("consensus"):
+                ):
                     (self.flat, y, z, rho, extra, met, qstats,
                      ef_out) = consensus_fn(
                         self.flat, y, z, rho, extra, jnp.int32(nadmm), mask,
@@ -2797,6 +2901,9 @@ class Trainer:
                 self.recorder.accuracies(
                     self.evaluate_deferred(), nloop=nloop, group=gid, nadmm=nadmm
                 )
+        self.recorder.log(
+            "solver_work", self._solver_work(lstate), nloop=nloop, group=gid
+        )
         if cfg.strategy == "admm":
             self._rho_store[gid] = rho
         if ef_on:
@@ -2858,91 +2965,98 @@ class Trainer:
             snap = self._entry_snapshot(gid)
         self._round_poisoned = False
         round_fn = self._round_fn(gid)
-        lstate, y, z, rho, extra = self._init_fn(gid)(self.flat)
-        if cfg.strategy == "admm" and gid in self._rho_store:
-            rho = self._rho_store[gid]  # carry BB-adapted rho across loops
+        # host spans of the round's edges (`record=False`: spans only, on
+        # the profiler's clock as `fedtpu:<span>`; the `step_time` series
+        # keeps its phase set): what the host does in the gaps the device
+        # trace shows before and after the round program
+        span = dict(record=False, nloop=nloop, group=gid)
+        with self.recorder.phase("round_init", **span):
+            lstate, y, z, rho, extra = self._init_fn(gid)(self.flat)
+            if cfg.strategy == "admm" and gid in self._rho_store:
+                rho = self._rho_store[gid]  # carry BB-adapted rho across loops
         gsize = self.partition.group_size(gid)
 
-        idx = self._round_indices(nloop, gid)
-        masks_np = np.ones((cfg.nadmm, cfg.n_clients), np.float32)
-        total_delay = 0.0
-        # masks and straggler stalls belong to the CONSENSUS exchange —
-        # the unfused path draws them under `if consensus_fn is not None`,
-        # so independent (strategy 'none') chaos runs must not stall or
-        # record them here either
-        if self.injector is not None and cfg.strategy != "none":
-            masks_np = self._vslice(
-                self.injector.masks_for_round(nloop, gid, cfg.nadmm), nloop
-            )
-            for a, d in enumerate(
-                self.injector.straggler_delays_for_round(nloop, gid, cfg.nadmm)
-            ):
-                if d > 0:
-                    dl_cap = self._deadline_for(nloop, gid)
-                    if dl_cap is not None:
-                        # deadline rounds cap the coordinator's wait: past
-                        # the deadline the round closes without the
-                        # straggler instead of stalling for it
-                        d = min(d, dl_cap)
-                    self.recorder.step_time(
-                        "straggler_wait", d, nloop=nloop, group=gid, nadmm=a
-                    )
-                    total_delay += d
-                if self.injector.will_crash(nloop, gid, a):
-                    # the unfused replay crashes at the END of iteration
-                    # `a`: its own stall is served, later iterations'
-                    # never happen — truncate so fused wall time and the
-                    # straggler_wait series match (and the resumed run,
-                    # sentinel fired, serves the full schedule like the
-                    # unfused one)
-                    break
-        if total_delay > 0 and rollback:
-            # rollback keeps the pre-dispatch stall: the transactional
-            # round's observable ordering (coordinator waits out the
-            # stragglers, THEN the round's work runs and is judged) must
-            # not change — a rolled-back round's wall must still include
-            # the stall it provoked, not hide it under discarded compute
-            time.sleep(total_delay)
-        hetero = self._hetero_enabled()
-        ragged = self._ragged_enabled()
-        total_steps = self._round_total_steps()
-        budgets_np = times_np = None
-        budget_args = ()
-        if hetero:
-            _, budgets_np, times_np = self._round_hetero(nloop, gid)
-        if ragged:
-            budget_args = (
-                self._put(
-                    budgets_np,
-                    NamedSharding(
-                        self.mesh, PartitionSpec(None, CLIENT_AXIS)
-                    ),
-                ),
-            )
-        masks = self._put(
-            masks_np,
-            NamedSharding(self.mesh, PartitionSpec(None, CLIENT_AXIS)),
-        )
-        corrupt = self._corruption_enabled()
-        corr_args = ()
-        if corrupt:
-            sh = NamedSharding(self.mesh, PartitionSpec(None, CLIENT_AXIS))
-            corr_args = tuple(
-                self._put(self._vslice(arr, nloop), sh)
-                for arr in self.injector.corruption_for_round(
-                    nloop, gid, cfg.nadmm
+        with self.recorder.phase("round_inputs", **span):
+            idx = self._round_indices(nloop, gid)
+            masks_np = np.ones((cfg.nadmm, cfg.n_clients), np.float32)
+            total_delay = 0.0
+            # masks and straggler stalls belong to the CONSENSUS exchange —
+            # the unfused path draws them under `if consensus_fn is not None`,
+            # so independent (strategy 'none') chaos runs must not stall or
+            # record them here either
+            if self.injector is not None and cfg.strategy != "none":
+                masks_np = self._vslice(
+                    self.injector.masks_for_round(nloop, gid, cfg.nadmm), nloop
                 )
+                for a, d in enumerate(
+                    self.injector.straggler_delays_for_round(nloop, gid, cfg.nadmm)
+                ):
+                    if d > 0:
+                        dl_cap = self._deadline_for(nloop, gid)
+                        if dl_cap is not None:
+                            # deadline rounds cap the coordinator's wait: past
+                            # the deadline the round closes without the
+                            # straggler instead of stalling for it
+                            d = min(d, dl_cap)
+                        self.recorder.step_time(
+                            "straggler_wait", d, nloop=nloop, group=gid, nadmm=a
+                        )
+                        total_delay += d
+                    if self.injector.will_crash(nloop, gid, a):
+                        # the unfused replay crashes at the END of iteration
+                        # `a`: its own stall is served, later iterations'
+                        # never happen — truncate so fused wall time and the
+                        # straggler_wait series match (and the resumed run,
+                        # sentinel fired, serves the full schedule like the
+                        # unfused one)
+                        break
+            if total_delay > 0 and rollback:
+                # rollback keeps the pre-dispatch stall: the transactional
+                # round's observable ordering (coordinator waits out the
+                # stragglers, THEN the round's work runs and is judged) must
+                # not change — a rolled-back round's wall must still include
+                # the stall it provoked, not hide it under discarded compute
+                time.sleep(total_delay)
+            hetero = self._hetero_enabled()
+            ragged = self._ragged_enabled()
+            total_steps = self._round_total_steps()
+            budgets_np = times_np = None
+            budget_args = ()
+            if hetero:
+                _, budgets_np, times_np = self._round_hetero(nloop, gid)
+            if ragged:
+                budget_args = (
+                    self._put(
+                        budgets_np,
+                        NamedSharding(
+                            self.mesh, PartitionSpec(None, CLIENT_AXIS)
+                        ),
+                    ),
+                )
+            masks = self._put(
+                masks_np,
+                NamedSharding(self.mesh, PartitionSpec(None, CLIENT_AXIS)),
             )
-        quarantine = self._quarantine_enabled()
-        ef_on = self._ef_enabled()
-        ef_args = (self._ef_for(gid),) if ef_on else ()
+            corrupt = self._corruption_enabled()
+            corr_args = ()
+            if corrupt:
+                sh = NamedSharding(self.mesh, PartitionSpec(None, CLIENT_AXIS))
+                corr_args = tuple(
+                    self._put(self._vslice(arr, nloop), sh)
+                    for arr in self.injector.corruption_for_round(
+                        nloop, gid, cfg.nadmm
+                    )
+                )
+            quarantine = self._quarantine_enabled()
+            ef_on = self._ef_enabled()
+            ef_args = (self._ef_for(gid),) if ef_on else ()
 
-        fold = self._fold_eval_enabled()
-        eval_args = (
-            (self.test_imgs, self.test_labels, self.test_mask)
-            if fold
-            else ()
-        )
+            fold = self._fold_eval_enabled()
+            eval_args = (
+                (self.test_imgs, self.test_labels, self.test_mask)
+                if fold
+                else ()
+            )
         self._step_num += cfg.nadmm * cfg.nepoch
         with self.recorder.phase(
             "fused_round", nloop=nloop, group=gid,
@@ -2968,94 +3082,103 @@ class Trainer:
             # device->host fetch of an output is the completion barrier
             # (the telemetry series is needed host-side regardless)
             losses = self._fetch(losses_d)  # [nadmm, nepoch, S, K]
-        param_ok = self._fetch(param_ok_d)  # [nadmm, K]
-        dual, primal, mean_rho, survivors = (self._fetch(m) for m in met)
-        # the folded evals' correct counts ride the same completion
-        # barrier: one [nadmm, K] fetch covers every eval of the round
-        correct = self._fetch(correct_d) if fold else None
+        with self.recorder.phase("round_fetch", **span):
+            param_ok = self._fetch(param_ok_d)  # [nadmm, K]
+            dual, primal, mean_rho, survivors = (
+                self._fetch(m) for m in met
+            )
+            # the folded evals' correct counts ride the same completion
+            # barrier: one [nadmm, K] fetch covers every eval of the round
+            correct = self._fetch(correct_d) if fold else None
+            # quarantine replay state: the in-carry decision already
+            # happened on device; qmask_np re-derives each exchange's
+            # trusted set so the host bookkeeping (wasted-uplink
+            # attribution) matches it. The [nadmm, K] statistic matrices
+            # are fetched ONCE here — the per-round read steps.py's
+            # docstring promises — and the replay loop below slices host
+            # arrays only.
+            qmask_np = np.ones(cfg.n_clients, np.float32)
+            if quarantine:
+                qnorm_m = self._fetch(qstats_d[0])  # [nadmm, K]
+                qsusp_m = self._fetch(qstats_d[1])
+            solver_work = self._solver_work(lstate)
         is_admm = cfg.strategy == "admm"
-        # quarantine replay state: the in-carry decision already happened
-        # on device; qmask_np re-derives each exchange's trusted set so
-        # the host bookkeeping (wasted-uplink attribution) matches it.
-        # The [nadmm, K] statistic matrices are fetched ONCE here — the
-        # per-round read steps.py's docstring promises — and the replay
-        # loop below slices host arrays only.
-        qmask_np = np.ones(cfg.n_clients, np.float32)
-        if quarantine:
-            qnorm_m = self._fetch(qstats_d[0])  # [nadmm, K]
-            qsusp_m = self._fetch(qstats_d[1])
 
         # host bookkeeping replay, in the unfused path's per-round order
-        for a in range(cfg.nadmm):
-            for e in range(cfg.nepoch):
-                for s in range(losses.shape[2]):
-                    self.recorder.batch_losses(
-                        losses[a, e, s],
-                        nloop=nloop, group=gid, nadmm=a, epoch=e, minibatch=s,
+        with self.recorder.phase("round_records", **span):
+            for a in range(cfg.nadmm):
+                for e in range(cfg.nepoch):
+                    for s in range(losses.shape[2]):
+                        self.recorder.batch_losses(
+                            losses[a, e, s],
+                            nloop=nloop, group=gid, nadmm=a, epoch=e, minibatch=s,
+                        )
+                    if check:
+                        self._check_losses(
+                            losses[a, e], nloop=nloop, group=gid, nadmm=a, epoch=e
+                        )
+                if cfg.strategy != "none":
+                    if hetero:
+                        self._record_hetero(
+                            times_np[a],
+                            budgets_np[a] if budgets_np is not None else None,
+                            nloop=nloop, gid=gid, a=a, total=total_steps,
+                        )
+                    self.recorder.residuals(
+                        float(primal[a]) if is_admm else None,
+                        float(dual[a]),
+                        float(mean_rho[a]) if is_admm else None,
+                        nloop=nloop, group=gid, nadmm=a, group_size=gsize,
                     )
+                    if self.injector is not None:
+                        self.recorder.participation(
+                            int(survivors[a]), cfg.n_clients,
+                            nloop=nloop, group=gid, nadmm=a,
+                        )
+                    # same comm accounting as the unfused path, one record per
+                    # consensus iteration of the fused scan (obs/ledger.py):
+                    # every transmitting (plan-alive, deadline-making)
+                    # client's bytes, with a quarantined sender's attributed
+                    # as wasted
+                    transmit = masks_np[a]
+                    if ragged:
+                        transmit = transmit * (budgets_np[a] > 0)
+                    _, quarantined_now = self._effective_exchange_mask(
+                        transmit, qmask_np, quarantine
+                    )
+                    self._comm.record(
+                        self.recorder, gid, int(transmit.sum()),
+                        nloop=nloop, nadmm=a, quarantined=quarantined_now,
+                    )
+                    if quarantine:
+                        qmask_np = self._record_quarantine(
+                            (qnorm_m[a], qsusp_m[a]), qmask_np,
+                            nloop=nloop, group=gid, nadmm=a,
+                        )
                 if check:
-                    self._check_losses(
-                        losses[a, e], nloop=nloop, group=gid, nadmm=a, epoch=e
+                    self._check_param_flags(
+                        param_ok[a], nloop=nloop, group=gid, nadmm=a
                     )
-            if cfg.strategy != "none":
-                if hetero:
-                    self._record_hetero(
-                        times_np[a],
-                        budgets_np[a] if budgets_np is not None else None,
-                        nloop=nloop, gid=gid, a=a, total=total_steps,
-                    )
-                self.recorder.residuals(
-                    float(primal[a]) if is_admm else None,
-                    float(dual[a]),
-                    float(mean_rho[a]) if is_admm else None,
-                    nloop=nloop, group=gid, nadmm=a, group_size=gsize,
-                )
                 if self.injector is not None:
-                    self.recorder.participation(
-                        int(survivors[a]), cfg.n_clients,
-                        nloop=nloop, group=gid, nadmm=a,
-                    )
-                # same comm accounting as the unfused path, one record per
-                # consensus iteration of the fused scan (obs/ledger.py):
-                # every transmitting (plan-alive, deadline-making)
-                # client's bytes, with a quarantined sender's attributed
-                # as wasted
-                transmit = masks_np[a]
-                if ragged:
-                    transmit = transmit * (budgets_np[a] > 0)
-                _, quarantined_now = self._effective_exchange_mask(
-                    transmit, qmask_np, quarantine
-                )
-                self._comm.record(
-                    self.recorder, gid, int(transmit.sum()),
-                    nloop=nloop, nadmm=a, quarantined=quarantined_now,
-                )
-                if quarantine:
-                    qmask_np = self._record_quarantine(
-                        (qnorm_m[a], qsusp_m[a]), qmask_np,
-                        nloop=nloop, group=gid, nadmm=a,
-                    )
-            if check:
-                self._check_param_flags(
-                    param_ok[a], nloop=nloop, group=gid, nadmm=a
-                )
-            if self.injector is not None:
-                self.injector.maybe_crash(nloop, gid, a)
-            if cfg.check_results:
-                if fold:
-                    # already computed inside the round program and
-                    # fetched above; Deferred keeps the record on the
-                    # same harvest/discard path as the outside evals
-                    acc = Deferred(
-                        lambda a=a: correct[a] / self._test_total
-                    )
-                else:
-                    flat_snaps, stats_snaps = snaps
-                    acc = self.evaluate_deferred(
-                        flat=flat_snaps[a],
-                        stats=jax.tree.map(lambda x: x[a], stats_snaps),
-                    )
-                self.recorder.accuracies(acc, nloop=nloop, group=gid, nadmm=a)
+                    self.injector.maybe_crash(nloop, gid, a)
+                if cfg.check_results:
+                    if fold:
+                        # already computed inside the round program and
+                        # fetched above; Deferred keeps the record on the
+                        # same harvest/discard path as the outside evals
+                        acc = Deferred(
+                            lambda a=a: correct[a] / self._test_total
+                        )
+                    else:
+                        flat_snaps, stats_snaps = snaps
+                        acc = self.evaluate_deferred(
+                            flat=flat_snaps[a],
+                            stats=jax.tree.map(lambda x: x[a], stats_snaps),
+                        )
+                    self.recorder.accuracies(acc, nloop=nloop, group=gid, nadmm=a)
+            self.recorder.log(
+                "solver_work", solver_work, nloop=nloop, group=gid
+            )
         if is_admm:
             self._rho_store[gid] = rho
         if ef_on:
@@ -3209,10 +3332,13 @@ class Trainer:
     def run(self) -> MetricsRecorder:
         """The full experiment (all Nloop outer loops).
 
-        With `cfg.profile_dir` set, the whole run is captured as a
-        jax.profiler trace (device + host timelines, viewable in
-        TensorBoard/Perfetto) — the tracing subsystem the reference lacks
-        (SURVEY.md §5: a dead `start_time=time.time()` is all it has).
+        With `cfg.profile_dir` set, every round of the run's SECOND outer
+        loop (the first compiles; the only one if `nloop == 1`, and the
+        record then says compilation is inside) is captured in a
+        jax.profiler window of its own, `<profile_dir>/round-<nloop>-<gid>/`
+        (device + host timelines, viewable in TensorBoard/Perfetto), and
+        reduced to `<profile_dir>/phases.json`: device seconds by phase
+        of the round program (obs/phases.py, `run_round`).
         `cfg.trace_out` is the complementary HOST-side trace: the loop
         nest's round/epoch/consensus/eval/compile spans as Chrome
         trace-event JSON (obs/trace.py), written even when the run dies on
@@ -3220,11 +3346,7 @@ class Trainer:
         """
         self._run_started = True
         try:
-            if self.cfg.profile_dir:
-                with jax.profiler.trace(self.cfg.profile_dir):
-                    out = self._run_impl()
-            else:
-                out = self._run_impl()
+            out = self._run_impl()
             self._run_completed = True
             return out
         finally:
@@ -3304,6 +3426,10 @@ class Trainer:
 
     def _run_impl(self) -> MetricsRecorder:
         cfg = self.cfg
+        # `--profile-dir` captures the second loop this process runs: the
+        # first holds every compilation
+        self._profile_loop = min(self._completed_nloops + 1, cfg.nloop - 1)
+        self._profile_compiles = self._profile_loop == self._completed_nloops
         for nloop in range(self._completed_nloops, cfg.nloop):
             self.run_loop(nloop)
             self._completed_nloops = nloop + 1

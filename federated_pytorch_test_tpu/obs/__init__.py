@@ -47,6 +47,11 @@ Twelve pillars over the structured metric store (`utils/metrics.py`):
   (benchdb.py);
 * `debt_main` — the `debt` CLI verb: the re-measurement debt ledger as
   data plus the runnable script that pays it (debt.py).
+
+Beside them, `phases.py` (imported as a module, not re-exported): the
+closed list of `fedtpu.<phase>` named scopes inside the round program
+and the reduction of a `--profile-dir` window to device seconds by
+phase.
 """
 
 from federated_pytorch_test_tpu.obs.benchdb import (

@@ -1,10 +1,14 @@
 """Host-side tracing: Chrome trace-event export + dispatch/recompile counts.
 
-`jax.profiler.trace` (config.profile_dir) captures device timelines but
-needs TensorBoard tooling and profiles *programs*, not the trainer's loop
-nest. `TraceRecorder` is the complementary host-side view: every
-round/epoch/consensus/compile region the trainer enters becomes one
-span in a Chrome trace-event JSON. Evals appear as a SPLIT pair —
+`jax.profiler.trace` (config.profile_dir: one window per round of the
+run's second loop) captures device timelines but needs TensorBoard
+tooling to look at. `TraceRecorder` is the host-side view that needs
+none: every round/epoch/consensus/compile region the trainer enters
+becomes one span in a Chrome trace-event JSON. The same regions are
+ALSO on the profiler's clock: `MetricsRecorder.phase` opens a
+`jax.profiler.TraceAnnotation("fedtpu:<phase>")` beside each span, so a
+device trace shows what the host was doing in its idle gaps
+(docs/OBSERVABILITY.md §Trace export). Evals appear as a SPLIT pair —
 `eval_enqueue` (the async program dispatch, inside its round's span) and
 `eval_harvest` (the deferred device->host fetch at the round-boundary
 flush, after the round span) — or not at all when they are folded into

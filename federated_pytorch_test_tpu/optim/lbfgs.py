@@ -43,6 +43,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from federated_pytorch_test_tpu.obs.phases import scope, scoped
 from federated_pytorch_test_tpu.optim.compact import compact_direction
 from federated_pytorch_test_tpu.optim.linesearch import (
     backtracking_armijo_aux,
@@ -334,7 +335,11 @@ def lbfgs_step(
     lr = jnp.asarray(config.lr, x.dtype)
 
     loss_fn_aux = loss_fn if has_aux else (lambda xx: (loss_fn(xx), ()))
-    value_and_grad = jax.value_and_grad(loss_fn_aux, has_aux=True)
+    # a phase scope (obs/phases.py PHASES) is HLO metadata: it names the
+    # ops in a profiler trace and computes nothing
+    value_and_grad = scoped(
+        "fedtpu.grad_eval", jax.value_and_grad(loss_fn_aux, has_aux=True)
+    )
     (loss0, aux0), g0 = value_and_grad(x)
     abs_grad_sum0 = jnp.sum(jnp.abs(g0))
     # Frozen at entry for both the loop guard and alphabar (see module
@@ -404,9 +409,11 @@ def lbfgs_step(
                 sh, yh, cnt = args
                 return _push_history(sh, yh, cnt, s, y)
 
-            s_hist, y_hist, hist_count = lax.cond(
-                accept, push, lambda a: a, (c.s_hist, c.y_hist, c.hist_count)
-            )
+            with scope("fedtpu.history"):
+                s_hist, y_hist, hist_count = lax.cond(
+                    accept, push, lambda a: a,
+                    (c.s_hist, c.y_hist, c.hist_count),
+                )
             yy = jnp.dot(y, y)
             h_new = jnp.where(yy != 0.0, ys / jnp.where(yy != 0.0, yy, 1.0), c.h_diag)
             h_diag = jnp.where(accept, h_new, c.h_diag)
@@ -429,9 +436,13 @@ def lbfgs_step(
                 ravgsq + vzero,
             )
 
-        (d, s_hist, y_hist, hist_count, h_diag, alphabar, ravg, ravgsq) = lax.cond(
-            first_ever, fresh_direction, update_direction, c
-        )
+        # the client vmap turns this cond into selects over both branches'
+        # outputs: scoping the call puts them under `direction` too
+        with scope("fedtpu.direction"):
+            (d, s_hist, y_hist, hist_count, h_diag, alphabar, ravg,
+             ravgsq) = lax.cond(
+                first_ever, fresh_direction, update_direction, c
+            )
 
         prev_grad = c.g
         prev_loss = c.loss
@@ -452,35 +463,36 @@ def lbfgs_step(
             def phi_aux(alpha):
                 return loss_fn_aux(x_cur + alpha * d)
 
-            if config.batch_mode:
-                # static dispatch on the fan width: ls_probes == 1 keeps
-                # the UNCHANGED sequential search — the bitwise fallback —
-                # while > 1 evaluates fans of consecutive halving rungs
-                # in one widened pass (same accepted alpha, amortized
-                # parameter streaming; linesearch.py)
-                if config.ls_probes > 1:
-                    t_ls, ls_ev, aux_ls = backtracking_armijo_probes_aux(
-                        phi_aux, c.loss, gtd, alphabar,
-                        probes=config.ls_probes,
-                        fan_phi=(
-                            (lambda alphas: fan_fn(x_cur, d, alphas))
-                            if fan_fn is not None else None
-                        ),
-                    )
+            with scope("fedtpu.line_search"):
+                if config.batch_mode:
+                    # static dispatch on the fan width: ls_probes == 1 keeps
+                    # the UNCHANGED sequential search — the bitwise fallback —
+                    # while > 1 evaluates fans of consecutive halving rungs
+                    # in one widened pass (same accepted alpha, amortized
+                    # parameter streaming; linesearch.py)
+                    if config.ls_probes > 1:
+                        t_ls, ls_ev, aux_ls = backtracking_armijo_probes_aux(
+                            phi_aux, c.loss, gtd, alphabar,
+                            probes=config.ls_probes,
+                            fan_phi=(
+                                (lambda alphas: fan_fn(x_cur, d, alphas))
+                                if fan_fn is not None else None
+                            ),
+                        )
+                    else:
+                        t_ls, ls_ev, aux_ls = backtracking_armijo_aux(
+                            phi_aux, c.loss, gtd, alphabar
+                        )
+                    ls_evals = c.ls_evals + ls_ev
+                    aux_new = aux_ls
+                    # a NaN step size falls back to lr below: the point
+                    # x + lr*d was never evaluated, so the carried aux does
+                    # not belong to it (restored if the re-evaluation runs)
+                    aux_ok_new = ~jnp.isnan(t_ls)
                 else:
-                    t_ls, ls_ev, aux_ls = backtracking_armijo_aux(
-                        phi_aux, c.loss, gtd, alphabar
+                    t_ls = cubic_linesearch(
+                        lambda a: phi_aux(a)[0], c.loss, config.lr
                     )
-                ls_evals = c.ls_evals + ls_ev
-                aux_new = aux_ls
-                # a NaN step size falls back to lr below: the point
-                # x + lr*d was never evaluated, so the carried aux does
-                # not belong to it (restored if the re-evaluation runs)
-                aux_ok_new = ~jnp.isnan(t_ls)
-            else:
-                t_ls = cubic_linesearch(
-                    lambda a: phi_aux(a)[0], c.loss, config.lr
-                )
             t = jnp.where(jnp.isnan(t_ls), lr, t_ls).astype(c.x.dtype)
 
         x = c.x + t * d
@@ -590,7 +602,15 @@ def lbfgs_step(
         frozen = c.done | jnp.isnan(grad_nrm)
         return jax.tree.map(lambda n, o: jnp.where(frozen, o, n), new, c)
 
-    final = lax.while_loop(cond, masked_body, init)
+    # `carry_mask` is opened around the loop, not only around the select
+    # above: under the client vmap the batching rule of `while_loop` adds a
+    # select of its own over the WHOLE carry, both histories included
+    # (op_name `vmap()/while`), which no scope inside the body can reach.
+    # The body's few unscoped vector ops (the step x + t*d, g.d, the exit
+    # tests) land here too; every phase inside overrides it (the last
+    # scope of an op_name wins, obs/phases.py)
+    with scope("fedtpu.carry_mask"):
+        final = lax.while_loop(cond, masked_body, init)
 
     new_state = LBFGSState(
         s_hist=final.s_hist,

@@ -97,6 +97,10 @@ class MetricsRecorder:
     # value is materialized (they must ignore Deferred-valued series).
     observers: List[Any] = dataclasses.field(default_factory=list)
     tracer: Optional[Any] = None
+    # `annotate(name)` -> context manager that puts a host span of that
+    # name on the DEVICE profiler's clock (the Trainer hands over
+    # `jax.profiler.TraceAnnotation`; this module stays free of jax)
+    annotate: Optional[Callable[[str], Any]] = None
     _t0: float = dataclasses.field(default_factory=time.perf_counter)
     # streamed records not yet forwarded to the sinks: a `Deferred` value
     # holds its slot here until harvested, and every later streamed
@@ -201,14 +205,19 @@ class MetricsRecorder:
         record — the shared enter/exit point of the timing series and the
         Chrome trace (obs/trace.py). `record=False` emits the span only,
         keeping the `step_time` series exactly its pre-obs phase set
-        (epoch / consensus / fused_round / straggler_wait)."""
+        (epoch / consensus / fused_round / straggler_wait).
+
+        With `annotate` set the phase is also a host event named
+        `fedtpu:<phase>` in a `jax.profiler` trace, whether or not a
+        tracer is attached. The prefix keeps these events apart from the
+        trainer's explicit `StepTraceAnnotation("fused_round")`, which
+        the benchmark's trace reduction finds by exact name."""
         t0 = time.perf_counter()
-        cm = (
-            self.tracer.span(phase, **context)
-            if self.tracer is not None
-            else contextlib.nullcontext()
-        )
-        with cm:
+        with contextlib.ExitStack() as stack:
+            if self.tracer is not None:
+                stack.enter_context(self.tracer.span(phase, **context))
+            if self.annotate is not None:
+                stack.enter_context(self.annotate("fedtpu:" + phase))
             yield
         if record:
             self.step_time(phase, time.perf_counter() - t0, **context)
