@@ -51,11 +51,11 @@ UNATTRIBUTED = "unattributed"
 PHASES = (
     "batch_gather",  # step_all's take_along_axis pair (engine/steps.py)
     "grad_eval",  # lbfgs_step's value_and_grad sites: entry and reeval
-    "direction",  # lax.cond(first_ever, fresh_direction, update_direction)
-    "history",  # the accept cond around _push_history, inside direction
+    "direction",  # where(first_ever, -g, direction_fn(...)): the contractions
+    "history",  # _ring_push: the one-row gather + scatter per history
     "line_search",  # the Armijo call; its probes are forward-only
-    "carry_mask",  # the L-BFGS loop's selects over its carry: masked_body's
-    # where(frozen, old, new) and the one vmap's while_loop rule adds
+    "carry_mask",  # the L-BFGS loop's freeze select over its carry (the
+    # histories are not in it) and the body's few unscoped vector ops
     "exchange",  # the consensus body (_consensus_local)
     "eval",  # the client_eval sweep (_client_eval_fn)
     "round_tail",  # param_ok, drift

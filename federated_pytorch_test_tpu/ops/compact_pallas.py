@@ -207,12 +207,14 @@ def fused_direction_assembly(s, y, g, w, u, h_diag, count=None):
     return hg[0]
 
 
-def compact_direction_pallas(g, s_hist, y_hist, count, h_diag):
+def compact_direction_pallas(g, s_hist, y_hist, count, h_diag, oldest=0):
     """-H·g via the compact representation, history traffic fused to 2 passes.
 
     Drop-in replacement for `optim.compact.compact_direction` (same
     signature, same result up to reduction order); see that module's
-    docstring for the algebra and the masking of invalid/degenerate slots.
+    docstring for the algebra, the ring layout (`oldest`: both kernels
+    run over the rows as stored, `compact_solves` alone reorders) and the
+    masking of invalid/degenerate slots.
     """
     m = s_hist.shape[0]
     dt = g.dtype
@@ -227,7 +229,8 @@ def compact_direction_pallas(g, s_hist, y_hist, count, h_diag):
 
     valid = jnp.arange(m) < count
     u, w, _, _ = compact_solves(
-        sy, p, q, valid, h_diag.astype(f32), lambda u: (yy @ u, None)
+        sy, p, q, valid, h_diag.astype(f32), lambda u: (yy @ u, None),
+        oldest,
     )
 
     hg = fused_direction_assembly(s32, y32, g32, w, u, h_diag, count)

@@ -14,19 +14,26 @@ methods") writes the SAME inverse-Hessian product in closed form:
                            [ −R⁻¹,                 0    ]] · [Sᵀg; γ Yᵀg]
 
 with S,Y the [m,N] step/grad-difference history, R the upper triangle of
-S Yᵀ (slot-chronological), D its diagonal, and γ the initial Hessian scale
-(`h_diag`). The heavy work becomes a handful of [m,N]-shaped matmuls — all
+S Yᵀ (pairs in chronological order), D its diagonal, and γ the initial
+Hessian scale (`h_diag`). The heavy work becomes a handful of [m,N]-shaped matmuls — all
 MXU-tileable — and two m×m triangular solves that are negligible at m=10.
 The result is algebraically identical to the two-loop recursion's
 direction (equal up to floating-point roundoff — reduction order differs;
 see tests/test_lbfgs.py equivalence tests).
 
-Invalid history slots (`i >= count`, or degenerate `yᵢ·sᵢ = 0`) are masked
-by zeroing their rows and pinning the corresponding diagonal of R to 1 so
-the triangular solves stay non-singular while the slot's contribution
-vanishes exactly. That masking + solve sequence lives in `compact_solves`,
-shared with the fused Pallas backend (ops/compact_pallas.py) so the two
-backends cannot drift.
+The history is a RING (optim/lbfgs.py): pair `i`, oldest first, lives in
+row `(oldest + i) % m` of the buffers, and no `[m, N]` array is ever
+reordered. The heavy contractions do not care — they run over the rows as
+they are stored — and only `R`'s triangle does: `compact_solves` permutes
+the `[m]`/`[m, m]` contractions to chronological order, solves, and
+permutes `u`, `w` back to storage order for the `[m]×[m, N]` assembly.
+
+Invalid history slots (rows `>= count`, or degenerate `yᵢ·sᵢ = 0`) are
+masked by zeroing their rows and pinning the corresponding diagonal of R to
+1 so the triangular solves stay non-singular while the slot's contribution
+vanishes exactly. That permutation + masking + solve sequence lives in
+`compact_solves`, shared with the fused Pallas backend
+(ops/compact_pallas.py) so the two backends cannot drift.
 """
 
 from __future__ import annotations
@@ -50,6 +57,7 @@ def compact_solves(
     valid: jnp.ndarray,
     h_diag: jnp.ndarray,
     yyu: Callable[[jnp.ndarray], Tuple[jnp.ndarray, object]],
+    oldest: jnp.ndarray | int = 0,
 ):
     """The middle section shared by both compact backends.
 
@@ -60,10 +68,22 @@ def compact_solves(
     contracts it as `Y (u @ Y)` reusing `uy` as aux; the Pallas backend
     has the m×m `Y Yᵀ` from its fused pass and uses `yy @ u`.
 
+    Everything handed in and out — `sy`, `p`, `q`, `valid`, `yyu`'s
+    argument and result, `u`, `w`, `ok` — is in STORAGE order, the ring's
+    rows as they lie; `oldest` is the row of the oldest pair. This is the
+    one place chronological order exists: `R` is the upper triangle of
+    `S Yᵀ` with the pairs oldest first, so the contractions are permuted
+    with `(oldest + arange(m)) % m` on the way in and `u`, `w` back on the
+    way out. `[m]`- and `[m, m]`-sized gathers: no history row moves.
+
     Returns `(u, w, ok, aux)` with `u = R⁻¹Sᵀg`,
     `w = R⁻ᵀ((D + γ YᵀY)u − γ Yᵀg)`, both exactly zero at non-`ok` slots.
     """
     dt = sy.dtype
+    m = sy.shape[0]
+    chron = (oldest + jnp.arange(m)) % m  # chronological i -> ring row
+    store = (jnp.arange(m) - oldest) % m  # ring row -> chronological i
+    sy, p, q, valid = sy[chron][:, chron], p[chron], q[chron], valid[chron]
     d_diag = jnp.diagonal(sy)
     # guard: treat slots with degenerate curvature as invalid too
     ok = valid & (d_diag != 0.0)
@@ -81,12 +101,13 @@ def compact_solves(
 
     u = solve_triangular(r, p, lower=False)  # R⁻¹ Sᵀg
     u = jnp.where(ok, u, 0.0)
-    yyu_vec, aux = yyu(u)
+    yyu_vec, aux = yyu(u[store])
     w = solve_triangular(
-        r, d_diag * u + h_diag * yyu_vec - h_diag * q, lower=False, trans=1
+        r, d_diag * u + h_diag * yyu_vec[chron] - h_diag * q,
+        lower=False, trans=1,
     )  # R⁻ᵀ((D + γ YᵀY) u − γ Yᵀg)
     w = jnp.where(ok, w, 0.0)
-    return u, w, ok, aux
+    return u[store], w[store], ok[store], aux
 
 
 def compact_direction(
@@ -95,12 +116,14 @@ def compact_direction(
     y_hist: jnp.ndarray,
     count: jnp.ndarray,
     h_diag: jnp.ndarray,
+    oldest: jnp.ndarray | int = 0,
 ) -> jnp.ndarray:
     """-H·g via the compact representation over the valid history slots.
 
     Drop-in replacement for `lbfgs._two_loop_direction` (same signature,
-    same result); `s_hist`/`y_hist` are [m, N] chronological buffers of
-    which the first `count` rows are valid.
+    same result); `s_hist`/`y_hist` are the [m, N] ring: rows `< count`
+    are valid, pair `i` (oldest first) is row `(oldest + i) % m`. With
+    `oldest = 0` that is a plain chronological buffer.
     """
     m = s_hist.shape[0]
 
@@ -120,7 +143,7 @@ def compact_direction(
         uy = jnp.matmul(u, y, precision=_HI)  # [N]
         return jnp.matmul(y, uy, precision=_HI), uy
 
-    u, w, _, uy = compact_solves(sy, p, q, valid, h_diag, yyu)
+    u, w, _, uy = compact_solves(sy, p, q, valid, h_diag, yyu, oldest)
 
     hg = h_diag * g + jnp.matmul(w, s, precision=_HI) - h_diag * uy
     return -hg

@@ -8,13 +8,23 @@ Capability parity with the reference's `LBFGSNew` optimizer
   (reference src/lbfgsnew.py:485-743). Here the optimizer is a pure
   transform `lbfgs_step(loss_fn, x, state) -> (x', state', aux)` over a
   flat parameter vector: the bounded inner iteration is a
-  `lax.while_loop`, the two-loop recursion runs over fixed-size circular
-  history buffers, and every line-search probe's forward pass is traced
-  into the same XLA program — one device computation per optimizer step,
-  no host round-trips.
-* History is a pair of `[m, N]` buffers + a count instead of Python lists
-  (reference src/lbfgsnew.py:598-605 uses `list.pop(0)/append`); invalid
-  slots are masked inside the recursion so shapes stay static.
+  `lax.while_loop`, the direction runs over fixed-size circular history
+  buffers, and every line-search probe's forward pass is traced into the
+  same XLA program — one device computation per optimizer step, no host
+  round-trips.
+* History is a RING: a pair of `[m, N]` buffers, a count and the row of
+  the oldest pair, instead of Python lists (reference
+  src/lbfgsnew.py:598-605 uses `list.pop(0)/append`). A push writes ONE
+  row — the next free one, or the oldest pair's once the ring is full —
+  and leaves the other rows where they are (`_ring_push`); a reset is
+  `count = 0`. No `[m, N]` array is ever shifted, gathered or selected
+  whole: the loop writes the buffers in place, and the only passes over
+  them are the direction's contractions. Chronological order, which the
+  recursion and the compact form's triangular `R` need, is restored
+  where it is cheap: the two-loop recursion walks rows
+  `(oldest + i) % m`, the compact backends permute their `[m]`/`[m, m]`
+  contractions (`compact.compact_solves`). Rows `>= count` are invalid
+  and masked by select, so shapes stay static and a stale row cannot leak.
 * All of the reference's stochastic-mode machinery is preserved:
   trust-region damping `y += lm0 * s` (reference src/lbfgsnew.py:572-573),
   the online inter-batch gradient mean/variance estimate feeding the
@@ -54,12 +64,12 @@ from federated_pytorch_test_tpu.optim.linesearch import (
 )
 
 
-def _pallas_direction(g, s_hist, y_hist, count, h_diag):
+def _pallas_direction(g, s_hist, y_hist, count, h_diag, oldest):
     # lazy import: pay the jax.experimental.pallas import cost only when
     # the 'pallas' backend is actually selected
     from federated_pytorch_test_tpu.ops import compact_direction_pallas
 
-    return compact_direction_pallas(g, s_hist, y_hist, count, h_diag)
+    return compact_direction_pallas(g, s_hist, y_hist, count, h_diag, oldest)
 
 LossFn = Callable[[jnp.ndarray], jnp.ndarray]  # flat params -> scalar loss
 
@@ -120,9 +130,12 @@ class LBFGSState(NamedTuple):
     """Persistent optimizer state (the reference's `self.state` dict,
     src/lbfgsnew.py:727-740), as fixed-shape arrays."""
 
-    s_hist: jnp.ndarray  # [m, N] past steps s_k = t * d
-    y_hist: jnp.ndarray  # [m, N] past (damped) gradient differences
-    hist_count: jnp.ndarray  # i32, number of valid (s, y) pairs
+    s_hist: jnp.ndarray  # [m, N] ring of past steps s_k = t * d
+    y_hist: jnp.ndarray  # [m, N] ring of past (damped) gradient differences
+    hist_count: jnp.ndarray  # i32, valid (s, y) pairs: rows [0, count)
+    # i32, the ring row holding the OLDEST pair; pair i (oldest first)
+    # lives in row (hist_oldest + i) % m. 0 until the ring is full
+    hist_oldest: jnp.ndarray
     h_diag: jnp.ndarray  # f32, initial inverse-Hessian scale
     d: jnp.ndarray  # [N] last search direction
     t: jnp.ndarray  # f32, last step size
@@ -186,6 +199,7 @@ def lbfgs_init(x0: jnp.ndarray, config: LBFGSConfig) -> LBFGSState:
         s_hist=jnp.zeros((m, n), dt),
         y_hist=jnp.zeros((m, n), dt),
         hist_count=jnp.int32(0),
+        hist_oldest=jnp.int32(0),
         h_diag=jnp.asarray(1.0, dt),
         d=z,
         t=jnp.asarray(config.lr, dt),
@@ -205,60 +219,72 @@ def _two_loop_direction(
     y_hist: jnp.ndarray,
     count: jnp.ndarray,
     h_diag: jnp.ndarray,
+    oldest: jnp.ndarray | int = 0,
 ) -> jnp.ndarray:
-    """Masked two-loop recursion: -H·g over the valid history slots.
+    """Masked two-loop recursion: -H·g over the ring's valid pairs.
 
     Reference src/lbfgsnew.py:615-637, with the Python lists replaced by
-    `[m, N]` buffers; slots `i >= count` contribute nothing because their
-    `al`/`be` coefficients are forced to zero.
+    the `[m, N]` ring: pair `i` (oldest first) is row `(oldest + i) % m`.
+    Pairs `i >= count` (and degenerate ones, `y·s = 0`) are skipped by
+    SELECT, never by a zero coefficient: whatever such a row holds —
+    a stale pair after a reset, a NaN — cannot reach the direction.
     """
     m = s_hist.shape[0]
+    rows = (oldest + jnp.arange(m)) % m  # chronological -> ring row
 
-    ys_all = jnp.einsum("in,in->i", y_hist, s_hist)  # y_i . s_i per slot
-    valid = jnp.arange(m) < count
-    # safe reciprocal: invalid or degenerate slots get rho = 0
-    ro = jnp.where(valid & (ys_all != 0.0), 1.0 / jnp.where(ys_all != 0.0, ys_all, 1.0), 0.0)
+    ys_all = jnp.einsum("in,in->i", y_hist, s_hist)[rows]  # y_i . s_i
+    ok = (jnp.arange(m) < count) & (ys_all != 0.0)
+    ro = 1.0 / jnp.where(ok, ys_all, 1.0)
 
     def backward(i_rev, carry):
         q, al = carry
         i = m - 1 - i_rev
-        a = jnp.dot(s_hist[i], q) * ro[i]
-        q = q - a * y_hist[i]
+        a = jnp.dot(s_hist[rows[i]], q) * ro[i]
+        q = jnp.where(ok[i], q - a * y_hist[rows[i]], q)
         return q, al.at[i].set(a)
 
     q0 = -g
     q, al = lax.fori_loop(0, m, backward, (q0, jnp.zeros((m,), g.dtype)))
 
     def forward(i, r):
-        b = jnp.dot(y_hist[i], r) * ro[i]
-        return r + (al[i] - b) * s_hist[i]
+        b = jnp.dot(y_hist[rows[i]], r) * ro[i]
+        return jnp.where(ok[i], r + (al[i] - b) * s_hist[rows[i]], r)
 
     r = q * h_diag
     return lax.fori_loop(0, m, forward, r)
 
 
-def _push_history(
+def _ring_push(
     s_hist: jnp.ndarray,
     y_hist: jnp.ndarray,
     count: jnp.ndarray,
+    oldest: jnp.ndarray,
     s: jnp.ndarray,
     y: jnp.ndarray,
-) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
-    """Append (s, y), evicting the oldest pair when full.
+    push: jnp.ndarray,
+) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray, jnp.ndarray]:
+    """Where `push` holds, append (s, y), evicting the oldest pair when full.
 
-    Reference src/lbfgsnew.py:598-605 (`pop(0)` + `append`); here a roll
-    keeps slots in chronological order so the recursion's masked loops
-    stay index-ordered.
+    Reference src/lbfgsnew.py:598-605 (`pop(0)` + `append`) on the ring:
+    the pair goes to the next free row, which once the ring is full is
+    the oldest pair's, and `oldest` moves on. The decision is taken in
+    the row INDEX: a pair that is not pushed is addressed to row `m`,
+    out of bounds, and a scatter drops such an update. So the buffers are
+    only ever touched one row at a time, written in place and never read:
+    one scatter of a row, under the client `vmap` of K rows. A `lax.cond`
+    or a `where` over the buffers would make `vmap` (per-client
+    predicate) read and rewrite both of them whole.
     """
     m = s_hist.shape[0]
+    row = jnp.where(push, (oldest + count) % m, m)  # == oldest when full
     full = count == m
-    s_hist = jnp.where(full, jnp.roll(s_hist, -1, axis=0), s_hist)
-    y_hist = jnp.where(full, jnp.roll(y_hist, -1, axis=0), y_hist)
-    idx = jnp.where(full, m - 1, count).astype(jnp.int32)
-    zero = jnp.zeros((), jnp.int32)
-    s_hist = lax.dynamic_update_slice(s_hist, s[None], (idx, zero))
-    y_hist = lax.dynamic_update_slice(y_hist, y[None], (idx, zero))
-    return s_hist, y_hist, jnp.minimum(count + 1, m)
+    step = push.astype(count.dtype)
+    return (
+        s_hist.at[row].set(s, mode="drop"),
+        y_hist.at[row].set(y, mode="drop"),
+        jnp.where(full, count, count + step),
+        jnp.where(full, (oldest + step) % m, oldest),
+    )
 
 
 class _Carry(NamedTuple):
@@ -271,6 +297,7 @@ class _Carry(NamedTuple):
     s_hist: jnp.ndarray
     y_hist: jnp.ndarray
     hist_count: jnp.ndarray
+    hist_oldest: jnp.ndarray
     h_diag: jnp.ndarray
     prev_grad: jnp.ndarray
     prev_loss: jnp.ndarray
@@ -284,6 +311,46 @@ class _Carry(NamedTuple):
     aux: Any  # user aux of the last evaluation at the carry's x
     aux_ok: jnp.ndarray  # False while x was produced by the NaN fallback
     ls_evals: jnp.ndarray  # i32, Armijo probe evaluations this step
+    # the loop's predicate: `_any_client` of the clients' `active`
+    go: jnp.ndarray
+
+
+def _match_vma(x, ref):
+    """`x` typed as varying over every mesh axis `ref` varies over.
+
+    A cast, not arithmetic: under `shard_map`'s vma checking a loop carry
+    must enter with the type its body produces, and `+ vma_zero(ref)`
+    buys that with a pass over `x` — for the histories 2·m·N floats at
+    every step. `pcast` moves no data, and is not even emitted where `x`
+    already varies like `ref` (the engine's state does: it enters the
+    round program sharded over the client axis).
+    """
+    missing = jax.typeof(ref).vma - jax.typeof(x).vma
+    return lax.pcast(x, tuple(missing), to="varying") if missing else x
+
+
+@jax.custom_batching.custom_vmap
+def _any_client(flag):
+    """Whether the L-BFGS loop goes on: `flag`, the client's own `active`.
+
+    Under `jax.vmap` (the engine maps `lbfgs_step` over each device's
+    block of clients) its batching rule below reduces instead: ONE
+    unbatched flag for the block, true while ANY client is active. A
+    `while_loop` whose predicate is batched is lowered with a select of
+    the WHOLE carry on the per-client predicate, both histories included,
+    at every iteration; on this flag the loop is an ordinary one, runs as
+    long as it would have, and `body` freezes the clients that are done.
+    (`lax.pmax` over a named vmap axis gives the same flag but cannot be
+    traced inside `shard_map(check_vma=True)` on jax 0.9.0: it asks
+    `pvary` for an axis that is no mesh axis.)
+    """
+    return flag
+
+
+@_any_client.def_vmap
+def _any_client_vmap(axis_size, in_batched, flag):
+    del axis_size, in_batched
+    return jnp.any(flag), False
 
 
 def lbfgs_step(
@@ -346,102 +413,95 @@ def lbfgs_step(
     # docstring on reproduced quirks).
     grad_nrm = jnp.linalg.norm(g0)
 
+    def active(n_inner, done):
+        return (n_inner < config.max_iter) & (~done) & (~jnp.isnan(grad_nrm))
+
     def cond(c: _Carry):
-        return (c.n_inner < config.max_iter) & (~c.done) & (~jnp.isnan(grad_nrm))
+        return c.go
+
+    direction_fn = {
+        "compact": compact_direction,
+        "two_loop": _two_loop_direction,
+        "pallas": _pallas_direction,
+    }[config.direction]
 
     def body(c: _Carry):
+        # vmap-safety: under `jax.vmap` the body runs for every client
+        # while ANY client is active; a client that already terminated
+        # must keep its carry frozen or its params would take extra
+        # L-BFGS iterations its siblings are still running. The NaN
+        # clause mirrors the loop guard: a client entering with a NaN
+        # gradient must keep its params untouched (reference
+        # src/lbfgsnew.py:541-542), not absorb a NaN step from the
+        # batched body. Applied at the end of the body to everything but
+        # the histories, which take it in their row write.
+        frozen = c.done | jnp.isnan(grad_nrm)
+
         n_inner = c.n_inner + 1
         n_global = c.n_global + 1
+        # reference src/lbfgsnew.py:550-557: the first iteration ever
+        # takes steepest descent and resets history and running
+        # statistics. A reset of the ring is count = 0: the rows stay
         first_ever = n_global == 1
 
-        # a varying scalar zero (the gradient is always varying under
-        # shard_map): added to scalar cond outputs below so both branches
-        # produce identical varying-mesh-axis types under vma checking,
-        # with any axis name (this module is mesh-agnostic and cannot
-        # pvary by name) — see linesearch.vma_zero
-        vzero = vma_zero(c.g[0])
+        y = c.g - c.prev_grad
+        s = c.d * c.t
+        if config.batch_mode:
+            y = y + config.lm0 * s  # trust-region damping
+        ys = jnp.dot(y, s)
+        ss = jnp.dot(s, s)
 
-        def fresh_direction(c: _Carry):
-            # reference src/lbfgsnew.py:550-557: steepest descent, reset
-            # history and running statistics.
-            return (
-                -c.g,
-                jnp.zeros_like(c.s_hist) + vzero,
-                jnp.zeros_like(c.y_hist) + vzero,
-                jnp.int32(0) + vzero.astype(jnp.int32),
-                jnp.asarray(1.0, c.x.dtype) + vzero,
-                c.alphabar + vzero,
-                jnp.zeros_like(c.running_avg) + vzero,
-                jnp.zeros_like(c.running_avg_sq) + vzero,
+        if config.batch_mode:
+            # First inner iteration of a new step = new mini-batch:
+            # update the inter-batch gradient statistics instead of the
+            # curvature history (reference src/lbfgsnew.py:578-591).
+            batch_changed = (n_inner == 1) & (n_global > 1)
+            g_minus_old = c.g - c.running_avg
+            ravg_new = c.running_avg + g_minus_old / n_global.astype(c.x.dtype)
+            ravgsq_new = c.running_avg_sq + (c.g - ravg_new) * g_minus_old
+            ravg = jnp.where(batch_changed, ravg_new, c.running_avg)
+            ravgsq = jnp.where(batch_changed, ravgsq_new, c.running_avg_sq)
+            var_term = jnp.sum(ravgsq) / (
+                (n_global - 1).astype(c.x.dtype) * grad_nrm
             )
-
-        def update_direction(c: _Carry):
-            y = c.g - c.prev_grad
-            s = c.d * c.t
-            if config.batch_mode:
-                y = y + config.lm0 * s  # trust-region damping
-            ys = jnp.dot(y, s)
-            ss = jnp.dot(s, s)
-
-            if config.batch_mode:
-                # First inner iteration of a new step = new mini-batch:
-                # update the inter-batch gradient statistics instead of the
-                # curvature history (reference src/lbfgsnew.py:578-591).
-                batch_changed = (n_inner == 1) & (n_global > 1)
-                g_minus_old = c.g - c.running_avg
-                ravg_new = c.running_avg + g_minus_old / n_global.astype(c.x.dtype)
-                ravgsq_new = c.running_avg_sq + (c.g - ravg_new) * g_minus_old
-                ravg = jnp.where(batch_changed, ravg_new, c.running_avg)
-                ravgsq = jnp.where(batch_changed, ravgsq_new, c.running_avg_sq)
-                var_term = jnp.sum(ravgsq) / (
-                    (n_global - 1).astype(c.x.dtype) * grad_nrm
-                )
-                alphabar = jnp.where(
-                    batch_changed, 1.0 / (1.0 + var_term), c.alphabar
-                )
-            else:
-                batch_changed = jnp.bool_(False)
-                ravg, ravgsq, alphabar = c.running_avg, c.running_avg_sq, c.alphabar
-
-            accept = (ys > 1e-10 * ss) & (~batch_changed)
-
-            def push(args):
-                sh, yh, cnt = args
-                return _push_history(sh, yh, cnt, s, y)
-
-            with scope("fedtpu.history"):
-                s_hist, y_hist, hist_count = lax.cond(
-                    accept, push, lambda a: a,
-                    (c.s_hist, c.y_hist, c.hist_count),
-                )
-            yy = jnp.dot(y, y)
-            h_new = jnp.where(yy != 0.0, ys / jnp.where(yy != 0.0, yy, 1.0), c.h_diag)
-            h_diag = jnp.where(accept, h_new, c.h_diag)
-            # NaN H_diag is carried through with only a warning in the
-            # reference (src/lbfgsnew.py:610-611); same here implicitly.
-            direction_fn = {
-                "compact": compact_direction,
-                "two_loop": _two_loop_direction,
-                "pallas": _pallas_direction,
-            }[config.direction]
-            d = direction_fn(c.g, s_hist, y_hist, hist_count, h_diag)
-            return (
-                d,
-                s_hist + vzero,
-                y_hist + vzero,
-                hist_count + vzero.astype(jnp.int32),
-                h_diag + vzero,
-                alphabar + vzero,
-                ravg + vzero,
-                ravgsq + vzero,
+            alphabar = jnp.where(
+                batch_changed, 1.0 / (1.0 + var_term), c.alphabar
             )
+        else:
+            batch_changed = jnp.bool_(False)
+            ravg, ravgsq, alphabar = c.running_avg, c.running_avg_sq, c.alphabar
+        ravg = jnp.where(first_ever, jnp.zeros_like(ravg), ravg)
+        ravgsq = jnp.where(first_ever, jnp.zeros_like(ravgsq), ravgsq)
 
-        # the client vmap turns this cond into selects over both branches'
-        # outputs: scoping the call puts them under `direction` too
+        accept = (ys > 1e-10 * ss) & (~batch_changed) & (~first_ever)
+
+        with scope("fedtpu.history"):
+            s_hist, y_hist, hist_count, hist_oldest = _ring_push(
+                c.s_hist, c.y_hist,
+                jnp.where(first_ever, 0, c.hist_count),
+                jnp.where(first_ever, 0, c.hist_oldest),
+                s, y, accept & ~frozen,
+            )
+        yy = jnp.dot(y, y)
+        h_new = jnp.where(yy != 0.0, ys / jnp.where(yy != 0.0, yy, 1.0), c.h_diag)
+        # NaN H_diag is carried through with only a warning in the
+        # reference (src/lbfgsnew.py:610-611); same here implicitly.
+        h_diag = jnp.where(
+            first_ever,
+            jnp.ones_like(c.h_diag),
+            jnp.where(accept, h_new, c.h_diag),
+        )
+
+        # a select, not a `lax.cond` with the histories among its
+        # operands: under the client vmap a cond on a per-client
+        # predicate selects over every operand and result
         with scope("fedtpu.direction"):
-            (d, s_hist, y_hist, hist_count, h_diag, alphabar, ravg,
-             ravgsq) = lax.cond(
-                first_ever, fresh_direction, update_direction, c
+            d = jnp.where(
+                first_ever,
+                -c.g,
+                direction_fn(
+                    c.g, s_hist, y_hist, hist_count, h_diag, hist_oldest
+                ),
             )
 
         prev_grad = c.g
@@ -529,16 +589,22 @@ def lbfgs_step(
             | (jnp.abs(loss - prev_loss) < tol_change)
         )
 
-        return _Carry(
+        # The freeze goes over everything but the histories and `go`
+        # (None: no leaf). The histories froze in `_ring_push` — a frozen
+        # client's row was rewritten with itself — where a select here
+        # would read and rewrite both whole, every iteration. `go` is
+        # the block's, not the client's: a frozen client must see it fall
+        new = _Carry(
             x=x,
             loss=loss,
             g=g,
             abs_grad_sum=abs_grad_sum,
             d=d,
             t=t,
-            s_hist=s_hist,
-            y_hist=y_hist,
+            s_hist=None,
+            y_hist=None,
             hist_count=hist_count,
+            hist_oldest=hist_oldest,
             h_diag=h_diag,
             prev_grad=prev_grad,
             prev_loss=prev_loss,
@@ -552,6 +618,17 @@ def lbfgs_step(
             aux=aux_new,
             aux_ok=aux_ok_new,
             ls_evals=ls_evals,
+            go=None,
+        )
+        kept = jax.tree.map(
+            lambda n, o: jnp.where(frozen, o, n),
+            new,
+            c._replace(s_hist=None, y_hist=None, go=None),
+        )
+        return kept._replace(
+            s_hist=s_hist,
+            y_hist=y_hist,
+            go=_any_client(active(kept.n_inner, kept.done)),
         )
 
     # Exact zeros carrying the loss's varying-mesh-axis type. Under
@@ -559,9 +636,13 @@ def lbfgs_step(
     # the vma its body produces; `state` may arrive as unvarying constants
     # (lbfgs_init) while the body mixes in the (always-varying) loss and
     # gradient. Seeding every field costs nothing numerically — see
-    # linesearch.vma_zero on the inf/NaN safety.
+    # linesearch.vma_zero on the inf/NaN safety. The histories are CAST
+    # instead (`_match_vma`): they enter the loop as the buffers the
+    # previous step left, with no pass over them.
     vz = vma_zero(loss0)
     iz = vz.astype(jnp.int32)
+    done0 = abs_grad_sum0 <= tol_grad
+    n_inner0 = jnp.int32(0) + iz
     init = _Carry(
         x=x,
         loss=loss0,
@@ -569,53 +650,40 @@ def lbfgs_step(
         abs_grad_sum=abs_grad_sum0,
         d=state.d + vz,
         t=state.t + vz,
-        s_hist=state.s_hist + vz,
-        y_hist=state.y_hist + vz,
+        s_hist=_match_vma(state.s_hist, loss0),
+        y_hist=_match_vma(state.y_hist, loss0),
         hist_count=state.hist_count + iz,
+        hist_oldest=state.hist_oldest + iz,
         h_diag=state.h_diag + vz,
         prev_grad=state.prev_grad + vz,
         prev_loss=state.prev_loss + vz,
         n_global=state.n_iter + iz,
         evals=jnp.int32(1) + iz,
-        n_inner=jnp.int32(0) + iz,
+        n_inner=n_inner0,
         alphabar=lr + vz,
         running_avg=state.running_avg + vz,
         running_avg_sq=state.running_avg_sq + vz,
-        done=abs_grad_sum0 <= tol_grad,
+        done=done0,
         # entry evaluation is at x: if no iteration runs, final x == x
         # and aux0 is exactly its aux
         aux=aux0,
         aux_ok=vz == 0,
         ls_evals=jnp.int32(0) + iz,
+        go=_any_client(active(n_inner0, done0)),
     )
 
-    def masked_body(c: _Carry) -> _Carry:
-        # vmap-safety: under `jax.vmap` the while body runs for every
-        # client while ANY client's condition holds; a client that already
-        # terminated must keep its carry frozen or its params would take
-        # extra L-BFGS iterations its siblings are still running. The NaN
-        # clause mirrors the loop guard: a client entering with a NaN
-        # gradient must keep its params untouched (reference
-        # src/lbfgsnew.py:541-542), not absorb a NaN step from the batched
-        # body.
-        new = body(c)
-        frozen = c.done | jnp.isnan(grad_nrm)
-        return jax.tree.map(lambda n, o: jnp.where(frozen, o, n), new, c)
-
-    # `carry_mask` is opened around the loop, not only around the select
-    # above: under the client vmap the batching rule of `while_loop` adds a
-    # select of its own over the WHOLE carry, both histories included
-    # (op_name `vmap()/while`), which no scope inside the body can reach.
-    # The body's few unscoped vector ops (the step x + t*d, g.d, the exit
-    # tests) land here too; every phase inside overrides it (the last
-    # scope of an op_name wins, obs/phases.py)
+    # `carry_mask` is opened around the loop: the freeze select at the
+    # body's end and the body's few unscoped vector ops (the step
+    # x + t*d, g.d, the exit tests) land here; every phase inside
+    # overrides it (the last scope of an op_name wins, obs/phases.py)
     with scope("fedtpu.carry_mask"):
-        final = lax.while_loop(cond, masked_body, init)
+        final = lax.while_loop(cond, body, init)
 
     new_state = LBFGSState(
         s_hist=final.s_hist,
         y_hist=final.y_hist,
         hist_count=final.hist_count,
+        hist_oldest=final.hist_oldest,
         h_diag=final.h_diag,
         d=final.d,
         t=final.t,

@@ -134,25 +134,45 @@ def test_nan_client_isolated_under_vmap():
     # One client with a NaN loss must come out of a vmapped step with its
     # params untouched while healthy siblings still optimize (the batched
     # while body runs for everyone; the NaN client's carry must be frozen).
+    # Its history ring is frozen too — rows, count and slot, bit for bit —
+    # by the row write itself (`_ring_push`'s `push` flag), while the
+    # sibling pushes: the step runs twice so the second one enters with
+    # pairs in the ring and `n_iter > 0` (no first-ever reset).
     loss_good, _ = _quadratic(n=6, seed=9)
     cfg = LBFGSConfig(max_iter=4, history_size=3, line_search=True)
     switches = jnp.asarray([0.0, 1.0], jnp.float32)  # 1.0 => NaN loss
 
-    def one(x, sw):
+    def one(x, sw, state):
         def loss(xx):
             return jnp.where(sw > 0.5, jnp.nan, 1.0) * loss_good(xx)
 
-        state = lbfgs_init(x, cfg)
-        x1, _, aux = lbfgs_step(loss, x, state, cfg)
-        return x1, aux.n_inner
+        x1, state1, aux = lbfgs_step(loss, x, state, cfg)
+        return x1, state1, aux.n_inner
 
     x0 = jnp.ones((2, 6), jnp.float32)
-    x1, n_inner = jax.vmap(one)(x0, switches)
+    state0 = jax.vmap(lambda x: lbfgs_init(x, cfg))(x0)
+    x1, state1, n_inner = jax.vmap(one)(x0, switches, state0)
     np.testing.assert_array_equal(np.asarray(x1[1]), np.asarray(x0[1]))
     assert int(n_inner[1]) == 0
     # the healthy client actually moved
     assert float(jnp.linalg.norm(x1[0] - x0[0])) > 1e-3
     assert np.isfinite(np.asarray(x1[0])).all()
+
+    # both healthy for one step (fills client 1's ring), then client 1
+    # turns NaN: its ring must come out as it went in
+    _, filled, _ = jax.vmap(one)(x0, jnp.zeros_like(switches), state0)
+    assert int(filled.hist_count[1]) > 0
+    x2, state2, n_inner = jax.vmap(one)(x1, switches, filled)
+    for field in ("s_hist", "y_hist", "hist_count", "hist_oldest"):
+        np.testing.assert_array_equal(
+            np.asarray(getattr(state2, field)[1]),
+            np.asarray(getattr(filled, field)[1]),
+            err_msg=field,
+        )
+    assert int(n_inner[1]) == 0
+    assert not np.array_equal(
+        np.asarray(state2.s_hist[0]), np.asarray(filled.s_hist[0])
+    )
 
 
 def test_nan_gradient_leaves_params_unchanged():
