@@ -560,14 +560,18 @@ def check_compact(
 ) -> dict:
     """`compact_direction_pallas` vs `optim.compact.compact_direction`
     at m=10: the flagship's largest group and an odd N (masked tail
-    tile), plain and under `jax.vmap` over K clients — the form the
-    engine uses. Relative tolerance is tests/test_ops.py's."""
+    grid step, zero lanes in the last tile), plain and under `jax.vmap`
+    over K clients — the form the engine uses — on `[m, R, 128]`
+    histories (optim/history.py). Relative tolerance is
+    tests/test_ops.py's. Also times one vmapped call of each backend at
+    each size, warm (`ms_*`: host clock around `block_until_ready`)."""
     import jax
     import jax.numpy as jnp
     import numpy as np
 
     from federated_pytorch_test_tpu.ops import compact_direction_pallas
     from federated_pytorch_test_tpu.optim.compact import compact_direction
+    from federated_pytorch_test_tpu.optim.history import history_of
 
     def history(key, n):
         # y ~ B s with B SPD: a well-conditioned compact form
@@ -575,7 +579,18 @@ def check_compact(
         s = 0.1 * jax.random.normal(ks, (m, n), jnp.float32)
         d = jax.random.uniform(kd, (n,), jnp.float32, 0.5, 2.0)
         y = s * d + 0.01 * jax.random.normal(kn, (m, n), jnp.float32)
-        return s, y, jax.random.normal(kg, (n,), jnp.float32)
+        return (
+            history_of(s), history_of(y),
+            jax.random.normal(kg, (n,), jnp.float32),
+        )
+
+    def ms(fn, *args, reps: int = 5) -> str:
+        jax.block_until_ready(fn(*args))
+        t0 = time.perf_counter()
+        for _ in range(reps):
+            out = fn(*args)
+        jax.block_until_ready(out)
+        return f"{(time.perf_counter() - t0) / reps * 1e3:.2f}"
 
     errs = {}
     for n in sizes:
@@ -583,12 +598,13 @@ def check_compact(
         s, y, g = jax.vmap(lambda kk: history(kk, n))(keys)
         counts = jnp.asarray([m, 4, 0][:k] + [m] * max(0, k - 3), jnp.int32)
         hd = jnp.asarray([0.7, 1.0, 2.0][:k] + [1.0] * max(0, k - 3), jnp.float32)
+        compact = jax.jit(jax.vmap(compact_direction))
+        pallas = jax.jit(jax.vmap(compact_direction_pallas))
         with jax.default_matmul_precision("highest"):
-            ref = jax.jit(jax.vmap(compact_direction))(g, s, y, counts, hd)
-        ref = np.asarray(ref)
-        pal = np.asarray(
-            jax.jit(jax.vmap(compact_direction_pallas))(g, s, y, counts, hd)
-        )
+            ref = np.asarray(compact(g, s, y, counts, hd))
+            errs[f"ms_compact_{n}"] = ms(compact, g, s, y, counts, hd)
+        pal = np.asarray(pallas(g, s, y, counts, hd))
+        errs[f"ms_pallas_{n}"] = ms(pallas, g, s, y, counts, hd)
         one = np.asarray(jax.jit(compact_direction_pallas)(
             g[0], s[0], y[0], counts[0], hd[0]
         ))

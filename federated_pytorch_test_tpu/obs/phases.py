@@ -52,7 +52,8 @@ PHASES = (
     "batch_gather",  # step_all's take_along_axis pair (engine/steps.py)
     "grad_eval",  # lbfgs_step's value_and_grad sites: entry and reeval
     "direction",  # where(first_ever, -g, direction_fn(...)): the contractions
-    "history",  # _ring_push: the one-row gather + scatter per history
+    "history",  # ring_push: a [R, 128] slab into lanes, read back and
+    # written in place, per history and client (optim/history.py)
     "line_search",  # the Armijo call; its probes are forward-only
     "carry_mask",  # the L-BFGS loop's freeze select over its carry (the
     # histories are not in it) and the body's few unscoped vector ops

@@ -3,7 +3,7 @@
 The compute path is JAX/XLA; these kernels exist where fusion beyond what
 XLA does automatically pays off on TPU — primarily the L-BFGS compact
 direction, whose history-sized matmul chain XLA schedules as ~5 HBM passes
-over the `[m, N]` buffers but a fused pair of kernels does in 2
+over the `[m, R, 128]` buffers but a fused pair of kernels does in 2
 (see `ops/compact_pallas.py`).
 
 All kernels run in interpret mode off-TPU (CPU tests / the virtual

@@ -13,20 +13,26 @@ methods") writes the SAME inverse-Hessian product in closed form:
     H g = γ g + [S  γY] · [[ R⁻ᵀ(D + γ YᵀY) R⁻¹,  −R⁻ᵀ ],
                            [ −R⁻¹,                 0    ]] · [Sᵀg; γ Yᵀg]
 
-with S,Y the [m,N] step/grad-difference history, R the upper triangle of
+with S,Y the m-pair step/grad-difference history, R the upper triangle of
 S Yᵀ (pairs in chronological order), D its diagonal, and γ the initial
-Hessian scale (`h_diag`). The heavy work becomes a handful of [m,N]-shaped matmuls — all
-MXU-tileable — and two m×m triangular solves that are negligible at m=10.
+Hessian scale (`h_diag`). The heavy work becomes a handful of contractions
+over the whole history — bound by its bytes, not by m sequential passes —
+and two m×m triangular solves that are negligible at m=10.
 The result is algebraically identical to the two-loop recursion's
 direction (equal up to floating-point roundoff — reduction order differs;
 see tests/test_lbfgs.py equivalence tests).
 
-The history is a RING (optim/lbfgs.py): pair `i`, oldest first, lives in
-row `(oldest + i) % m` of the buffers, and no `[m, N]` array is ever
-reordered. The heavy contractions do not care — they run over the rows as
-they are stored — and only `R`'s triangle does: `compact_solves` permutes
-the `[m]`/`[m, m]` contractions to chronological order, solves, and
-permutes `u`, `w` back to storage order for the `[m]×[m, N]` assembly.
+The history is a RING laid out in lanes (optim/history.py): the buffers
+are `[m, R, 128]`, pair `i`, oldest first, lives in row `(oldest + i) % m`,
+a `[R, 128]` slab of whole `(8, 128)` tiles, and no buffer is ever
+reordered or relaid. The heavy contractions run over `(R, 128)` as the
+lanes lie (`irc,rc->i`, `i,irc->rc`, and the Gram `s_i · y_j` as one
+multiply-reduce, `_gram`): `g` goes into lanes once per call and the
+direction comes back to `[N]` once. They do not care
+about the ring — they run over the rows as they are stored — and only
+`R`'s triangle does: `compact_solves` permutes the `[m]`/`[m, m]`
+contractions to chronological order, solves, and permutes `u`, `w` back
+to storage order for the assembly.
 
 Invalid history slots (rows `>= count`, or degenerate `yᵢ·sᵢ = 0`) are
 masked by zeroing their rows and pinning the corresponding diagonal of R to
@@ -41,12 +47,16 @@ from __future__ import annotations
 from typing import Callable, Tuple
 
 import jax.numpy as jnp
+from jax import lax
 from jax.lax import Precision
 from jax.scipy.linalg import solve_triangular
 
-# full-f32 MXU passes for the heavy [m, N] contractions: they are HBM-
-# bandwidth-bound, so this costs nothing and matches the Pallas backend's
-# fidelity instead of drifting with single-bf16-pass MXU defaults on TPU
+from federated_pytorch_test_tpu.optim.history import from_lanes, to_lanes
+
+# full-f32 passes for the heavy contractions over the history: they are
+# HBM-bandwidth-bound, so this costs nothing and matches the Pallas
+# backend's fidelity instead of drifting with single-bf16-pass MXU
+# defaults on TPU
 _HI = Precision.HIGHEST
 
 
@@ -110,6 +120,30 @@ def compact_solves(
     return u[store], w[store], ok[store], aux
 
 
+def _gram(s: jnp.ndarray, y: jnp.ndarray) -> jnp.ndarray:
+    """`sy[i, j] = s_i · y_j` over `[m, R, 128]` histories, in ONE pass.
+
+    A multiply-reduce, like the `[m]` contractions are once compiled: one
+    variadic `reduce` whose m² operands are the products of a slab of `s`
+    and a slab of `y`, so the m² sums come out of one fusion that streams
+    both histories once, lanes as they lie, in exact f32 products. The
+    `dot_general` `irc,jrc->ij` is answered on the TPU by a convolution
+    that relays both operands, pair index into the sublanes, inside its
+    fusion (5.3 ms for 2.27 GB against this one's 3.1; PERF.md §6, PR 30),
+    and will not take a select as its producer, so masked copies of both
+    histories were written out first.
+    """
+    m = s.shape[0]
+    prods = [s[i] * y[j] for i in range(m) for j in range(m)]
+    sums = lax.reduce(
+        prods,
+        [jnp.zeros((), s.dtype)] * len(prods),
+        lambda a, b: tuple(u + v for u, v in zip(a, b)),
+        (0, 1),
+    )
+    return jnp.stack(sums).reshape(m, m)
+
+
 def compact_direction(
     g: jnp.ndarray,
     s_hist: jnp.ndarray,
@@ -121,29 +155,33 @@ def compact_direction(
     """-H·g via the compact representation over the valid history slots.
 
     Drop-in replacement for `lbfgs._two_loop_direction` (same signature,
-    same result); `s_hist`/`y_hist` are the [m, N] ring: rows `< count`
-    are valid, pair `i` (oldest first) is row `(oldest + i) % m`. With
-    `oldest = 0` that is a plain chronological buffer.
+    same result); `s_hist`/`y_hist` are the `[m, R, 128]` ring: rows
+    `< count` are valid, pair `i` (oldest first) is row `(oldest + i) % m`.
+    With `oldest = 0` that is a plain chronological buffer.
     """
     m = s_hist.shape[0]
 
     valid = jnp.arange(m) < count
-    s = jnp.where(valid[:, None], s_hist, 0.0)
-    y = jnp.where(valid[:, None], y_hist, 0.0)
+    s = jnp.where(valid[:, None, None], s_hist, 0.0)
+    y = jnp.where(valid[:, None, None], y_hist, 0.0)
+    gl = to_lanes(g)
 
-    # the heavy contractions: [m,N] @ [N,m] / [m,N] @ [N] passes (MXU)
-    sy = jnp.matmul(s, y.T, precision=_HI)  # sy[i, j] = s_i . y_j
-    p = jnp.matmul(s, g, precision=_HI)  # Sᵀg  [m]
-    q = jnp.matmul(y, g, precision=_HI)  # Yᵀg  [m]
+    # the heavy contractions: passes over the history, lanes as they lie.
+    # The Gram reads the buffers unmasked: an invalid row taints its own
+    # row and column of `sy` and nothing else, and `compact_solves` takes
+    # those out by select
+    sy = _gram(s_hist, y_hist)
+    p = jnp.einsum("irc,rc->i", s, gl, precision=_HI)  # Sᵀg  [m]
+    q = jnp.einsum("irc,rc->i", y, gl, precision=_HI)  # Yᵀg  [m]
 
     def yyu(u):
         # (YᵀY)u contracted as Y(uᵀY): (yy @ u)[i] = y_i · Σ_j u_j y_j =
-        # (y @ uy)[i]; avoids an [m,N]@[N,m] Gram pass and `uy` is reused
-        # in the final assembly
-        uy = jnp.matmul(u, y, precision=_HI)  # [N]
-        return jnp.matmul(y, uy, precision=_HI), uy
+        # (y @ uy)[i]; avoids a second Gram pass and `uy` is reused in
+        # the final assembly
+        uy = jnp.einsum("i,irc->rc", u, y, precision=_HI)  # [R, 128]
+        return jnp.einsum("irc,rc->i", y, uy, precision=_HI), uy
 
     u, w, _, uy = compact_solves(sy, p, q, valid, h_diag, yyu, oldest)
 
-    hg = h_diag * g + jnp.matmul(w, s, precision=_HI) - h_diag * uy
-    return -hg
+    ws = jnp.einsum("i,irc->rc", w, s, precision=_HI)
+    return -from_lanes(h_diag * gl + ws - h_diag * uy, g.shape[0])

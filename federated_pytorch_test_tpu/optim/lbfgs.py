@@ -12,14 +12,17 @@ Capability parity with the reference's `LBFGSNew` optimizer
   buffers, and every line-search probe's forward pass is traced into the
   same XLA program — one device computation per optimizer step, no host
   round-trips.
-* History is a RING: a pair of `[m, N]` buffers, a count and the row of
-  the oldest pair, instead of Python lists (reference
+* History is a RING: a pair of `[m, R, 128]` buffers — every pair laid
+  out in lanes, whole `(8, 128)` tiles of parameters, `R` following from
+  `N` (optim/history.py, the one place that spells the shape) —, a count
+  and the row of the oldest pair, instead of Python lists (reference
   src/lbfgsnew.py:598-605 uses `list.pop(0)/append`). A push writes ONE
   row — the next free one, or the oldest pair's once the ring is full —
-  and leaves the other rows where they are (`_ring_push`); a reset is
-  `count = 0`. No `[m, N]` array is ever shifted, gathered or selected
-  whole: the loop writes the buffers in place, and the only passes over
-  them are the direction's contractions. Chronological order, which the
+  and leaves the other rows where they are (`history.ring_push`); a
+  reset is `count = 0`. No history is ever shifted, gathered, relaid or
+  selected whole: the loop writes the buffers in place, and the only
+  passes over them are the direction's contractions, over the lanes as
+  they lie. Chronological order, which the
   recursion and the compact form's triangular `R` need, is restored
   where it is cheap: the two-loop recursion walks rows
   `(oldest + i) % m`, the compact backends permute their `[m]`/`[m, m]`
@@ -55,6 +58,12 @@ from jax import lax
 
 from federated_pytorch_test_tpu.obs.phases import scope, scoped
 from federated_pytorch_test_tpu.optim.compact import compact_direction
+from federated_pytorch_test_tpu.optim.history import (
+    empty_history,
+    from_lanes,
+    ring_push,
+    to_lanes,
+)
 from federated_pytorch_test_tpu.optim.linesearch import (
     backtracking_armijo_aux,
     backtracking_armijo_probes_aux,
@@ -91,8 +100,9 @@ class LBFGSConfig:
     # src/lbfgsnew.py:538 `lm0=1e-6`)
     lm0: float = 1e-6
     # 'compact': Byrd–Nocedal compact representation — the same H·g as the
-    #   two-loop recursion, restructured into MXU-tileable [m,N] matmuls
-    #   (see optim/compact.py). 'two_loop': the masked sequential recursion.
+    #   two-loop recursion, restructured into a few contractions over
+    #   the whole history (see optim/compact.py). 'two_loop': the masked
+    #   sequential recursion.
     # 'pallas': the compact form with its history traffic fused into two
     #   Pallas kernels — one HBM pass for all four Gram/projection
     #   contractions, one for the direction assembly (see
@@ -130,8 +140,10 @@ class LBFGSState(NamedTuple):
     """Persistent optimizer state (the reference's `self.state` dict,
     src/lbfgsnew.py:727-740), as fixed-shape arrays."""
 
-    s_hist: jnp.ndarray  # [m, N] ring of past steps s_k = t * d
-    y_hist: jnp.ndarray  # [m, N] ring of past (damped) gradient differences
+    # [m, R, 128] rings (optim/history.py): past steps s_k = t * d, and
+    # past (damped) gradient differences
+    s_hist: jnp.ndarray
+    y_hist: jnp.ndarray
     hist_count: jnp.ndarray  # i32, valid (s, y) pairs: rows [0, count)
     # i32, the ring row holding the OLDEST pair; pair i (oldest first)
     # lives in row (hist_oldest + i) % m. 0 until the ring is full
@@ -196,8 +208,8 @@ def lbfgs_init(x0: jnp.ndarray, config: LBFGSConfig) -> LBFGSState:
     dt = x0.dtype
     z = jnp.zeros((n,), dt)
     return LBFGSState(
-        s_hist=jnp.zeros((m, n), dt),
-        y_hist=jnp.zeros((m, n), dt),
+        s_hist=empty_history(m, n, dt),
+        y_hist=empty_history(m, n, dt),
         hist_count=jnp.int32(0),
         hist_oldest=jnp.int32(0),
         h_diag=jnp.asarray(1.0, dt),
@@ -224,7 +236,9 @@ def _two_loop_direction(
     """Masked two-loop recursion: -H·g over the ring's valid pairs.
 
     Reference src/lbfgsnew.py:615-637, with the Python lists replaced by
-    the `[m, N]` ring: pair `i` (oldest first) is row `(oldest + i) % m`.
+    the `[m, R, 128]` ring: pair `i` (oldest first) is row
+    `(oldest + i) % m`, a `[R, 128]` slab; `q` and `r` run in lanes too,
+    `g` goes in once and the direction comes back to `[N]` once.
     Pairs `i >= count` (and degenerate ones, `y·s = 0`) are skipped by
     SELECT, never by a zero coefficient: whatever such a row holds —
     a stale pair after a reset, a NaN — cannot reach the direction.
@@ -232,59 +246,29 @@ def _two_loop_direction(
     m = s_hist.shape[0]
     rows = (oldest + jnp.arange(m)) % m  # chronological -> ring row
 
-    ys_all = jnp.einsum("in,in->i", y_hist, s_hist)[rows]  # y_i . s_i
+    def dot(a, b):
+        return jnp.einsum("rc,rc->", a, b)
+
+    ys_all = jnp.einsum("irc,irc->i", y_hist, s_hist)[rows]  # y_i . s_i
     ok = (jnp.arange(m) < count) & (ys_all != 0.0)
     ro = 1.0 / jnp.where(ok, ys_all, 1.0)
 
     def backward(i_rev, carry):
         q, al = carry
         i = m - 1 - i_rev
-        a = jnp.dot(s_hist[rows[i]], q) * ro[i]
+        a = dot(s_hist[rows[i]], q) * ro[i]
         q = jnp.where(ok[i], q - a * y_hist[rows[i]], q)
         return q, al.at[i].set(a)
 
-    q0 = -g
+    q0 = -to_lanes(g)
     q, al = lax.fori_loop(0, m, backward, (q0, jnp.zeros((m,), g.dtype)))
 
     def forward(i, r):
-        b = jnp.dot(y_hist[rows[i]], r) * ro[i]
+        b = dot(y_hist[rows[i]], r) * ro[i]
         return jnp.where(ok[i], r + (al[i] - b) * s_hist[rows[i]], r)
 
     r = q * h_diag
-    return lax.fori_loop(0, m, forward, r)
-
-
-def _ring_push(
-    s_hist: jnp.ndarray,
-    y_hist: jnp.ndarray,
-    count: jnp.ndarray,
-    oldest: jnp.ndarray,
-    s: jnp.ndarray,
-    y: jnp.ndarray,
-    push: jnp.ndarray,
-) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray, jnp.ndarray]:
-    """Where `push` holds, append (s, y), evicting the oldest pair when full.
-
-    Reference src/lbfgsnew.py:598-605 (`pop(0)` + `append`) on the ring:
-    the pair goes to the next free row, which once the ring is full is
-    the oldest pair's, and `oldest` moves on. The decision is taken in
-    the row INDEX: a pair that is not pushed is addressed to row `m`,
-    out of bounds, and a scatter drops such an update. So the buffers are
-    only ever touched one row at a time, written in place and never read:
-    one scatter of a row, under the client `vmap` of K rows. A `lax.cond`
-    or a `where` over the buffers would make `vmap` (per-client
-    predicate) read and rewrite both of them whole.
-    """
-    m = s_hist.shape[0]
-    row = jnp.where(push, (oldest + count) % m, m)  # == oldest when full
-    full = count == m
-    step = push.astype(count.dtype)
-    return (
-        s_hist.at[row].set(s, mode="drop"),
-        y_hist.at[row].set(y, mode="drop"),
-        jnp.where(full, count, count + step),
-        jnp.where(full, (oldest + step) % m, oldest),
-    )
+    return from_lanes(lax.fori_loop(0, m, forward, r), g.shape[0])
 
 
 class _Carry(NamedTuple):
@@ -476,7 +460,7 @@ def lbfgs_step(
         accept = (ys > 1e-10 * ss) & (~batch_changed) & (~first_ever)
 
         with scope("fedtpu.history"):
-            s_hist, y_hist, hist_count, hist_oldest = _ring_push(
+            s_hist, y_hist, hist_count, hist_oldest = ring_push(
                 c.s_hist, c.y_hist,
                 jnp.where(first_ever, 0, c.hist_count),
                 jnp.where(first_ever, 0, c.hist_oldest),
@@ -590,7 +574,7 @@ def lbfgs_step(
         )
 
         # The freeze goes over everything but the histories and `go`
-        # (None: no leaf). The histories froze in `_ring_push` — a frozen
+        # (None: no leaf). The histories froze in `ring_push` — a frozen
         # client's row was rewritten with itself — where a select here
         # would read and rewrite both whole, every iteration. `go` is
         # the block's, not the client's: a frozen client must see it fall

@@ -1,6 +1,6 @@
 """Device seconds by phase of a benchmark cell's round programs.
 
-    python3 scripts/phase_table.py --workload resnet18-admm --seed 3000000019
+    python3 scripts/phase_table.py --workload resnet18-admm-f32 --seed 3000000019
 
 Builds the cell's `ExperimentConfig` and data the way `chipbench/run.py`
 does (configuration file, traffic file, `--seed`), runs `Trainer.run()`
@@ -54,6 +54,12 @@ def main(argv=None, expect_backend: str = "tpu") -> int:
 
     import jax
 
+    # what the configuration states of the runtime (chipbench/run.py does
+    # the same): the cell's matmul precision, which nothing in the
+    # program sets, decides what the model's phases cost
+    for option, value in cell.config.get("jax_config", {}).items():
+        jax.config.update(option, value)
+
     from federated_pytorch_test_tpu.data import synthetic_cifar
     from federated_pytorch_test_tpu.engine import Trainer, get_preset
     from federated_pytorch_test_tpu.obs.phases import PHASES, UNATTRIBUTED
@@ -66,7 +72,8 @@ def main(argv=None, expect_backend: str = "tpu") -> int:
             f"phase_table needs backend {expect_backend!r}; jax found {backend!r}"
         )
     print(f"# device={jax.devices()[0].device_kind} x{jax.device_count()} "
-          f"cache={cache_dir}", flush=True)
+          f"cache={cache_dir} jax_config={cell.config.get('jax_config', {})}",
+          flush=True)
 
     prof = tempfile.mkdtemp(prefix="phase_table_")
     try:

@@ -135,7 +135,7 @@ def test_nan_client_isolated_under_vmap():
     # params untouched while healthy siblings still optimize (the batched
     # while body runs for everyone; the NaN client's carry must be frozen).
     # Its history ring is frozen too — rows, count and slot, bit for bit —
-    # by the row write itself (`_ring_push`'s `push` flag), while the
+    # by the row write itself (`ring_push`'s `push` flag), while the
     # sibling pushes: the step runs twice so the second one enters with
     # pairs in the ring and `n_iter > 0` (no first-ever reset).
     loss_good, _ = _quadratic(n=6, seed=9)
@@ -283,6 +283,7 @@ def test_compact_direction_matches_two_loop():
     # direction as the masked two-loop recursion for any history fill level:
     # empty, partial, full, and with a degenerate (zero-curvature) slot.
     from federated_pytorch_test_tpu.optim.compact import compact_direction
+    from federated_pytorch_test_tpu.optim.history import history_of
     from federated_pytorch_test_tpu.optim.lbfgs import _two_loop_direction
 
     jax.config.update("jax_enable_x64", True)
@@ -290,11 +291,13 @@ def test_compact_direction_matches_two_loop():
         rng = np.random.RandomState(11)
         m, n = 6, 20
         for count in [0, 1, 3, 6]:
-            s_hist = jnp.asarray(rng.randn(m, n))
-            y_hist = jnp.asarray(rng.randn(m, n))
+            s_rows = rng.randn(m, n)
             # make curvature products positive for valid slots, as the
             # acceptance guard guarantees (reference src/lbfgsnew.py:596)
-            y_hist = y_hist + s_hist  # biases y.s upward
+            y_rows = rng.randn(m, n) + s_rows  # biases y.s upward
+            s_hist = history_of(jnp.asarray(s_rows))
+            y_hist = history_of(jnp.asarray(y_rows))
+            assert s_hist.dtype == jnp.float64
             g = jnp.asarray(rng.randn(n))
             h_diag = jnp.asarray(0.37)
             cnt = jnp.int32(count)

@@ -1,12 +1,14 @@
-"""The L-BFGS history as a ring (optim/lbfgs.py `_ring_push`).
+"""The L-BFGS history as a ring laid out in lanes (optim/history.py).
 
-Three things are held here, in the fast tier (tests/test_lbfgs.py is the
+Four things are held here, in the fast tier (tests/test_lbfgs.py is the
 heavy one): the ring gives the direction a plain chronological list of
-pairs gives, on every backend; the program the client `vmap` lowers to
-touches the `[K, m, N]` histories inside the L-BFGS loop with nothing
-but the direction's contractions and the one-row scatter; and a
-client that is frozen keeps its ring bit for bit while the loop still
-ends as soon as every client of the block is done.
+pairs gives, on every backend; the `[m, R, 128]` layout is invisible
+from outside (any `N`, lanes past it exactly zero); the program the
+client `vmap` lowers to touches the `[K, m, R, 128]` histories inside
+the L-BFGS loop with nothing but the direction's contractions and the
+one-row scatter, and makes no new history; and a client that is frozen
+keeps its ring bit for bit while the loop still ends as soon as every
+client of the block is done.
 """
 
 import re
@@ -19,10 +21,12 @@ import pytest
 from federated_pytorch_test_tpu.ops import compact_direction_pallas
 from federated_pytorch_test_tpu.optim import LBFGSConfig, lbfgs_init, lbfgs_step
 from federated_pytorch_test_tpu.optim.compact import compact_direction
-from federated_pytorch_test_tpu.optim.lbfgs import (
-    _ring_push,
-    _two_loop_direction,
+from federated_pytorch_test_tpu.optim.history import (
+    empty_history,
+    lane_rows,
+    ring_push,
 )
+from federated_pytorch_test_tpu.optim.lbfgs import _two_loop_direction
 
 BACKENDS = {
     "compact": compact_direction,
@@ -46,10 +50,10 @@ SCENARIOS = {
 }
 
 
-def _pair(rng):
-    s = rng.normal(size=N).astype(np.float32) * 0.1
-    curv = rng.uniform(0.5, 2.0, size=N).astype(np.float32)
-    y = s * curv + 0.01 * rng.normal(size=N).astype(np.float32)
+def _pair(rng, n=N):
+    s = rng.normal(size=n).astype(np.float32) * 0.1
+    curv = rng.uniform(0.5, 2.0, size=n).astype(np.float32)
+    y = s * curv + 0.01 * rng.normal(size=n).astype(np.float32)
     return s, y
 
 
@@ -71,21 +75,21 @@ def _reference_direction(g, pairs, h_diag):
     return r
 
 
-def _drive(events, seed=0):
-    """Run `events` through `_ring_push` and through a list with
+def _drive(events, seed=0, n=N):
+    """Run `events` through `ring_push` and through a list with
     `pop(0)/append`; returns the ring's state and the list."""
     rng = np.random.default_rng(seed)
-    s_hist = jnp.zeros((M, N), jnp.float32)
-    y_hist = jnp.zeros((M, N), jnp.float32)
+    s_hist = empty_history(M, n)
+    y_hist = empty_history(M, n)
     count = oldest = jnp.int32(0)
     pairs = []
-    push = jax.jit(_ring_push)
+    push = jax.jit(ring_push)
     for ev in events:
         if ev == "reset":
             count = oldest = jnp.int32(0)
             pairs = []
             continue
-        s, y = _pair(rng)
+        s, y = _pair(rng, n)
         before = (np.asarray(s_hist), np.asarray(y_hist), int(count), int(oldest))
         s_hist, y_hist, count, oldest = push(
             s_hist, y_hist, count, oldest, jnp.asarray(s), jnp.asarray(y),
@@ -133,6 +137,86 @@ def test_nan_in_invalid_row_cannot_reach_the_direction(backend):
     np.testing.assert_allclose(
         np.asarray(d), ref, rtol=1e-5, atol=1e-5 * np.abs(ref).max()
     )
+
+
+# --------------------------------------------------------------- the lanes
+#
+# R = 8 * ceil(N / 1024) follows from N alone: 10 parameters fill a corner
+# of one tile, 1,024 exactly one, 1,030 spill six lanes into a second.
+# Whatever N is, the layout must be invisible from outside.
+
+
+def _past(hist, n):
+    """The lanes of every row of a `[..., m, R, 128]` buffer past `n`."""
+    a = np.asarray(hist)
+    return a.reshape(*a.shape[:-2], -1)[..., n:]
+
+
+def _check_direction(n, backend):
+    # against the dense reference on [m, N] pairs, after a wrapped ring
+    s_hist, y_hist, count, oldest, pairs = _drive(SCENARIOS["wrapped_once"], n=n)
+    assert s_hist.shape == (M, lane_rows(n), 128) and int(oldest) > 0
+    g = jnp.asarray(np.random.default_rng(97).normal(size=n), jnp.float32)
+    h_diag = jnp.float32(0.37)
+    d = BACKENDS[backend](g, s_hist, y_hist, count, h_diag, oldest)
+    assert d.shape == (n,)
+    ref = _reference_direction(g, pairs, h_diag)
+    np.testing.assert_allclose(
+        np.asarray(d), ref, rtol=1e-5, atol=1e-5 * np.abs(ref).max()
+    )
+
+
+def _check_zero_lanes(n, backend):
+    # exactly zero past N: after pushes, rejected pairs and a reset ...
+    s_hist, y_hist, *_ = _drive(
+        SCENARIOS["reset_after_pushes"] + [False, True], n=n
+    )
+    assert np.abs(np.asarray(s_hist)).max() > 0
+    for hist in (s_hist, y_hist):
+        assert _past(hist, n).size == M * (lane_rows(n) * 128 - n)
+        assert not _past(hist, n).any()
+    # ... and after whole steps of a block with frozen clients in it
+    cfg = LBFGSConfig(
+        max_iter=4, history_size=3, line_search=True, batch_mode=True,
+        direction=backend,
+    )
+    step, x1, centre, scale, state1 = _filled_block(cfg, k=3, n=n)
+    x_in = x1.at[2].set(centre[2])  # client 2 `done` at entry, 1 poisoned
+    _, state2, _ = step(
+        x_in, centre, scale, jnp.asarray([False, True, False]), state1
+    )
+    for state in (state1, state2):
+        assert np.abs(np.asarray(state.s_hist)).max() > 0
+        assert not _past(state.s_hist, n).any()
+        assert not _past(state.y_hist, n).any()
+
+
+def _check_nan_row(n, backend):
+    # a NaN in an invalid row, lanes past N included, and in nothing else
+    s_hist, y_hist, count, oldest, pairs = _drive([True, True], n=n)
+    s_hist = s_hist.at[2:].set(jnp.nan)
+    y_hist = y_hist.at[2:].set(jnp.nan)
+    g = jnp.asarray(np.random.default_rng(98).normal(size=n), jnp.float32)
+    d = BACKENDS[backend](g, s_hist, y_hist, count, jnp.float32(1.3), oldest)
+    assert np.isfinite(np.asarray(d)).all()
+    ref = _reference_direction(g, pairs, 1.3)
+    np.testing.assert_allclose(
+        np.asarray(d), ref, rtol=1e-5, atol=1e-5 * np.abs(ref).max()
+    )
+
+
+LANE_CHECKS = {
+    "direction": _check_direction,
+    "zero_lanes": _check_zero_lanes,
+    "nan_row": _check_nan_row,
+}
+
+
+@pytest.mark.parametrize("backend", sorted(BACKENDS))
+@pytest.mark.parametrize("n", [10, 1024, 1030])
+@pytest.mark.parametrize("check", sorted(LANE_CHECKS))
+def test_lane_layout_is_invisible(check, n, backend):
+    LANE_CHECKS[check](n, backend)
 
 
 @pytest.mark.parametrize("backend", sorted(BACKENDS))
@@ -231,9 +315,9 @@ def _history_ops(text, hist):
 def test_loop_touches_the_histories_by_row_only(direction):
     # The counter that says the mechanism engages: inside the L-BFGS loop
     # of the program the engine's client vmap lowers to, the instructions
-    # on a whole [K, m, N] history are the direction's own and one row
-    # scatter per buffer. No select over the carry (the loop's predicate
-    # is one flag for the block), no shift, no seeding.
+    # on a whole [K, m, R, 128] history are the direction's own and one
+    # row scatter per buffer. No select over the carry (the loop's
+    # predicate is one flag for the block), no shift, no seeding.
     k, m, n = 3, 4, 24
     cfg = LBFGSConfig(
         max_iter=4, history_size=m, line_search=True, batch_mode=True,
@@ -248,7 +332,7 @@ def test_loop_touches_the_histories_by_row_only(direction):
     text = jax.jit(jax.vmap(one)).lower(x, x + 1.0, state).as_text(
         debug_info=True
     )
-    hist = f"tensor<{k}x{m}x{n}xf32>"
+    hist = f"tensor<{k}x{m}x{lane_rows(n)}x128xf32>"
 
     # one predicate for the block: the loop's condition hands back a
     # carried scalar, it computes nothing
@@ -268,13 +352,57 @@ def test_loop_touches_the_histories_by_row_only(direction):
     # outside the direction: the row write and nothing else
     assert [op for op, _ in outside] == ["scatter", "scatter"], outside
     assert all("fedtpu.history" in sc for _, sc in outside), outside
-    # inside it: contractions and the row mask; nothing that builds a new
-    # history (a shift's pieces, a seeding add, a write)
+    # inside it: contractions, the row mask and reads of a slab (the
+    # Gram's `s[i]`, the recursion's row); nothing that builds a new
+    # history (a shift's concatenate, a seeding add, a write, a relaying).
+    # That no reader's OUTPUT is a history is held below, on the jaxpr
     inside = {op for op, sc in ops if "fedtpu.direction" in sc}
     assert inside, ops
     assert not inside & {
-        "concatenate", "slice", "add", "dynamic_update_slice", "scatter", "pad",
+        "concatenate", "add", "dynamic_update_slice", "scatter", "pad",
+        "reshape", "transpose",
     }, inside
+
+
+def _equations(jaxpr):
+    """Every equation of `jaxpr` and of the jaxprs inside its equations
+    (loop bodies, branches, calls, batched custom rules)."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _equations(sub)
+
+
+def test_only_row_writes_and_row_masks_make_a_history():
+    # The layout must never be relaid: in the jaxpr of the vmapped step
+    # (direction `compact`) the equations with a history-sized output are
+    # the two row scatters and `compact_direction`'s two validity selects
+    # (they fuse into their readers; the broadcasts are their constant
+    # operands) and nothing else — no reshape, transpose, pad,
+    # concatenate, slice or copy of a [K, m, R, 128] array. (The loop, its
+    # branches and calls hand the buffers on; they are descended into.)
+    k, m, n = 3, 4, 1030
+    cfg = LBFGSConfig(
+        max_iter=4, history_size=m, line_search=True, batch_mode=True
+    )
+
+    def one(x, a, state):
+        return lbfgs_step(lambda xx: jnp.sum(a * (xx - 1.0) ** 2), x, state, cfg)
+
+    x = jnp.zeros((k, n), jnp.float32)
+    state = jax.vmap(lambda xx: lbfgs_init(xx, cfg))(x)
+    shape = (k, m, lane_rows(n), 128)
+    assert state.s_hist.shape == shape
+    closed = jax.make_jaxpr(jax.vmap(one))(x, x + 1.0, state)
+    made = [
+        eqn.primitive.name
+        for eqn in _equations(closed.jaxpr)
+        if not list(jax.core.jaxprs_in_params(eqn.params))
+        and any(getattr(v.aval, "shape", None) == shape for v in eqn.outvars)
+    ]
+    assert sorted(p for p in made if p != "broadcast_in_dim") == [
+        "scatter", "scatter", "select_n", "select_n",
+    ], made
 
 
 # ------------------------------------------------------- freeze, early exit

@@ -19,6 +19,7 @@ from federated_pytorch_test_tpu.ops import (
 )
 from federated_pytorch_test_tpu.optim import LBFGSConfig, lbfgs_init, lbfgs_step
 from federated_pytorch_test_tpu.optim.compact import compact_direction
+from federated_pytorch_test_tpu.optim.history import history_of
 
 pytestmark = pytest.mark.smoke  # fast CI tier
 
@@ -30,7 +31,9 @@ def _rel_close(a, b, rtol):
     )
 
 
-def _history(m, n, seed, curvature=True):
+def _pairs(m, n, seed, curvature=True):
+    """(s, y, g): `m` pairs of `n` parameters as `[m, n]` stacks, and a
+    gradient."""
     rng = np.random.default_rng(seed)
     s = jnp.asarray(rng.normal(size=(m, n)), jnp.float32) * 0.1
     noise = jnp.asarray(rng.normal(size=(m, n)), jnp.float32)
@@ -43,11 +46,20 @@ def _history(m, n, seed, curvature=True):
     return s, y, g
 
 
+def _history(m, n, seed, curvature=True):
+    """(S, Y, g): `_pairs` as the solver holds them, two `[m, R, 128]`
+    buffers (optim/history.py)."""
+    s, y, g = _pairs(m, n, seed, curvature)
+    return history_of(s), history_of(y), g
+
+
 def test_fused_gram_projections_all_contractions():
     # one fused pass == the four separate contractions
-    m, n = 10, 5000  # n not a tile multiple => exercises the tail mask
-    s, y, g = _history(m, n, 0)
-    sy, yy, p, q = fused_gram_projections(s, y, g)
+    # n no multiple of 1,024 => zero lanes in the last tile; R = 160 is
+    # two grid steps, the second 32 rows of 128 => exercises the tail mask
+    m, n = 10, 20000
+    s, y, g = _pairs(m, n, 0)
+    sy, yy, p, q = fused_gram_projections(history_of(s), history_of(y), g)
     np.testing.assert_allclose(np.asarray(sy), np.asarray(s @ y.T), rtol=2e-5, atol=1e-5)
     np.testing.assert_allclose(np.asarray(yy), np.asarray(y @ y.T), rtol=2e-5, atol=1e-5)
     np.testing.assert_allclose(np.asarray(p), np.asarray(s @ g), rtol=2e-5, atol=1e-5)
