@@ -81,8 +81,8 @@ HARDNESS = dict(noise=110.0, overlap=0.35, label_noise=0.25)
 
 SIMPLE = dict(batch=64, n_train=960)   # 320/client -> 5 lockstep batches
 # 128/client -> 4 lockstep batches of 32. Small on purpose: the torch
-# side pays ~36 s per lockstep minibatch on this 1-core host
-# (benchmarks/reference_throughput.json), so the full-10-block resnet
+# side pays ~36 s per lockstep minibatch on a 1-core host (the torch
+# reference's own speed, as this script saw it), so the full-10-block resnet
 # schedule at RESNET_NLOOP outer loops is hours, not days — the dataset
 # is shrunk and the loop count raised until both sides learn well above
 # chance (round-2 VERDICT item 1: "shrink the dataset / raise epochs

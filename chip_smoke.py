@@ -533,7 +533,7 @@ def check_flash(seq: int = 2048, heads: int = 4, dim: int = 64) -> dict:
 
         out = merged(q, k, v, jnp.int32(half), jnp.asarray([0, half], jnp.int32))
         # 2e-5 absolute is the bound the on-chip check this stage
-        # replaces (benchmarks/tpu_kernel_check.py) asserted: the fold
+        # replaces (removed in PR 21) asserted: the fold
         # goes through the kernel's in-VMEM log and an exp of the lse,
         # and on the chip that path is ~6x less exact than the
         # triangular kernel (1.1e-5 vs 1.8e-6 against a float64

@@ -122,8 +122,6 @@ init, so they run on any host):
     python -m federated_pytorch_test_tpu report runs/ --json report.json
     python -m federated_pytorch_test_tpu watch runs/ [--once] [--interval S]
     python -m federated_pytorch_test_tpu scrub ckpt/ [--repair]
-    python -m federated_pytorch_test_tpu trend . benchmarks/ [--store F]
-    python -m federated_pytorch_test_tpu debt [--script remeasure.sh]
     python -m federated_pytorch_test_tpu chaos [--budget-s S | --cases N]
                                                [--seed S] [--repro FILE]
 
@@ -143,12 +141,7 @@ else drop the chunk so its rows re-initialize pristine. The storage
 fault axis itself rides the plan string — `storage=<p>:<bitrot|torn|
 ioerror|enospc>[:strength]` chaos-injects the store/checkpoint/stream
 byte paths, survived by checksum-verified reads with bounded retry
-(docs/FAULT.md §Storage-integrity axis). `trend` (obs/benchdb.py)
-ingests BENCH_*.json wrappers and benchmark artifacts into an
-append-only trend store keyed by (metric, provenance class) and runs
-the noise-aware regression sentinel — CPU-twin baselines never judge
-TPU numbers; `debt` (obs/debt.py) lists DEBT.json's open
-re-measurement entries and emits the runnable script that pays them.
+(docs/FAULT.md §Storage-integrity axis).
 `chaos` (fault/chaos.py) soaks the engine under a seeded fuzzer that
 composes random fault-plan axes with random config knobs, checks every
 drawn case against the crash+resume invariant oracle, shrinks any
@@ -346,6 +339,34 @@ def _print_summary(recorder, cfg) -> None:
         print(f"# FIRST NON-FINITE at {recorder.first_nonfinite}")
 
 
+def _build_parser() -> argparse.ArgumentParser:
+    """The argument parser of a plain `--preset` run (the verbs parse
+    their own arguments)."""
+    from federated_pytorch_test_tpu.engine import PRESETS
+
+    parser = argparse.ArgumentParser(
+        prog="federated_pytorch_test_tpu",
+        description="TPU-native federated / consensus optimization experiments",
+    )
+    parser.add_argument(
+        "--preset",
+        default="fedavg",
+        choices=sorted(PRESETS),
+        help="base experiment (one of the five reference drivers)",
+    )
+    parser.add_argument("--list-presets", action="store_true")
+    parser.add_argument(
+        "--metrics-out",
+        default=None,
+        help="write the final metrics JSON here (atomic write; envelope "
+        '{"series": ..., "first_nonfinite": ...}). For an incremental '
+        "stream that survives crashes, use --metrics-stream instead.",
+    )
+    parser.add_argument("--quiet", action="store_true")
+    _add_config_flags(parser)
+    return parser
+
+
 def main(argv=None) -> int:
     if argv is None:
         argv = sys.argv[1:]
@@ -372,21 +393,6 @@ def main(argv=None) -> int:
         from federated_pytorch_test_tpu.fault.scrub import scrub_main
 
         return scrub_main(argv[1:])
-    if argv and argv[0] == "trend":
-        # the perf-trend verb (obs/benchdb.py): ingest BENCH wrappers /
-        # benchmark artifacts into the append-only trend store and run
-        # the provenance-class-isolated regression sentinel —
-        # backend-free like report/watch/scrub (pure file analysis)
-        from federated_pytorch_test_tpu.obs.benchdb import trend_main
-
-        return trend_main(argv[1:])
-    if argv and argv[0] == "debt":
-        # the re-measurement debt verb (obs/debt.py): list DEBT.json's
-        # open entries and emit the ready-to-run payment script for the
-        # first session with the owed backend — backend-free too
-        from federated_pytorch_test_tpu.obs.debt import debt_main
-
-        return debt_main(argv[1:])
     if argv and argv[0] == "chaos":
         # the chaos-harness verb (fault/chaos.py): seeded fuzzer over
         # composed fault plans x knob lattice, invariant oracle with
@@ -404,26 +410,7 @@ def main(argv=None) -> int:
         run_experiment,
     )
 
-    parser = argparse.ArgumentParser(
-        prog="federated_pytorch_test_tpu",
-        description="TPU-native federated / consensus optimization experiments",
-    )
-    parser.add_argument(
-        "--preset",
-        default="fedavg",
-        choices=sorted(PRESETS),
-        help="base experiment (one of the five reference drivers)",
-    )
-    parser.add_argument("--list-presets", action="store_true")
-    parser.add_argument(
-        "--metrics-out",
-        default=None,
-        help="write the final metrics JSON here (atomic write; envelope "
-        '{"series": ..., "first_nonfinite": ...}). For an incremental '
-        "stream that survives crashes, use --metrics-stream instead.",
-    )
-    parser.add_argument("--quiet", action="store_true")
-    _add_config_flags(parser)
+    parser = _build_parser()
     args = parser.parse_args(argv)
 
     if args.list_presets:

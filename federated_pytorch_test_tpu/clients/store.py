@@ -1,9 +1,8 @@
 """Host-side virtual-client state store: N ≫ K clients, O(C) round cost.
 
 The pre-cohort engine holds every configured client's state as `[K]`
-device arrays — cross-*silo* simulation, where K is bounded by HBM and
-`benchmarks/client_scaling_tpu.json` shows per-client efficiency
-collapsing as K grows on one device. Cross-*device* federated learning
+device arrays — cross-*silo* simulation, where K is bounded by HBM.
+Cross-*device* federated learning
 inverts the shape: a server keeps state for thousands-to-millions of
 mostly-idle virtual clients on the HOST, and each round only the sampled
 cohort's rows ever touch a device (clients/cohort.py, engine/trainer.py

@@ -50,12 +50,12 @@ class ExperimentConfig:
     # not low precision). 'float32' matches the reference bit-for-bit in
     # spirit — and note that XLA's default matmul precision already runs
     # f32 convs as single bf16 MXU passes, so on CIFAR-sized workloads
-    # f32 keeps bf16's compute speed without its cast seams. Round-2
-    # profiling (BASELINE.md roofline note) recovered bf16 from 2.1x to
-    # ~1.3x slower on the batch-32 flagship (hoisted closure cast,
-    # fusable bf16 BN reductions); f32 stays the default — the knob pays
-    # off where activation memory is the binding constraint (long-context
-    # transformers, large batches with remat), not small CNNs.
+    # f32 keeps bf16's compute speed without its cast seams (what that
+    # costs in exactness, and what `highest` costs in speed: root PERF.md
+    # §4, §7). bf16 against f32 on this installation: not measured. f32
+    # stays the default — the knob is meant for where activation memory
+    # is the binding constraint (long-context transformers, large
+    # batches with remat), not small CNNs.
     compute_dtype: str = "float32"
     # rematerialize the forward during backprop (jax.checkpoint): trades
     # ~1/3 more FLOPs for activation memory — the lever for batch sizes /
@@ -213,7 +213,7 @@ class ExperimentConfig:
     # metrics-stream tag, unlike the dispatch-shape-only fold/async
     # knobs). The roofline lever: the sequential search's mean ~4 probes
     # per step each re-stream the full parameter vector from HBM; a fan
-    # streams once per P probes (bench.py probe_batch_speedup).
+    # streams once per P probes (on the chip: not measured).
     linesearch_probes: int = 1
     # widened client GEMM (engine/steps.py GroupContext.client_fold,
     # docs/PERF.md §Widened GEMM): 'gemm' (the default) re-batches the
@@ -1008,7 +1008,7 @@ PRESETS = {
         bb_update=False,
         shuffle_group_order=True,
     ),
-    # BASELINE.json config #5 (scale-out, no reference script): K=64
+    # SURVEY.md §7 item 9 (scale-out, no reference script): K=64
     # ResNet18 clients on CIFAR100, one client per core on a v4-64 —
     # the mesh maps clients to devices 1:1 when 64 devices are present,
     # or folds K into local blocks on smaller meshes (parallel/mesh.py).

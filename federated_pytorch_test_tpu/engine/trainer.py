@@ -3305,10 +3305,10 @@ class Trainer:
         slot's partition round → cohort scatter.
 
         The public per-loop entry point — `run()`'s loop body minus the
-        commit/checkpoint boundary, and the unit the cohort benchmarks
-        time (bench.py `_cohort_probe`,
-        benchmarks/client_scaling_tpu.py `_cohort_sweep`): one warm call
-        is exactly one gather→rounds→scatter cycle. A loop holds
+        commit/checkpoint boundary, and the unit the benchmark times
+        (chipbench/run.py, whose window is whole loops): one warm call
+        is exactly one gather→rounds→scatter cycle.
+        A loop holds
         `len(group_order)` round SLOTS; round-robin maps slot s to
         `group_order[s]` (the legacy schedule, verbatim) while the
         adaptive scheduler picks each slot's group by drift — or skips
@@ -3546,8 +3546,8 @@ class Trainer:
                 hbm_bytes=cost.get("hbm_bytes"),
                 device_kind=jax.devices()[0].device_kind,
                 source=cost.get("source", "measured"),
-                # the stamp that keeps this record from ever serving as
-                # a cross-backend baseline downstream (obs/benchdb.py)
+                # the stamp that says which backend and commit this
+                # record's walls are from (obs/provenance.py)
                 provenance=cached_stamp(),
             )
             # the intensity claim as a recorded number, not prose

@@ -31,11 +31,11 @@ heterogeneity (arXiv:2204.03529) and partial-participation regimes
   via `chaos --repro FILE`.
 * SOAK mode (`chaos --budget-s N --seed S`) — streams one verdict per
   plan as JSONL with provenance stamps and cumulative axis/knob
-  coverage, and writes a `trend`-ingestible `chaos_soak.json` workload
-  summary, so chaos coverage is a first-class perf-trend trajectory.
+  coverage, and writes a crc-self-verified `chaos_soak.json` summary
+  of the soak (cases cleared, violations, coverage, wall).
 
 The `chaos` verb dispatches ENGINE-IMPORT-FREE from `__main__` (like
-`report`/`scrub`/`trend`): this module imports no engine code at import
+`report`/`scrub`): this module imports no engine code at import
 time, pins the backend to host CPU itself (`force_host_cpu`, the
 conftest contract — a soak must never claim an accelerator), and only
 then lazily imports the Trainer inside the oracle.
@@ -1260,10 +1260,9 @@ def _shrink_and_dump(case: ChaosCase, verdict: dict, args) -> str:
 
 
 def _write_summary(args, stamp, cleared, violations, axes_seen, knobs_seen, t0):
-    """The trend-ingestible workload artifact (obs/benchdb.py ingests
-    docs with a `workload` key, numeric items namespaced by file stem +
-    provenance): chaos coverage becomes a first-class trajectory next
-    to the perf smokes."""
+    """The soak's summary artifact, `chaos_soak.json`: what was
+    cleared, what was violated, how much of the axis and knob lattice
+    the drawn cases covered, stamped with where it ran."""
     doc = {
         "workload": "chaos_soak",
         "seed": args.seed,

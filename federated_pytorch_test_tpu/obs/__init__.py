@@ -1,7 +1,7 @@
 """Observability: sinks, ledger, traces, health, roofline, registry,
-flight recorder, memory telemetry, live console, provenance + trend.
+flight recorder, memory telemetry, live console, provenance.
 
-Twelve pillars over the structured metric store (`utils/metrics.py`):
+Ten pillars over the structured metric store (`utils/metrics.py`):
 
 * `JsonlSink` — a crash-safe append-only JSONL metric stream with
   per-outer-loop commit markers; `resume='auto'` replays it and truncates
@@ -21,8 +21,7 @@ Twelve pillars over the structured metric store (`utils/metrics.py`):
   resume (health.py);
 * `lbfgs_round_cost` / `roofline_record` / `chip_peaks` — the analytic
   per-round cost model and achieved-utilization accounting behind the
-  trainer's, bench.py's, and full_schedule_tpu.py's `roofline` records
-  (roofline.py);
+  trainer's `roofline` record (roofline.py);
 * `RunRegistry` — the cross-run experiment registry behind the
   `python -m federated_pytorch_test_tpu report` CLI: validated stream
   ingestion, round-aligned comparisons, and the convergence-vs-bytes
@@ -37,16 +36,11 @@ Twelve pillars over the structured metric store (`utils/metrics.py`):
 * `watch_main` — the `watch` CLI verb: a refreshing terminal dashboard
   tailing metric streams through the registry's validated ingestion
   (console.py);
-* `provenance_stamp` / `provenance_class` / `condition_satisfied` — the
-  self-describing stamp (commit, backend, chip, host, repeats) attached
-  to every measurement artifact, the isolation key the trend layer
-  compares within, and the DEBT.json condition grammar (provenance.py);
-* `BenchDB` / `trend_main` — the `trend` CLI verb: append-only trend
-  store over BENCH wrappers and benchmark artifacts, keyed by (metric,
-  provenance class), with the noise-aware regression sentinel
-  (benchdb.py);
-* `debt_main` — the `debt` CLI verb: the re-measurement debt ledger as
-  data plus the runnable script that pays it (debt.py).
+* `provenance_stamp` / `provenance_class` — the self-describing stamp
+  (commit, backend, chip, host) attached to the trainer's status
+  sidecar and `roofline` record and to the chaos verb's artifacts, and
+  the class (`tpu`, `cpu_twin`, `unstamped`) `report` lists a run
+  under (provenance.py).
 
 Beside them, `phases.py` (imported as a module, not re-exported): the
 closed list of `fedtpu.<phase>` named scopes inside the round program
@@ -54,24 +48,7 @@ and the reduction of a `--profile-dir` window to device seconds by
 phase.
 """
 
-from federated_pytorch_test_tpu.obs.benchdb import (
-    BenchDB,
-    TrendRefused,
-    extract_measurement,
-    metric_direction,
-    render_trend_markdown,
-    trend_main,
-)
 from federated_pytorch_test_tpu.obs.console import render, watch_main
-from federated_pytorch_test_tpu.obs.debt import (
-    close_entries,
-    debt_main,
-    emit_script,
-    load_debt,
-    open_entries,
-    render_debt_markdown,
-    save_debt,
-)
 from federated_pytorch_test_tpu.obs.flight import (
     MAX_INCIDENTS,
     FlightRecorder,
@@ -96,7 +73,6 @@ from federated_pytorch_test_tpu.obs.memory import (
 from federated_pytorch_test_tpu.obs.provenance import (
     STAMP_KEYS,
     cached_stamp,
-    condition_satisfied,
     git_info,
     host_stamp,
     provenance_class,
@@ -119,7 +95,6 @@ from federated_pytorch_test_tpu.obs.sinks import JsonlSink
 from federated_pytorch_test_tpu.obs.trace import DispatchCounter, TraceRecorder
 
 __all__ = [
-    "BenchDB",
     "CHIP_PEAKS",
     "CommLedger",
     "DEADLINE_WARMUP_OBS",
@@ -135,15 +110,9 @@ __all__ = [
     "STAMP_KEYS",
     "StreamRefused",
     "TraceRecorder",
-    "TrendRefused",
     "cached_stamp",
     "chip_peaks",
-    "close_entries",
-    "condition_satisfied",
-    "debt_main",
     "device_memory_stats",
-    "emit_script",
-    "extract_measurement",
     "git_info",
     "host_rss_bytes",
     "host_rss_peak_bytes",
@@ -151,20 +120,13 @@ __all__ = [
     "incidents_dir",
     "lbfgs_round_cost",
     "list_incidents",
-    "load_debt",
     "memory_record",
-    "metric_direction",
-    "open_entries",
     "provenance_class",
     "provenance_stamp",
     "read_stream",
     "render",
-    "render_debt_markdown",
     "render_markdown",
-    "render_trend_markdown",
     "report_main",
     "roofline_record",
-    "save_debt",
-    "trend_main",
     "watch_main",
 ]

@@ -2,9 +2,8 @@
 
 ROADMAP item 4's spilled-store work needs a bounded-RSS *gate*, and a
 gate needs a measurement: this module is the one place host and device
-memory are read, feeding the trainer's per-round `memory` series, the
-`watch` console's memory panel (via the status sidecar — see below), and
-bench.py's `memory_rss_peak_mb` headline.
+memory are read, feeding the trainer's per-round `memory` series and
+the `watch` console's memory panel (via the status sidecar — see below).
 
 Sources, each gracefully None where absent:
 

@@ -1,35 +1,28 @@
 """Provenance stamps: every performance number says WHERE it came from.
 
-Eight straight sessions closed with "no TPU reachable, re-measure
-later", and nothing in the artifacts distinguishes a CPU-twin guess
-from a real chip measurement — a stale host number can masquerade as a
-TPU result the moment the filename stops saying so. This module is the
-fix at the source: one small self-describing stamp attached to every
-measurement artifact the repo emits —
+Nothing in an artifact's numbers distinguishes a CPU-twin run from a
+real chip measurement — a host number can masquerade as a TPU result
+the moment the filename stops saying so. One small self-describing
+stamp is attached to what the program itself emits:
 
-* `bench.py` headlines (and the `benchmarks/bench_full.json` blob),
-* both `benchmarks/*_tpu.py` output JSONs,
 * the trainer's end-of-run `roofline` record (obs/roofline.py),
 * the `<stream>.status.json` live sidecar (`watch` renders a one-line
-  `backend/sha/twin` row from it).
+  `backend/sha/twin` row from it; `report --integrity` lists each run's
+  class),
+* the chaos verb's repro bundles and soak summary (fault/chaos.py).
 
 The stamp answers: which commit (sha + dirty flag), which backend and
 chip (platform, device kind and count), which host (hostname, cpu
-count), which jax, whether this is the CPU twin, and how many bench
-repeats stood behind the number. `provenance_class` collapses a stamp
-to the ISOLATION KEY the trend layer compares within (obs/benchdb.py):
-CPU-twin numbers compare against CPU-twin baselines, TPU against TPU,
-never across — and an unstamped (pre-provenance) artifact is its own
-class, forever unable to close a `backend==tpu` re-measurement debt
-entry (DEBT.json, the `debt` verb).
+count), which jax, whether this is the CPU twin. `provenance_class`
+collapses a stamp to one word: `cpu_twin`, the backend (`tpu`, ...), or
+`unstamped` for an artifact that carries no stamp.
 
 Import rules: this module is accelerator-free. `provenance_stamp`
 PROBES jax only when asked (`probe_jax=True` — callers that already
-initialized a backend: the trainer, bench.py, the benchmark harnesses);
-`host_stamp` never touches jax at all (the jax version comes from
-package metadata, no import) — it is the stamp for host-side facts like
-the CI tier walls, which always run the forced-CPU virtual mesh
-(tests/conftest.py), so `backend: cpu` is the honest label.
+initialized a backend: the trainer); `host_stamp` never touches jax at
+all (the jax version comes from package metadata, no import) — it is
+the stamp for host-side facts of runs on the forced-CPU virtual mesh
+(the chaos verb pins it), so `backend: cpu` is the honest label.
 """
 
 from __future__ import annotations
@@ -109,7 +102,7 @@ def provenance_stamp(
     `probe_jax=True` (default) reads backend/device facts from an
     ALREADY-IMPORTABLE jax — `jax.default_backend()` initializes the
     backend, so only call it from processes that run device work anyway
-    (the trainer, bench.py, benchmarks/). Backend-free callers pass the
+    (the trainer). Backend-free callers pass the
     facts explicitly or use `host_stamp`. Any probe failure degrades to
     nulls: a stamp is never the thing that kills a run.
     """
@@ -139,33 +132,27 @@ def provenance_stamp(
     }
 
 
-def host_stamp(repeats: Optional[int] = None) -> dict:
-    """A stamp for HOST-side measurements (CI tier walls, preflight
-    findings): no jax probe, `backend: cpu` asserted — honest because
-    the CI suite always runs the forced-CPU virtual mesh
-    (tests/conftest.py `JAX_PLATFORMS=cpu`)."""
-    return provenance_stamp(repeats=repeats, probe_jax=False, backend="cpu")
+def host_stamp() -> dict:
+    """A stamp for HOST-side artifacts (the chaos verb's bundles): no
+    jax probe, `backend: cpu` asserted — honest because the caller
+    runs the forced-CPU virtual mesh (fault/chaos.py pins it)."""
+    return provenance_stamp(probe_jax=False, backend="cpu")
 
 
-def cached_stamp(repeats: Optional[int] = None) -> dict:
+def cached_stamp() -> dict:
     """One stamp per process (git subprocesses run once): the trainer
     rewrites the status sidecar every round and must not fork git each
-    time. `repeats`, when given, overrides the cached stamp's field."""
+    time."""
     global _CACHED_STAMP
     if _CACHED_STAMP is None:
         _CACHED_STAMP = provenance_stamp()
-    stamp = dict(_CACHED_STAMP)
-    if repeats is not None:
-        stamp["bench_repeats"] = repeats
-    return stamp
+    return dict(_CACHED_STAMP)
 
 
 def provenance_class(stamp) -> str:
-    """Collapse a stamp to the trend layer's ISOLATION KEY.
+    """Collapse a stamp to the class a run's numbers are listed under.
 
-    * no stamp (pre-provenance artifacts) -> `unstamped` — comparable
-      only against other unstamped history, never a baseline for (or
-      closer of) anything conditioned on a backend;
+    * no stamp (pre-provenance artifacts) -> `unstamped`;
     * `cpu_twin` stamps -> `cpu_twin`;
     * everything else -> the backend string (`tpu`, `gpu`, ...), or
       `unstamped` when the stamp carries no backend at all.
@@ -178,38 +165,3 @@ def provenance_class(stamp) -> str:
     if not backend:
         return "unstamped"
     return str(backend)
-
-
-def condition_satisfied(condition: str, stamp) -> bool:
-    """Evaluate a DEBT.json owed-condition against a stamp.
-
-    The grammar is deliberately tiny — conjunctions of equality tests
-    over stamp keys: `backend==tpu`, `cpu_twin==false`,
-    `backend==tpu and git_dirty==false`. Values compare as
-    case-insensitive strings (`True` == `true`). An ABSENT stamp (or
-    absent key) satisfies nothing: unstamped measurements cannot close
-    debt, the provenance-class isolation rule as a parser property.
-    """
-    condition = (condition or "").strip()
-    if not condition:
-        return True
-    if not isinstance(stamp, dict):
-        return False
-    for clause in condition.split(" and "):
-        clause = clause.strip()
-        if "!=" in clause:
-            key, want = clause.split("!=", 1)
-            negate = True
-        elif "==" in clause:
-            key, want = clause.split("==", 1)
-            negate = False
-        else:
-            raise ValueError(f"unparsable debt condition clause: {clause!r}")
-        key, want = key.strip(), want.strip().lower()
-        have = stamp.get(key)
-        if have is None:
-            return False  # an unprovable clause never satisfies
-        match = str(have).lower() == want
-        if match == negate:
-            return False
-    return True

@@ -1,14 +1,10 @@
 """Roofline telemetry: per-round cost models + achieved-utilization records.
 
-ROADMAP item 2 asks for "an honest roofline note" on the memory-bound
-L-BFGS epoch; until this module that note was prose assembled by hand
-from `bench.py` output. Here the accounting is code, shared by
-`bench.py`, `benchmarks/full_schedule_tpu.py`, and the trainer's
-end-of-run `roofline` record:
+The accounting behind the trainer's end-of-run `roofline` record, as
+code:
 
 * `chip_peaks(device_kind)` — the public spec-sheet (peak dense bf16 MXU
-  TFLOP/s, peak HBM GB/s) pairs per TPU generation (previously a private
-  table inside bench.py);
+  TFLOP/s, peak HBM GB/s) pairs per TPU generation;
 * `lbfgs_round_cost(...)` — the ANALYTIC cost model: bytes moved and
   FLOPs of one federated round derived from the static shape of the
   work (param count n, L-BFGS history m, inner iterations, line-search
@@ -89,9 +85,10 @@ def lbfgs_round_cost(
     * `func_evals_per_step` model evaluations, each streaming the
       parameter vector in and the gradient out (2·n values). Default
       `1 + max_iter` — the floor of one value_and_grad per inner
-      iteration plus the entry evaluation; pass the measured
-      `mean_func_evals_per_step` (bench.py) for honest numbers (the
-      Armijo search's extra probes are real traffic). Under the widened
+      iteration plus the entry evaluation; pass the measured mean of
+      `func_evals + ls_evals` per step (the `solver_work` series) for
+      honest numbers (the Armijo search's extra probes are real
+      traffic). Under the widened
       fold (`client_fold='gemm'`) a probe fan (`ls_probes` > 1) streams
       the parameters ONCE per widened pass — the amortization
       `--linesearch-probes` exists for — so the per-eval stream is

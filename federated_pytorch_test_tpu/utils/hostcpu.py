@@ -1,7 +1,7 @@
 """Process start-up: pin jax to the host CPU, place the compile cache.
 
 Two process-global decisions every launcher (the CLI, the test conftest,
-the multichip dry run, the chaos verb, bench.py, chip_smoke.py) makes
+the multichip dry run, the chaos verb, chip_smoke.py, chipbench) makes
 before the first program compiles, kept here so each is decided once:
 
 * `force_host_cpu` — run on XLA's host platform with a virtual device

@@ -112,17 +112,7 @@
 #                                    vmap rerun whose stream matches the
 #                                    gemm twin's bitwise modulo the
 #                                    fold-mode tag — the documented
-#                                    CPU tolerance) and trend_smoke
-#                                    (the provenance+trend layer —
-#                                    obs/benchdb.py: two probe-gated
-#                                    bench runs wrapped as BENCH_*.json,
-#                                    trend report byte-identical on
-#                                    re-ingest, a synthetic 2x slowdown
-#                                    flagged by the regression sentinel
-#                                    while the twin-noise rerun passes,
-#                                    and the CPU-twin runs leaving every
-#                                    backend==tpu DEBT.json entry open —
-#                                    the class-isolation rule end to end)
+#                                    CPU tolerance)
 #                                    and chaos_smoke (the chaos HARNESS
 #                                    — fault/chaos.py: a fixed-seed
 #                                    soak of composed fuzzer-drawn
@@ -142,12 +132,7 @@
 # $CI_PREFLIGHT_JSON (default ci_preflight.json) for the round's CI
 # artifact — and every pytest tier run through run_tier() APPENDS its
 # suite wall + pass count to the same file, so the tier-1-at-the-edge
-# trend (PR 10 note) is data, not anecdote. After the tiers, trend_feed()
-# stamps the preflight JSON with a host provenance stamp
-# (obs/provenance.py host_stamp — the suite always runs the forced-CPU
-# mesh) and ingests it into the trend store ($CI_TREND_STORE, default
-# ci_trend.jsonl), so the tier walls become a queryable trajectory the
-# `trend` verb's sentinel watches.
+# trend (PR 10 note) is data, not anecdote.
 #
 # Usage:
 #   scripts/ci.sh            # tier 1 then tier 2 (both tiers, full CI)
@@ -332,8 +317,7 @@ chaos_smoke() {
   # plans (the three deterministic invariant probes + composed cases)
   # must clear the full invariant oracle with ZERO violations. Every
   # verdict streams to verdicts.jsonl; the chaos_soak.json workload
-  # summary is crc-self-verified and fed to the trend store by
-  # trend_feed (it carries a host provenance stamp).
+  # summary is crc-self-verified (it carries a host provenance stamp).
   #
   # Leg 2 — the planted bug: CHAOS_PLANT_BUG=combiner swaps the
   # Byzantine-robust combiner for a naive masked mean that averages
@@ -398,8 +382,7 @@ PY
     echo "chaos smoke FAILED: bundle 'reproduced' on the honest engine" >&2
     tail -10 "$d/replay2.log" >&2; rm -rf "$d"; return 1
   fi
-  # feed this smoke's wall to the preflight JSON like run_tier does, so
-  # the chaos soak's cost is a trend-store trajectory too
+  # feed this smoke's wall to the preflight JSON like run_tier does
   python - chaos_smoke "$((SECONDS - t0))" \
     "${CI_PREFLIGHT_JSON:-ci_preflight.json}" <<'PY' || true
 import json, sys
@@ -1274,146 +1257,6 @@ PY
   rm -rf "$d"
 }
 
-trend_smoke() {
-  # The provenance+trend layer end to end (obs/provenance.py,
-  # obs/benchdb.py, obs/debt.py — ISSUE-18): two probe-gated bench runs
-  # (flagship headline only: BENCH_PROBES=0 skips the subsystem probe
-  # suite, BENCH_SWEEP=0 the utilization sweep) wrapped as the driver's
-  # {n, cmd, rc, tail, parsed} BENCH_*.json format, then four gates:
-  #   1. DETERMINISM — the trend report is byte-identical when the same
-  #      wrappers are re-ingested (digest-deduped append-only store);
-  #   2. TWIN NOISE — two honest back-to-back CPU runs of the same
-  #      commit must NOT trip the regression sentinel (the >=25% noise
-  #      band, widened by each headline's own sps_p25/p75 spread);
-  #   3. SENTINEL — a synthetic 2x slowdown of the same provenance
-  #      class IS flagged, exit nonzero, metric named;
-  #   4. ISOLATION — the CPU-twin measurements leave every
-  #      backend==tpu DEBT.json entry open (a twin can never pay TPU
-  #      debt), and the `debt` verb still emits a syntactically valid
-  #      payment script for them.
-  local d; d="$(mktemp -d)"
-  echo "trend smoke: two probe-gated bench runs..."
-  # BENCH_MODEL=net: the flagship resnet18 L-BFGS epoch costs minutes
-  # per draw on the CPU twin; the tiny CNN drives the identical timing
-  # path in seconds (bench.py renames the headline metric so these rows
-  # can never touch the resnet18 trajectory)
-  local benv=(env BENCH_DEVICE=cpu BENCH_PROBES=0 BENCH_SWEEP=0
-              BENCH_MODEL=net BENCH_BATCH=8 BENCH_REPEATS=5 BENCH_STEPS=2)
-  "${benv[@]}" python bench.py > "$d/b1.log" 2>&1 || {
-    echo "trend smoke FAILED: bench run 1 died" >&2
-    tail -20 "$d/b1.log" >&2; rm -rf "$d"; return 1
-  }
-  "${benv[@]}" python bench.py > "$d/b2.log" 2>&1 || {
-    echo "trend smoke FAILED: bench run 2 died" >&2
-    tail -20 "$d/b2.log" >&2; rm -rf "$d"; return 1
-  }
-  # wrap each run's final stdout line exactly the way the driver does,
-  # plus the synthetic regression: run 2's headline again, value halved
-  # (same provenance class — the sentinel MUST see it)
-  python - "$d" <<'PY' || { rm -rf "$d"; return 1; }
-import json, sys
-
-d = sys.argv[1]
-for i in (1, 2):
-    tail = open(f"{d}/b{i}.log").read().strip().splitlines()[-1]
-    parsed = json.loads(tail)
-    assert parsed.get("provenance", {}).get("cpu_twin") is True, \
-        "bench headline is missing the cpu_twin provenance stamp"
-    with open(f"{d}/BENCH_s{i:02d}.json", "w") as f:
-        json.dump({"n": i, "cmd": "python bench.py", "rc": 0,
-                   "tail": tail, "parsed": parsed}, f)
-slow = json.loads(open(f"{d}/b2.log").read().strip().splitlines()[-1])
-slow["value"] = slow["value"] / 2.0
-for k in ("sps_p25", "sps_p75"):
-    if slow.get(k):
-        slow[k] = slow[k] / 2.0
-with open(f"{d}/slowdown.json", "w") as f:
-    json.dump({"n": 3, "cmd": "python bench.py", "rc": 0,
-               "tail": "", "parsed": slow}, f)
-PY
-  echo "trend smoke: ingest + twin-noise + determinism gates..."
-  python -m federated_pytorch_test_tpu trend \
-    "$d/BENCH_s01.json" "$d/BENCH_s02.json" \
-    --store "$d/t.jsonl" --json "$d/r1.json" --md "$d/r1.md" \
-    --debt none --quiet || {
-    echo "trend smoke FAILED: the twin-noise rerun tripped the sentinel" >&2
-    cat "$d/r1.md" >&2; rm -rf "$d"; return 1
-  }
-  python -m federated_pytorch_test_tpu trend \
-    "$d/BENCH_s01.json" "$d/BENCH_s02.json" \
-    --store "$d/t.jsonl" --json "$d/r2.json" --md "$d/r2.md" \
-    --debt none --quiet || {
-    echo "trend smoke FAILED: re-ingest tripped the sentinel" >&2
-    rm -rf "$d"; return 1
-  }
-  cmp -s "$d/r1.json" "$d/r2.json" && cmp -s "$d/r1.md" "$d/r2.md" || {
-    echo "trend smoke FAILED: report not byte-identical on re-ingest" >&2
-    diff "$d/r1.json" "$d/r2.json" | head -20 >&2; rm -rf "$d"; return 1
-  }
-  echo "trend smoke: synthetic 2x slowdown must be flagged..."
-  if python -m federated_pytorch_test_tpu trend "$d/slowdown.json" \
-       --store "$d/t.jsonl" --md "$d/r3.md" --debt none --quiet; then
-    echo "trend smoke FAILED: the 2x slowdown sailed past the sentinel" >&2
-    rm -rf "$d"; return 1
-  fi
-  grep -q "REGRESSION" "$d/r3.md" || {
-    echo "trend smoke FAILED: regression not named in the report" >&2
-    rm -rf "$d"; return 1
-  }
-  echo "trend smoke: CPU-twin measurements must not pay TPU debt..."
-  cp DEBT.json "$d/DEBT.json"
-  python -m federated_pytorch_test_tpu trend \
-    "$d/BENCH_s01.json" "$d/BENCH_s02.json" \
-    --store "$d/t_debt.jsonl" --debt "$d/DEBT.json" --quiet || true
-  python - "$d/DEBT.json" <<'PY' || { rm -rf "$d"; return 1; }
-import json, sys
-
-doc = json.load(open(sys.argv[1]))
-still_open = [e for e in doc["entries"] if e.get("status", "open") == "open"]
-assert len(still_open) == len(doc["entries"]), (
-    "a CPU-twin measurement closed TPU debt: "
-    + str([e["id"] for e in doc["entries"] if e not in still_open])
-)
-print(f"trend smoke: all {len(still_open)} backend==tpu entries stayed open")
-PY
-  python -m federated_pytorch_test_tpu debt --file "$d/DEBT.json" \
-    --script "$d/remeasure.sh" --quiet > /dev/null || {
-    echo "trend smoke FAILED: the debt verb died" >&2
-    rm -rf "$d"; return 1
-  }
-  bash -n "$d/remeasure.sh" || {
-    echo "trend smoke FAILED: emitted payment script does not parse" >&2
-    rm -rf "$d"; return 1
-  }
-  echo "trend smoke OK"
-  rm -rf "$d"
-}
-
-trend_feed() {
-  # Feed this CI session's walls into the trend store (ISSUE-18
-  # satellite): stamp the preflight+tiers JSON with a host provenance
-  # stamp (host_stamp — the suite always runs the forced-CPU virtual
-  # mesh, so backend:cpu is the honest label), then ingest it. Advisory:
-  # a trend-store hiccup must never fail a green suite, hence || true.
-  local pf="${CI_PREFLIGHT_JSON:-ci_preflight.json}"
-  [ -f "$pf" ] || return 0
-  python - "$pf" <<'PY' || true
-import json, sys
-
-from federated_pytorch_test_tpu.obs.provenance import host_stamp
-
-path = sys.argv[1]
-doc = json.load(open(path))
-doc["provenance"] = host_stamp()
-with open(path, "w") as f:
-    json.dump(doc, f, indent=1)
-    f.write("\n")
-PY
-  python -m federated_pytorch_test_tpu trend "$pf" \
-    --store "${CI_TREND_STORE:-ci_trend.jsonl}" --debt none --quiet \
-    || true
-}
-
 tier="${CI_TIER:-all}"
 preflight
 case "$tier" in
@@ -1432,7 +1275,6 @@ case "$tier" in
     incident_smoke
     integrity_smoke
     widened_smoke
-    trend_smoke
     chaos_smoke
     ;;
   all)
@@ -1449,9 +1291,7 @@ case "$tier" in
     incident_smoke
     integrity_smoke
     widened_smoke
-    trend_smoke
     chaos_smoke
     ;;
   *) echo "unknown CI_TIER='$tier' (want 0, 1, 2 or all)" >&2; exit 2 ;;
 esac
-trend_feed

@@ -29,7 +29,7 @@ def tiny(preset: str, **over) -> ExperimentConfig:
 
 def test_presets_cover_reference_drivers():
     # the five reference driver scripts -> five presets (SURVEY.md §2 C12),
-    # plus the two BASELINE.json config-#5 scale-out presets
+    # plus the two scale-out presets (SURVEY.md §7 item 9)
     assert set(PRESETS) == {
         "no_consensus",
         "fedavg",
@@ -344,7 +344,7 @@ def test_fault_detection_warn_and_raise():
 
 
 def test_scale64_preset_runs_on_8_devices():
-    # BASELINE.json config #5: K=64 clients, CIFAR100, one client per core
+    # SURVEY.md §7 item 9: K=64 clients, CIFAR100, one client per core
     # on a v4-64. On the 8-device CPU mesh the 64 clients fold into local
     # blocks of 8; the model is downsized for CPU CI but keeps the
     # 100-class head the preset specifies.
@@ -717,8 +717,8 @@ def test_model_kwargs_are_validated():
 
 
 def test_diag_forward_off_keeps_trajectory_identical():
-    # skipping the per-batch diagnostic forward (a pure-throughput knob,
-    # benchmarks/epoch_attribution.py) must not change the parameter
+    # skipping the per-batch diagnostic forward (a pure-throughput
+    # knob, `diag_forward`) must not change the parameter
     # trajectory — only the reported per-batch loss (entry vs accepted)
     runs = {}
     for diag in (True, False):

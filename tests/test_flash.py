@@ -145,10 +145,10 @@ def test_flash_default_precision_mode():
 
 
 def test_auto_attn_dispatch_matches_measured_crossover():
-    # attn_impl='auto' picks dense below the measured flash crossover
-    # (round 5: S>=1024 'default' — flash wins 1.55x there — and
-    # S>=2048 'highest'; benchmarks/long_context_tpu.json,
-    # flash_f32_tiles.json) and flash above it. Bit-equality against
+    # attn_impl='auto' picks dense below the flash crossover the old
+    # runtime's sweeps set (S>=1024 at 'default' precision, S>=2048 at
+    # 'highest'; not measured on this installation) and flash above
+    # it. Bit-equality against
     # the explicit impls proves which core ran (same params, same ops).
     from federated_pytorch_test_tpu.models.transformer import (
         MultiHeadAttention,

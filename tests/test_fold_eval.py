@@ -37,8 +37,7 @@ smoke = pytest.mark.smoke
 
 SRC = synthetic_cifar(n_train=240, n_test=60)
 
-# the three eval modes of a FUSED run (bench.py's `eval_mode` headline
-# values): folded = evals inside the round program (default), async =
+# the three eval modes of a FUSED run: folded = evals inside the round program (default), async =
 # standalone eval program on the round's snapshots with the host fetch
 # deferred to the round boundary, sync = same program, blocking fetch at
 # the call site (the pre-async behavior, kept as the escape hatch)

@@ -2,7 +2,7 @@
 
 Smoke tier: JSONL sink truncation/replay mechanics, comm-ledger
 arithmetic against hand-computed bytes, Chrome-trace validity, recorder
-envelope/atomic-save.
+envelope/atomic-save, provenance stamps.
 
 Middle (default) tier: the trainer-level contracts —
 
@@ -25,6 +25,12 @@ import numpy as np
 import pytest
 
 from federated_pytorch_test_tpu.obs import CommLedger, JsonlSink, TraceRecorder
+from federated_pytorch_test_tpu.obs.provenance import (
+    STAMP_KEYS,
+    host_stamp,
+    provenance_class,
+    provenance_stamp,
+)
 from federated_pytorch_test_tpu.partition import Partition, Segment
 from federated_pytorch_test_tpu.utils import MetricsRecorder
 
@@ -595,3 +601,40 @@ def test_metrics_stream_crash_resume_identical(_src, tmp_path):
     # fault plan does not
     assert tr_c._stream_tag() == tr_b2._stream_tag()
     assert tr_a._stream_tag() != tr_b2._stream_tag()
+
+
+# ------------------------------------------------------ provenance stamps
+
+def _stamp(backend, **over):
+    s = {k: None for k in STAMP_KEYS}
+    s.update(
+        schema=1, backend=backend,
+        cpu_twin=(backend == "cpu") if backend else None,
+        git_sha="abc1234", git_dirty=False,
+    )
+    s.update(over)
+    return s
+
+
+@smoke
+def test_provenance_class_mapping():
+    assert provenance_class(None) == "unstamped"
+    assert provenance_class("garbage") == "unstamped"
+    assert provenance_class({}) == "unstamped"
+    assert provenance_class(_stamp(None)) == "unstamped"
+    assert provenance_class(_stamp("cpu")) == "cpu_twin"
+    assert provenance_class(_stamp("tpu")) == "tpu"
+    assert provenance_class(_stamp("gpu")) == "gpu"
+    # an explicit cpu_twin flag wins even with an odd backend string
+    assert provenance_class(_stamp("tpu", cpu_twin=True)) == "cpu_twin"
+
+
+@smoke
+def test_provenance_stamp_backend_free():
+    # probe_jax=False must never touch jax; explicit facts pass through
+    s = provenance_stamp(probe_jax=False, backend="tpu",
+                         device_kind="TPU v4", device_count=4, repeats=7)
+    assert tuple(s) == STAMP_KEYS
+    assert s["backend"] == "tpu" and s["cpu_twin"] is False
+    assert s["device_kind"] == "TPU v4" and s["bench_repeats"] == 7
+    assert host_stamp()["cpu_twin"] is True
