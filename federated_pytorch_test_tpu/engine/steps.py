@@ -87,7 +87,6 @@ from federated_pytorch_test_tpu.consensus import (
 )
 from federated_pytorch_test_tpu.data import normalize
 from federated_pytorch_test_tpu.exchange import make_codec
-from federated_pytorch_test_tpu.models.base import active_leaf_mask, fold_params
 from federated_pytorch_test_tpu.obs.phases import scope, scoped
 from federated_pytorch_test_tpu.ops import _interpret
 from federated_pytorch_test_tpu.parallel.diagnostics import group_distances
@@ -104,6 +103,12 @@ from federated_pytorch_test_tpu.parallel import (
 )
 from federated_pytorch_test_tpu.parallel.collectives import client_sum
 from federated_pytorch_test_tpu.partition import Partition
+from federated_pytorch_test_tpu.partition.assemble import (
+    assemble,
+    gather_span,
+    leaf_plan,
+    span_pieces,
+)
 
 PyTree = Any
 
@@ -216,20 +221,21 @@ class GroupContext(NamedTuple):
     # runs compile the exact pre-drift programs.
     group_drift: bool = False
     # widened client GEMM (docs/PERF.md §Widened GEMM): how the probe
-    # fan's alpha axis meets the model's dots. 'vmap' batches the WHOLE
-    # params tree along the fan — XLA lowers every layer to P skinny
-    # batched dots with M=B — and compiles today's exact programs
-    # byte-for-byte. 'gemm' re-batches at the tree level: only the
-    # ACTIVE group's leaves ride the fan (models/base.py fold_params);
-    # every frozen layer's dot then folds the P axis into its M
-    # dimension (M = P·B per client, M = K·P·B across the client vmap)
-    # and the probe-invariant prefix below the first active layer is
-    # computed ONCE for all probes. Same values — vmap's dot_general
-    # batching rule only restructures the contraction — but the wide
-    # reduction may reorder, so 'gemm' is parity-gated to documented
-    # ulps (tests/test_widened.py) and joins the stream tag. Static:
-    # the default keeps hand-built contexts on the unchanged programs;
-    # the ENGINE default is 'gemm' (engine/config.py client_fold).
+    # fan's alpha axis (`ls_probes > 1`) meets the model's dots. Every
+    # evaluation's tree is assembled from (frozen tree, active group)
+    # (partition/assemble.py), so under the fan's alpha vmap only the
+    # ACTIVE group's leaves are batched: that IS 'gemm' — every frozen
+    # layer's dot folds the P axis into its M dimension (M = P·B per
+    # client, M = K·P·B across the client vmap) and the probe-invariant
+    # prefix below the first active layer is computed once. 'vmap'
+    # hands the solver a fan that inserts into the whole vector and
+    # unravels it, so the WHOLE tree rides the fan and XLA lowers every
+    # layer to P skinny batched dots with M=B. Same values — vmap's
+    # dot_general batching rule only restructures the contraction — but
+    # the wide reduction may reorder, so the two are parity-gated to
+    # documented ulps (tests/test_widened.py) and the knob joins the
+    # stream tag. Inert at `ls_probes` 1, where no fan is built. The
+    # ENGINE default is 'gemm' (engine/config.py client_fold).
     client_fold: str = "vmap"
 
 
@@ -242,12 +248,13 @@ def _tree_data_loss(ctx: GroupContext, params: PyTree, stats: PyTree,
                     images, labels):
     """`_data_loss` at an already-unraveled params TREE.
 
-    The tree-level entry exists for the widened-GEMM fan
-    (`client_fold='gemm'`): there the params tree is assembled by
-    `fold_params` — active-group leaves probe-batched, frozen leaves
-    unbatched — rather than by one `unravel` call, and THIS body is
-    what both assemblies share, so the two fold modes run the identical
-    loss ops on identical values.
+    The tree-level entry is what the solver's objective calls: its
+    tree comes from `partition/assemble.py assemble` — the active
+    group's leaves cut from `x`, every frozen leaf the step-entry
+    tree's own — and not from an `unravel` of a whole vector. The
+    diagnostic forward and the whole-tree probe fan (`client_fold=
+    'vmap'`) unravel; THIS body is what they all share, so every
+    assembly runs the identical loss ops on identical values.
     """
     collections = []
     if ctx.has_stats:
@@ -288,15 +295,23 @@ def _tree_data_loss(ctx: GroupContext, params: PyTree, stats: PyTree,
     return loss, new_stats
 
 
-def _regularizer(ctx: GroupContext, x: jnp.ndarray, flat: jnp.ndarray):
-    """Elastic-net term for one client (reference src/federated_trio.py:303-333)."""
+def _regularizer(ctx: GroupContext, x: jnp.ndarray, fixed: Tuple):
+    """Elastic-net term for one client (reference src/federated_trio.py:303-333).
+
+    `fixed` holds the step-entry values of the fixed segments
+    (`reg_segments`, preset `net`'s `first_linear`), one float32 vector
+    a segment, cut from `flat` once a step. They are read from `x` where
+    the active group covers them and from `fixed` where it does not: the
+    values of `insert(flat, gid, x)` there, without the vector.
+    """
     reg = jnp.asarray(0.0, x.dtype)
     if ctx.reg_on_active:
         reg = reg + elastic_net(x, ctx.lambda1, ctx.lambda2)
     if ctx.reg_segments:
+        active = ctx.partition.groups[ctx.gid]
         parts = [
-            lax.slice(flat, (s.start,), (s.start + s.size,))
-            for s in ctx.reg_segments
+            gather_span(span_pieces(active, s.start, s.size), x, rest)
+            for s, rest in zip(ctx.reg_segments, fixed)
         ]
         v = parts[0] if len(parts) == 1 else jnp.concatenate(parts)
         reg = reg + elastic_net(v, ctx.lambda1, ctx.lambda2)
@@ -339,64 +354,72 @@ def _client_train_step(ctx: GroupContext):
         and (ctx.diag_forward or ctx.has_stats)
     )
 
-    # WIDENED client GEMM (`client_fold='gemm'`, docs/PERF.md §Widened
-    # GEMM): the default probe fan vmaps the WHOLE `phi_aux` — because
-    # `objective` inserts the probed x into the full flat and unravels,
-    # EVERY leaf (frozen layers included) arrives probe-batched, and XLA
-    # lowers each layer to P skinny batched dots with M=B. The fan built
-    # here re-batches at the TREE level instead: the probed unravel
-    # contributes only the ACTIVE group's leaves (they genuinely vary
-    # along the fan), every other leaf comes from `unravel(base)` closed
-    # over OUTSIDE the alpha vmap. vmap's dot_general batching rule then
+    # (FROZEN TREE, ACTIVE GROUP): how an evaluation gets its parameters
+    # (partition/assemble.py). Between the evaluations of one lockstep
+    # step only the active group's `x` moves, so the tree is assembled
+    # from `unravel(base)` — once a step, OUTSIDE `lbfgs_step` — and
+    # slices of `x`; no evaluation writes `x` into the whole vector and
+    # cuts all the leaves out of the result again. The values are the
+    # inserted vector's (the frozen coordinates of `insert(base, gid,
+    # x)` ARE `base`'s bits) and the gradient in `x` is the same sum of
+    # the same leaf cotangents. What it buys is visible dependence: a
+    # frozen leaf depends on no probe, so the compiler moves the frozen
+    # leaves' slices and relayouts out of the solver's loops, and with
+    # them the forward pass BELOW the first active layer (same
+    # minibatch, same frozen weights), computed once an L-BFGS iteration
+    # and not once per evaluation. Nothing here splits the model by
+    # hand; tests/test_tpu_compile.py holds the hoisting. The plan is
+    # static per group; a group that starts at the model's first layer
+    # hoists nothing and only loses the copies.
+    plan = leaf_plan(ctx.unravel, ctx.partition, ctx.gid)
+
+    # The probe fan (`ls_probes > 1`, docs/PERF.md §Widened GEMM): the
+    # solver's default fan is `vmap(objective)` over the alphas, and
+    # under that vmap only what is cut from `x` is batched — the frozen
+    # leaves come unbatched from outside it, vmap's dot_general rule
     # folds the fan axis into the frozen layers' M dimension, and the
-    # probe-invariant prefix below the first active layer is computed
-    # once for all P probes. Values are the inserted full vector's
-    # either way (the frozen coordinates of `insert(base, gid, xc)` ARE
-    # `base`'s bits), so the fan computes the same objective — only the
+    # probe-invariant prefix is computed once for all P probes:
+    # `client_fold='gemm'` is the objective as it stands, no second
+    # path. 'vmap' states the other contract — the WHOLE tree batched
+    # along the fan, P skinny dots a layer — and keeps its
+    # `insert` + `unravel` for that: the one objective that still builds
+    # a whole vector per evaluation. Same values either way; only the
     # reduction structure of the widened dots may reorder (documented
-    # ulps, tests/test_widened.py). Static per (group, fold mode): off
-    # when probes==1, where the sequential search never builds a fan.
-    fan_gemm = (
-        ctx.client_fold == "gemm"
+    # ulps, tests/test_widened.py).
+    fan_whole_tree = (
+        ctx.client_fold != "gemm"
         and ctx.lbfgs.line_search
         and ctx.lbfgs.batch_mode
         and ctx.lbfgs.ls_probes > 1
-    )
-    leaf_mask = (
-        active_leaf_mask(ctx.unravel, ctx.partition, ctx.gid)
-        if fan_gemm
-        else None
     )
 
     def step(flat, lstate, stats, images_u8, labels, mean, std, y, z, rho):
         images = normalize(images_u8, mean, std)
         base = flat.astype(model_dt) if hoist_cast else flat
+        frozen = ctx.unravel(base)
+        # the elastic net reads f32 `x` and f32 `flat`, whatever the
+        # model's dtype
+        reg_fixed = tuple(
+            lax.slice(flat, (s.start,), (s.start + s.size,))
+            for s in ctx.reg_segments
+        )
 
-        def objective_with(params_of, x):
-            # substituting the active group into the PRE-CAST remainder is
+        def objective_at(params_of, x):
+            # the active group substituted into the PRE-CAST remainder is
             # numerically identical to casting inside: the frozen
             # coordinates round f32->bf16 the same either way, and x's
             # own cast keeps the gradient path to f32 x
             xc = x.astype(model_dt) if hoist_cast else x
-            full = ctx.partition.insert(base, ctx.gid, xc)
             data_loss, new_stats = _tree_data_loss(
-                ctx, params_of(full), stats, images, labels
+                ctx, params_of(xc), stats, images, labels
             )
-            loss = data_loss
-            if ctx.reg_segments and hoist_cast:
-                # fixed-segment elastic net reads FROZEN coordinates of
-                # the full vector: keep that in f32 (the segments don't
-                # change within the step, so this inserts into f32 flat)
-                full_reg = ctx.partition.insert(flat, ctx.gid, x)
-            else:
-                full_reg = full
-            loss = loss + _regularizer(ctx, x, full_reg)
+            loss = data_loss + _regularizer(ctx, x, reg_fixed)
             if ctx.strategy == "admm":
                 loss = loss + admm_penalty(x, y, z, rho)
             return loss, (data_loss, new_stats)
 
         def objective(x):
-            return objective_with(ctx.unravel, x)
+            return objective_at(lambda xc: assemble(plan, frozen, xc), x)
 
         if fold:
             loss_fn = objective
@@ -409,18 +432,13 @@ def _client_train_step(ctx: GroupContext):
             # every line-search probe is forward-only and unaffected
             loss_fn = jax.checkpoint(loss_fn)
 
-        if fan_gemm:
-            # frozen leaves evaluated OUTSIDE the alpha vmap — closing
-            # over them unbatched is what lets vmap widen M; XLA
-            # dead-code-eliminates the probed unravel's unused slices
-            frozen = ctx.unravel(base)
-
-            def params_of(full):
-                return fold_params(ctx.unravel(full), frozen, leaf_mask)
+        if fan_whole_tree:
+            def whole_tree(xc):
+                return ctx.unravel(ctx.partition.insert(base, ctx.gid, xc))
 
             def fan_fn(x_cur, d, alphas):
                 def phi(alpha):
-                    loss, aux = objective_with(params_of, x_cur + alpha * d)
+                    loss, aux = objective_at(whole_tree, x_cur + alpha * d)
                     # mirror lbfgs_step's loss_fn_aux contract: the fan's
                     # aux structure must match the sequential path's
                     return (loss, aux) if fold else (loss, ())
@@ -433,6 +451,7 @@ def _client_train_step(ctx: GroupContext):
         x1, lstate, aux = lbfgs_step(
             loss_fn, x0, lstate, ctx.lbfgs, has_aux=fold, fan_fn=fan_fn
         )
+        # the one write of the whole vector in a step, in place
         flat = ctx.partition.insert(flat, ctx.gid, x1)
         if fold:
             data_loss_f, stats_f = aux.aux
@@ -475,10 +494,17 @@ def _gather_batch(shard_imgs, shard_labels, idx_t):
     resident uint8 shards: `idx_t [K_loc, B]` -> images `[K_loc, B, H, W,
     C]`, labels `[K_loc, B]`. Shared by the epoch and the round program."""
     with scope("fedtpu.batch_gather"):
+        # the schedule's indices are permutations of the shard: in
+        # bounds by construction, so no fill select rides the gather
+        # (with it, XLA's CPU backend compiles the normalisation fused
+        # behind the gather to other bits than a normalisation alone)
         images = jnp.take_along_axis(
-            shard_imgs, idx_t[:, :, None, None, None], axis=1
+            shard_imgs, idx_t[:, :, None, None, None], axis=1,
+            mode="promise_in_bounds",
         )
-        labels = jnp.take_along_axis(shard_labels, idx_t, axis=1)
+        labels = jnp.take_along_axis(
+            shard_labels, idx_t, axis=1, mode="promise_in_bounds"
+        )
     return images, labels
 
 

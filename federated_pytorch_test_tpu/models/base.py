@@ -9,7 +9,6 @@ import jax
 import jax.numpy as jnp
 
 from federated_pytorch_test_tpu.partition import Partition, build_partition
-from federated_pytorch_test_tpu.partition.flat import leaf_offsets
 
 PyTree = Any
 
@@ -97,49 +96,3 @@ def init_client_params(model: nn.Module, n_clients: int, seed: int = 0) -> PyTre
     return jax.tree.map(
         lambda x: jnp.broadcast_to(x[None], (n_clients,) + x.shape), variables
     )
-
-
-def active_leaf_mask(unravel, partition: Partition, gid: int):
-    """Which params-tree leaves intersect group `gid`'s flat segments.
-
-    The widened-GEMM fold (`--client-fold gemm`, engine/steps.py) needs to
-    know, per leaf of `unravel`'s output tree, whether any of the leaf's
-    flat coordinates belong to the active group: those leaves vary along
-    the probe fan and must stay probe-batched, while every other leaf is
-    probe-invariant and can be taken from a single unbatched tree — which
-    is what lets vmap fold the probe axis into the M dimension of the
-    frozen layers' dots.
-
-    A leaf that only PARTIALLY overlaps the group is conservatively
-    marked active (it varies along the fan, so it cannot be frozen).
-
-    Returns a list of bools in canonical tree-flatten leaf order.
-    """
-    template = jax.eval_shape(
-        unravel, jax.ShapeDtypeStruct((partition.total,), jnp.float32)
-    )
-    segs = partition.groups[gid]
-    mask = []
-    for _path, start, size in leaf_offsets(template):
-        end = start + size
-        mask.append(
-            any(s.start < end and start < s.start + s.size for s in segs)
-        )
-    return mask
-
-
-def fold_params(probed: PyTree, frozen: PyTree, mask) -> PyTree:
-    """Merge a probe-batched and an unbatched params tree leaf-wise.
-
-    `probed` is `unravel(x_full)` evaluated INSIDE the probe-fan vmap
-    (every leaf carries the batched alpha), `frozen` is `unravel(base)`
-    evaluated outside it, and `mask` is `active_leaf_mask`'s verdict.
-    Active leaves come from `probed` (their values genuinely vary along
-    the fan); all others come from `frozen`, so downstream dots see them
-    unbatched and vmap widens M instead of emitting one skinny dot per
-    probe. XLA dead-code-eliminates the unused probed slices.
-    """
-    p_leaves, treedef = jax.tree_util.tree_flatten(probed)
-    f_leaves = jax.tree_util.tree_leaves(frozen)
-    merged = [p if a else f for p, f, a in zip(p_leaves, f_leaves, mask)]
-    return jax.tree_util.tree_unflatten(treedef, merged)

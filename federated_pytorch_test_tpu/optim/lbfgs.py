@@ -369,9 +369,10 @@ def lbfgs_step(
     `fan_fn(x, d, alphas) -> (losses, auxs)` handed to the multi-alpha
     Armijo search as its `fan_phi` (linesearch.py) — it must compute the
     same values as `vmap(phi_aux)` over the fan, only batched
-    differently (the `--client-fold gemm` hook, engine/steps.py). Only
-    consulted when `ls_probes > 1`; `None` compiles today's exact
-    programs byte-for-byte.
+    differently (the `--client-fold vmap` hook, engine/steps.py: a fan
+    that batches the whole parameter tree; the default fan batches what
+    `loss_fn` derives from `x` and nothing else). Only consulted when
+    `ls_probes > 1`.
     """
     if has_aux and not (config.batch_mode and config.line_search):
         raise ValueError(

@@ -175,12 +175,13 @@ def backtracking_armijo_probes_aux(
     `jax.vmap(phi_aux)(alphas)` with `fan_phi(alphas) -> (losses, auxs)`
     over the `[P]` alpha fan. It MUST compute the same values as the
     default (same objective, same aux structure) — only the batching
-    structure may differ. This is the widened-GEMM hook
-    (`--client-fold gemm`, engine/steps.py): the engine's fan keeps the
-    frozen partition groups' parameters UNBATCHED along the probe axis,
-    so XLA's vmap batching rules fold the P axis into the matmul M
-    dimension instead of emitting P skinny per-probe dots. `None`
-    compiles today's exact fan byte-for-byte.
+    structure may differ. The engine uses it for `--client-fold vmap`
+    (engine/steps.py): its objective takes the frozen leaves from a
+    tree held OUTSIDE the fan, so the default fan already keeps them
+    unbatched along the probe axis and XLA's vmap batching rules fold
+    the P axis into the matmul M dimension (`gemm`); the `vmap` fold
+    hands over a fan that batches the whole tree, P skinny per-probe
+    dots a layer.
     """
     if probes < 1:
         raise ValueError(f"probes must be >= 1, got {probes}")

@@ -1,12 +1,17 @@
-"""The inner solver's history passes, compiled at the flagship's real
-size for a TPU v5e that is described and not attached.
+"""The inner solver's history passes and the flagship's client step,
+compiled at their real size for a TPU v5e that is described and not
+attached.
 
 What interpret mode and the CPU backend cannot show: whether the TPU's
 compiler takes the Pallas kernels' blocks and in-kernel reshapes, and
 what it makes of the `[K, m, R, 128]` histories (optim/history.py) in
 the compact direction — the layout must be read as it lies and written
-one slab at a time, in place, never relaid or copied whole. Nothing runs
-here: these are compiles, a few seconds each, no times.
+one slab at a time, in place, never relaid or copied whole; and whether
+the compiler, given an objective assembled from (frozen tree, active
+group), takes the whole-vector copies and the forward pass below the
+active layer out of the line search's loop. Nothing runs here: these
+are compiles, a few seconds each (the client step: a minute or two), no
+times.
 
 The topology is described inside a fixture, never at import: a worker
 that only collects this file must not load the TPU's library
@@ -48,24 +53,54 @@ def one_chip():
     compilation_cache.reset_cache()
 
 
+# instructions that hand a buffer on and make none
+_PASSES_ON = (
+    "parameter", "get-tuple-element", "tuple", "bitcast", "while",
+    "conditional", "call",
+)
+
+
+def _computations(text):
+    """{computation: [(name, result shape, opcode, line)]} of a compiled
+    program's text, and the entry's name. A tuple shape has spaces."""
+    comps, entry, cur = {}, None, None
+    for line in text.splitlines():
+        m = re.match(r"^(ENTRY\s+)?%?([\w.\-]+)\s*\(.*\)\s*->.*\{\s*$", line)
+        if m:
+            cur = m.group(2)
+            comps[cur] = []
+            entry = cur if m.group(1) else entry
+            continue
+        head = re.match(r"\s*(?:ROOT )?%?([\w.\-]+) = ", line)
+        if cur is None or not head:
+            continue
+        rest = line[head.end():]
+        if rest.startswith("("):
+            depth = 0
+            for i, ch in enumerate(rest):
+                depth += (ch == "(") - (ch == ")")
+                if depth == 0:
+                    break
+            shape, rest = rest[: i + 1], rest[i + 1:]
+        else:
+            shape, _, rest = rest.partition(" ")
+        op = re.match(r"\s*([\w\-]+)\(", rest)
+        if op:
+            comps[cur].append((head.group(1), shape, op.group(1), line))
+    return comps, entry
+
+
 def _top_level(text, shape):
     """[(name, op, line)] of the instructions OUTSIDE fused computations
     whose result is `shape` (parameters, tuples and loops hand a buffer
     on and are left out)."""
-    out, fused = [], False
-    for line in text.splitlines():
-        if re.match(r"^(ENTRY\s+)?%?[\w.\-]+\s*\(.*\)\s*->.*\{\s*$", line):
-            fused = "fused_computation" in line.split("(")[0]
-            continue
-        m = re.match(r"\s*(?:ROOT )?%?([\w.\-]+) = (\S+) ([\w\-]+)\(", line)
-        if fused or not m or not m.group(2).startswith(shape):
-            continue
-        if m.group(3) not in (
-            "parameter", "get-tuple-element", "tuple", "bitcast", "while",
-            "conditional", "call",
-        ):
-            out.append((m.group(1), m.group(3), line))
-    return out
+    return [
+        (name, op, line)
+        for comp, instructions in _computations(text)[0].items()
+        if "fused_computation" not in comp
+        for name, result, op, line in instructions
+        if result.startswith(shape) and op not in _PASSES_ON
+    ]
 
 
 def _fused_root(text, line):
@@ -146,3 +181,136 @@ def test_pallas_direction_compiles_at_real_widths(one_chip, n, monkeypatch):
     # the kernels read the buffers as they are: nothing history-sized is
     # made on the way in
     assert not _top_level(text, f"f32[{k},{M},{lane_rows(n)},128]")
+
+
+# ------------------------------------------------ the flagship's client step
+
+
+def _reached(comps, name, seen=None):
+    """(computation, instruction) for everything `name` runs, the
+    computations its fusions, calls and branches name included; a
+    nested loop's body is NOT entered (it is a loop of its own)."""
+    seen = set() if seen is None else seen
+    if name in seen or name not in comps:
+        return
+    seen.add(name)
+    for ins in comps[name]:
+        yield name, ins
+        if ins[2] == "while":
+            continue
+        for callee in re.findall(
+            r"(?:calls|to_apply|true_computation|false_computation)"
+            r"=%?([\w.\-]+)", ins[3]
+        ) + [
+            c.strip().lstrip("%")
+            for group in re.findall(r"branch_computations=\{([^}]*)\}", ins[3])
+            for c in group.split(",")
+        ]:
+            yield from _reached(comps, callee, seen)
+
+
+def _loops(comps, name, depth=0):
+    """[(depth, body)] of the loops under computation `name`, nested."""
+    out = []
+    for _, ins in _reached(comps, name):
+        if ins[2] == "while":
+            body = re.search(r"body=%?([\w.\-]+)", ins[3]).group(1)
+            out += [(depth, body)] + _loops(comps, body, depth + 1)
+    return out
+
+
+def _convolutions(comps, name):
+    return sum(ins[2] == "convolution" for _, ins in _reached(comps, name))
+
+
+def test_client_step_keeps_whole_vector_and_frozen_forward_out_of_the_loops(
+    one_chip,
+):
+    # the vmapped client step of preset admm_resnet as the benchmark's
+    # cell runs it (K = 6, batch 32, float32 at `highest`, group 8 =
+    # layer4.1), compiled by the chip's compiler. Every evaluation's tree
+    # comes from (frozen tree, active group) (partition/assemble.py), so
+    # (a) nothing inside a loop makes a whole [6, 11173962] parameter
+    # matrix: the one write of a step is the final insert, outside them;
+    # (b) the compiler itself lifts the forward pass below layer4.1 out
+    # of the Armijo loop: a probe runs the active block's convolutions
+    # and what follows, a fraction of the entry evaluation's
+    from jax.flatten_util import ravel_pytree
+
+    from federated_pytorch_test_tpu.engine import get_preset
+    from federated_pytorch_test_tpu.engine.steps import (
+        GroupContext,
+        _client_train_step,
+    )
+    from federated_pytorch_test_tpu.models import ResNet18
+
+    k, batch, gid = 6, 32, 8
+    cfg = get_preset(
+        "admm_resnet", n_clients=k, batch=batch, lbfgs_history=M,
+        lbfgs_max_iter=4, lbfgs_direction="compact", client_fold="gemm",
+    )
+    model = ResNet18()
+    shapes = jax.eval_shape(
+        lambda: model.init(
+            jax.random.PRNGKey(0), jnp.zeros((1, 32, 32, 3)), train=False
+        )
+    )
+    zeros = jax.tree.map(lambda s: jnp.zeros(s.shape, s.dtype), shapes)
+    flat, unravel = ravel_pytree(zeros["params"])
+    part = ResNet18.partition(zeros["params"])
+    n, g = part.total, part.group_size(gid)
+    assert (n, g) == (11_173_962, RESNET18_LARGEST_GROUP)
+    ctx = GroupContext(
+        model=model, unravel=unravel, partition=part, gid=gid,
+        has_stats=True, lbfgs=cfg.lbfgs_config(), strategy="admm",
+        admm=cfg.admm_config(), reg_on_active=False,
+        client_fold=cfg.client_fold,
+    )
+
+    def on_chip(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def stacked(tree):
+        return jax.tree.map(lambda s: on_chip((k,) + s.shape, s.dtype), tree)
+
+    lstate = jax.eval_shape(
+        lambda x: lbfgs_init(x, ctx.lbfgs), jnp.zeros((g,))
+    )
+    args = (
+        on_chip((k, n)), stacked(lstate), stacked(shapes["batch_stats"]),
+        on_chip((k, batch, 32, 32, 3), jnp.uint8),
+        on_chip((k, batch), jnp.int32),
+        on_chip((k,)), on_chip((k,)),            # mean, std
+        on_chip((k, g)), on_chip((g,)), on_chip((k, 1)),  # y, z, rho
+    )
+    step = jax.vmap(
+        _client_train_step(ctx), in_axes=(0, 0, 0, 0, 0, 0, 0, 0, None, 0)
+    )
+    with jax.default_matmul_precision("highest"):
+        compiled = (
+            jax.jit(step, donate_argnums=(0, 1, 2)).lower(*args).compile()
+        )
+    comps, entry = _computations(compiled.as_text())
+    loops = _loops(comps, entry)
+    # the L-BFGS loop, and inside it the Armijo loop: the deepest loop
+    # that runs the model (the ring's row writes are loops too)
+    with_convs = [
+        (depth, body) for depth, body in loops if _convolutions(comps, body)
+    ]
+    assert [d for d, _ in with_convs] == [0, 1], loops
+    (_, lbfgs_body), (_, armijo_body) = with_convs
+
+    whole = f"f32[{k},{n}]"
+    for _, body in loops:
+        made = [
+            (c, ins[0], ins[2]) for c, ins in _reached(comps, body)
+            if ins[1].startswith(whole) and ins[2] not in _PASSES_ON
+        ]
+        assert not made, made
+
+    entry_convs = _convolutions(comps, entry)
+    probe_convs = _convolutions(comps, armijo_body)
+    # 5 against 25 when this was written; the whole forward is 21
+    assert 0 < probe_convs <= 8 and entry_convs >= 21, (
+        probe_convs, entry_convs, _convolutions(comps, lbfgs_body)
+    )

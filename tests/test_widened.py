@@ -14,7 +14,8 @@ fault/robust/codec stack.
 
 Smoke tier: grouped-GEMM kernel units (einsum == vmap bitwise, Pallas
 interpret parity, shape/backend validation), config validation,
-`active_leaf_mask`/`fold_params` semantics, FOLD_LAYERS metadata.
+`leaf_plan`/`assemble` semantics (the fan's selective batching),
+FOLD_LAYERS metadata.
 
 Middle (default) tier — the tier-1 wall sits AT the 870 s driver
 timeout on the 1-core host (867.66 s measured this session), so this
@@ -48,17 +49,18 @@ from federated_pytorch_test_tpu.engine import (
     get_preset,
 )
 from federated_pytorch_test_tpu.models import Net
-from federated_pytorch_test_tpu.models.base import (
-    PartitionedModel,
-    active_leaf_mask,
-    fold_params,
-)
+from federated_pytorch_test_tpu.models.base import PartitionedModel
 from federated_pytorch_test_tpu.obs import JsonlSink
 from federated_pytorch_test_tpu.ops import grouped_matmul, grouped_matmul_pallas
 from federated_pytorch_test_tpu.optim import (
     LBFGSConfig,
     lbfgs_init,
     lbfgs_step,
+)
+from federated_pytorch_test_tpu.partition.assemble import (
+    assemble,
+    leaf_plan,
+    touches_x,
 )
 
 smoke = pytest.mark.smoke
@@ -157,35 +159,53 @@ def test_fold_layers_metadata_on_every_model():
 
 
 @smoke
-def test_active_leaf_mask_and_fold_params_semantics():
-    """The fan's selective batching: group fc1 marks exactly fc1's
-    kernel+bias active; fold_params takes active leaves from the probed
-    tree and everything else from the frozen one."""
+def test_leaf_plan_and_assemble_semantics():
+    """The fan's selective batching: group fc1 reaches exactly fc1's
+    kernel+bias; `assemble` cuts those leaves from `x` and hands every
+    other leaf over as the frozen tree's own object, so under the alpha
+    vmap only the active leaves are batched."""
     m = Net()
     params = m.init(jax.random.PRNGKey(0), m.dummy_input())["params"]
     flat, unravel = ravel_pytree(params)
     part = Net.partition(params)
     gid = 2  # fc1 (GROUP_PATHS order: conv1, conv2, fc1, fc2, fc3)
-    mask = active_leaf_mask(unravel, part, gid)
-    assert sum(mask) == 2 and not all(mask)
-    probed = jax.tree.map(lambda l: l + 1.0, params)
-    merged = fold_params(probed, params, mask)
+    plan = leaf_plan(unravel, part, gid)
+    active = [touches_x(pieces) for pieces in plan]
+    assert sum(active) == 2 and not all(active)
+    x = part.extract(flat, gid) + 1.0
+    merged = assemble(plan, params, x)
     for layer in params:
-        src = probed if layer == "fc1" else params
         for leaf in params[layer]:
-            np.testing.assert_array_equal(
-                np.asarray(merged[layer][leaf]),
-                np.asarray(src[layer][leaf]),
-            )
+            if layer == "fc1":
+                np.testing.assert_array_equal(
+                    np.asarray(merged[layer][leaf]),
+                    np.asarray(params[layer][leaf]) + 1.0,
+                )
+            else:
+                assert merged[layer][leaf] is params[layer][leaf]
+    # along a fan of xs only fc1 is batched: the frozen leaves come out
+    # of the alpha vmap as they went in (out_axes=None refuses a batched
+    # output), which is what folds the fan into the frozen dots' M
+    fan = jnp.stack([x, x + 1.0])
+    rest = jax.vmap(
+        lambda xx: {
+            k: v for k, v in assemble(plan, params, xx).items() if k != "fc1"
+        },
+        out_axes=None,
+    )(fan)
+    assert rest["conv1"]["kernel"].shape == params["conv1"]["kernel"].shape
+    fc1 = jax.vmap(lambda xx: assemble(plan, params, xx)["fc1"])(fan)
+    assert fc1["kernel"].shape == (2,) + params["fc1"]["kernel"].shape
 
 
 # -------------------------------------- per-model parity: direct harness
 #
 # The engine path normalizes u8 images, so token models (and tiny inline
 # BN models) go through the exact steps.py fan construction against a
-# direct `lbfgs_step`: same `active_leaf_mask`/`fold_params` selective
-# batching, same `fan_fn(x, d, alphas)` contract, compared against the
-# fan-less call that compiles today's probe-batched program.
+# direct `lbfgs_step`: the objective assembled from (frozen tree, active
+# group) as `_client_train_step` assembles it, whose default fan is the
+# gemm fold, against the `fan_fn(x, d, alphas)` that batches the whole
+# tree (insert + unravel), which is the vmap fold.
 
 
 class _BNNet(PartitionedModel):
@@ -249,38 +269,34 @@ class _ResBlockNet(PartitionedModel):
 
 
 def _direct_parity(part, flat0, unravel, loss_of_params, probes, gids):
-    """gemm (steps.py fan construction) == vmap (fan-less) through
-    `lbfgs_step`, bitwise, per active group."""
+    """gemm (the assembled objective under the solver's default fan) ==
+    vmap (steps.py's whole-tree fan) through `lbfgs_step`, bitwise, per
+    active group."""
     cfg = LBFGSConfig(
         max_iter=2, history_size=3, line_search=True, batch_mode=True,
         ls_probes=probes,
     )
     for gid in gids:
         x0 = part.extract(flat0, gid)
-        mask = active_leaf_mask(unravel, part, gid)
-        # the fan only folds anything when the mask is MIXED: active
-        # leaves stay probe-batched, the rest are genuinely frozen
-        assert any(mask) and not all(mask), (gid, mask)
+        plan = leaf_plan(unravel, part, gid)
+        active = [touches_x(pieces) for pieces in plan]
+        # the fan only folds anything when the plan is MIXED: active
+        # leaves ride the fan, the rest are genuinely frozen
+        assert any(active) and not all(active), (gid, active)
         frozen = unravel(flat0)
 
-        def objective_with(params_of, x, _gid=gid):
-            full = part.insert(flat0, _gid, x)
-            return loss_of_params(params_of(full))
-
         def loss_fn(x):
-            return objective_with(unravel, x)
+            return loss_of_params(assemble(plan, frozen, x))
 
-        def params_of(full):
-            return fold_params(unravel(full), frozen, mask)
-
-        def fan_fn(x_cur, d, alphas):
+        def fan_fn(x_cur, d, alphas, _gid=gid):
             def phi(a):
-                return objective_with(params_of, x_cur + a * d), ()
+                full = part.insert(flat0, _gid, x_cur + a * d)
+                return loss_of_params(unravel(full)), ()
 
             return jax.vmap(phi)(alphas)
 
         outs = {}
-        for label, fan in (("vmap", None), ("gemm", fan_fn)):
+        for label, fan in (("gemm", None), ("vmap", fan_fn)):
             step = jax.jit(
                 lambda x, st, _fan=fan: lbfgs_step(
                     loss_fn, x, st, cfg, fan_fn=_fan
