@@ -109,6 +109,7 @@ from federated_pytorch_test_tpu.partition.assemble import (
     leaf_plan,
     span_pieces,
 )
+from federated_pytorch_test_tpu.partition.stage import stage_invariant
 
 PyTree = Any
 
@@ -363,14 +364,20 @@ def _client_train_step(ctx: GroupContext):
     # inserted vector's (the frozen coordinates of `insert(base, gid,
     # x)` ARE `base`'s bits) and the gradient in `x` is the same sum of
     # the same leaf cotangents. What it buys is visible dependence: a
-    # frozen leaf depends on no probe, so the compiler moves the frozen
-    # leaves' slices and relayouts out of the solver's loops, and with
-    # them the forward pass BELOW the first active layer (same
-    # minibatch, same frozen weights), computed once an L-BFGS iteration
-    # and not once per evaluation. Nothing here splits the model by
-    # hand; tests/test_tpu_compile.py holds the hoisting. The plan is
-    # static per group; a group that starts at the model's first layer
-    # hoists nothing and only loses the copies.
+    # frozen leaf depends on no probe, and `stage_invariant`
+    # (partition/stage.py) acts on it. The objective is traced once a
+    # step and split by what depends on `x`: the frozen leaves' slices
+    # and relayouts, the forward pass BELOW the first active layer (same
+    # minibatch, same frozen weights), the regulariser's fixed segments
+    # are evaluated once, at the step's level under `fedtpu.invariant`;
+    # every evaluation of the solver (entry, probes, re-evaluation, the
+    # default fan) replays the dependent equations only. The program
+    # takes the invariant part out, not the compiler (which lifted half
+    # of it, by size: PERF.md §6, PRs 32 and 35). Nothing here splits the
+    # model by hand; tests/test_stage.py and tests/test_tpu_compile.py
+    # hold where the convolutions end up. The plan is static per group;
+    # a group that starts at the model's first layer keeps the whole
+    # model in its evaluations and loses nothing.
     plan = leaf_plan(ctx.unravel, ctx.partition, ctx.gid)
 
     # The probe fan (`ls_probes > 1`, docs/PERF.md §Widened GEMM): the
@@ -421,6 +428,10 @@ def _client_train_step(ctx: GroupContext):
         def objective(x):
             return objective_at(lambda xc: assemble(plan, frozen, xc), x)
 
+        x0 = ctx.partition.extract(flat, ctx.gid)
+        with scope("fedtpu.invariant"):
+            objective = stage_invariant(objective, x0)
+
         if fold:
             loss_fn = objective
         else:
@@ -447,7 +458,6 @@ def _client_train_step(ctx: GroupContext):
         else:
             fan_fn = None
 
-        x0 = ctx.partition.extract(flat, ctx.gid)
         x1, lstate, aux = lbfgs_step(
             loss_fn, x0, lstate, ctx.lbfgs, has_aux=fold, fan_fn=fan_fn
         )

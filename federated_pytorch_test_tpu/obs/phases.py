@@ -50,6 +50,9 @@ UNATTRIBUTED = "unattributed"
 # (docs/OBSERVABILITY.md §Phase scopes and --profile-dir)
 PHASES = (
     "batch_gather",  # step_all's take_along_axis pair (engine/steps.py)
+    "invariant",  # what of the client step's objective no probe can
+    # change, once a lockstep step (partition/stage.py): the forward
+    # below the first active layer, the frozen leaves' relayouts
     "grad_eval",  # lbfgs_step's value_and_grad sites: entry and reeval
     "direction",  # where(first_ever, -g, direction_fn(...)): the contractions
     "history",  # ring_push: a [R, 128] slab into lanes, read back and
