@@ -411,6 +411,12 @@ def _client_train_step(ctx: GroupContext):
             for s in ctx.reg_segments
         )
 
+        # The objective holds no collective, and must not: under
+        # `shard_map` every device runs the solver's loop, and the
+        # re-evaluation's conditional inside it, as its own block of
+        # clients decides (optim/lbfgs.py `_any_client`, `_reevaluate`),
+        # so two devices may run different numbers of evaluations. A
+        # collective here would wait for a device that never comes.
         def objective_at(params_of, x):
             # the active group substituted into the PRE-CAST remainder is
             # numerically identical to casting inside: the frozen

@@ -2628,10 +2628,11 @@ class Trainer:
         by the round-init program, so they are the round's totals.
         `func_evals` counts gradient evaluations (entry + re-evaluations),
         `ls_evals` the forward-only Armijo probes, `n_iter` the inner
-        iterations (optim/lbfgs.py LBFGSState)."""
+        iterations, `grad_evals` the gradient evaluations the device ran
+        on the client's lane, kept or not (optim/lbfgs.py LBFGSState)."""
         return {
             name: [int(v) for v in self._fetch(getattr(lstate, name))]
-            for name in ("n_iter", "func_evals", "ls_evals")
+            for name in ("n_iter", "func_evals", "ls_evals", "grad_evals")
         }
 
     def _run_round_unfused(self, nloop: int, gid: int) -> None:

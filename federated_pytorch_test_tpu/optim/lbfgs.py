@@ -162,11 +162,19 @@ class LBFGSState(NamedTuple):
     # 0). Separate from `func_evals` on purpose: func_evals keeps its
     # historical meaning (entry + re-evaluations — the quantity the
     # `max_eval` budget is charged against), while this counter makes the
-    # line search's forward passes visible — the roofline quantity
-    # bench.py's `mean_func_evals_per_step` reports (func_evals +
-    # ls_evals per step). Under `ls_probes > 1` one widened fan charges
-    # its full fan width: the amortization is honest, not hidden.
+    # line search's forward passes visible — with `func_evals`, what the
+    # trainer's `solver_work` series carries out of each round and the
+    # benchmark's `solver_evals_per_step` reads (func_evals + ls_evals
+    # per step). Under `ls_probes > 1` one widened fan charges its full
+    # fan width: the amortization is honest, not hidden.
     ls_evals: jnp.ndarray
+    # i32, cumulative gradient evaluations the device RAN on this
+    # client's lane: the entry evaluation and every re-evaluation its
+    # block ran, whether or not this client kept the result (see
+    # `_reevaluate`). Unbatched it equals `func_evals`; under the client
+    # vmap it is at least `func_evals`, and the difference is the work a
+    # sibling's need made this client do and throw away
+    grad_evals: jnp.ndarray
 
 
 class LBFGSAux(NamedTuple):
@@ -222,6 +230,7 @@ def lbfgs_init(x0: jnp.ndarray, config: LBFGSConfig) -> LBFGSState:
         running_avg=z,
         running_avg_sq=z,
         ls_evals=jnp.int32(0),
+        grad_evals=jnp.int32(0),
     )
 
 
@@ -295,6 +304,7 @@ class _Carry(NamedTuple):
     aux: Any  # user aux of the last evaluation at the carry's x
     aux_ok: jnp.ndarray  # False while x was produced by the NaN fallback
     ls_evals: jnp.ndarray  # i32, Armijo probe evaluations this step
+    grad_evals: jnp.ndarray  # i32, gradient evaluations run this step
     # the loop's predicate: `_any_client` of the clients' `active`
     go: jnp.ndarray
 
@@ -335,6 +345,32 @@ def _any_client(flag):
 def _any_client_vmap(axis_size, in_batched, flag):
     del axis_size, in_batched
     return jnp.any(flag), False
+
+
+def _reevaluate(stop_now, frozen, keep, reeval):
+    """`keep()` where the client stops now, else `reeval()`; and whether
+    the re-evaluation ran on the client's lane.
+
+    A `lax.cond` on the client's own `stop_now` is lowered to a select
+    under the client vmap, so BOTH branches run: the re-evaluation's
+    forward and backward pass ran in every iteration, also the one in
+    which every client stops (the iteration cap) and all is discarded.
+    Here the conditional's predicate is the block's, as for the loop
+    (`_any_client`): the pass runs when ANY live client needs it, and
+    each client then picks by its own `stop_now` — the select that ran
+    before, on the same values. It is taken after the conditional, where
+    the compiler fuses it with the freeze at the body's end. A frozen
+    client needs nothing: the freeze discards its result either way.
+    Under `shard_map` each device decides for its own block; the
+    objective holds no collective (engine/steps.py), so devices that
+    disagree cannot deadlock — as for the loop's own trip count.
+    """
+    need = _any_client(~stop_now & ~frozen)
+    fresh = lax.cond(need, lambda _: reeval(), lambda _: keep(), None)
+    return (
+        jax.tree.map(lambda k, r: jnp.where(stop_now, k, r), keep(), fresh),
+        need,
+    )
 
 
 def lbfgs_step(
@@ -551,7 +587,7 @@ def lbfgs_step(
             | (jnp.sum(jnp.abs(t * d)) <= tol_change)
         )
 
-        def reeval(_):
+        def reeval():
             (l, aux_r), gg = value_and_grad(x)
             # the re-evaluation IS at x, whatever step-size fallback
             # produced it — aux becomes valid again (| True keeps
@@ -560,11 +596,11 @@ def lbfgs_step(
                 aux_ok_new | True
             )
 
-        def keep(_):
+        def keep():
             return c.loss, c.g, c.abs_grad_sum, c.evals, aux_new, aux_ok_new
 
-        loss, g, abs_grad_sum, evals, aux_new, aux_ok_new = lax.cond(
-            stop_now, keep, reeval, None
+        (loss, g, abs_grad_sum, evals, aux_new, aux_ok_new), ran = _reevaluate(
+            stop_now, frozen, keep, reeval
         )
 
         done = (
@@ -574,11 +610,13 @@ def lbfgs_step(
             | (jnp.abs(loss - prev_loss) < tol_change)
         )
 
-        # The freeze goes over everything but the histories and `go`
-        # (None: no leaf). The histories froze in `ring_push` — a frozen
-        # client's row was rewritten with itself — where a select here
-        # would read and rewrite both whole, every iteration. `go` is
-        # the block's, not the client's: a frozen client must see it fall
+        # The freeze goes over everything but the histories, `grad_evals`
+        # and `go` (None: no leaf). The histories froze in `ring_push` — a
+        # frozen client's row was rewritten with itself — where a select
+        # here would read and rewrite both whole, every iteration. The
+        # pass ran on a frozen client's lane too, and `grad_evals` counts
+        # it. `go` is the block's, not the client's: a frozen client must
+        # see it fall
         new = _Carry(
             x=x,
             loss=loss,
@@ -603,16 +641,18 @@ def lbfgs_step(
             aux=aux_new,
             aux_ok=aux_ok_new,
             ls_evals=ls_evals,
+            grad_evals=None,
             go=None,
         )
         kept = jax.tree.map(
             lambda n, o: jnp.where(frozen, o, n),
             new,
-            c._replace(s_hist=None, y_hist=None, go=None),
+            c._replace(s_hist=None, y_hist=None, grad_evals=None, go=None),
         )
         return kept._replace(
             s_hist=s_hist,
             y_hist=y_hist,
+            grad_evals=c.grad_evals + ran.astype(jnp.int32),
             go=_any_client(active(kept.n_inner, kept.done)),
         )
 
@@ -654,6 +694,7 @@ def lbfgs_step(
         aux=aux0,
         aux_ok=vz == 0,
         ls_evals=jnp.int32(0) + iz,
+        grad_evals=jnp.int32(1) + iz,
         go=_any_client(active(n_inner0, done0)),
     )
 
@@ -679,6 +720,7 @@ def lbfgs_step(
         running_avg=final.running_avg,
         running_avg_sq=final.running_avg_sq,
         ls_evals=state.ls_evals + final.ls_evals,
+        grad_evals=state.grad_evals + final.grad_evals,
     )
     aux = LBFGSAux(
         loss=loss0,

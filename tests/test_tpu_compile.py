@@ -187,6 +187,17 @@ def test_pallas_direction_compiles_at_real_widths(one_chip, n, monkeypatch):
 # ------------------------------------------------ the flagship's client step
 
 
+def _branches(line):
+    """The computations a `conditional` instruction names as branches."""
+    return re.findall(
+        r"(?:true_computation|false_computation)=%?([\w.\-]+)", line
+    ) + [
+        c.strip().lstrip("%")
+        for group in re.findall(r"branch_computations=\{([^}]*)\}", line)
+        for c in group.split(",")
+    ]
+
+
 def _reached(comps, name, seen=None):
     """(computation, instruction) for everything `name` runs, the
     computations its fusions, calls and branches name included; a
@@ -200,13 +211,8 @@ def _reached(comps, name, seen=None):
         if ins[2] == "while":
             continue
         for callee in re.findall(
-            r"(?:calls|to_apply|true_computation|false_computation)"
-            r"=%?([\w.\-]+)", ins[3]
-        ) + [
-            c.strip().lstrip("%")
-            for group in re.findall(r"branch_computations=\{([^}]*)\}", ins[3])
-            for c in group.split(",")
-        ]:
+            r"(?:calls|to_apply)=%?([\w.\-]+)", ins[3]
+        ) + _branches(ins[3]):
             yield from _reached(comps, callee, seen)
 
 
@@ -224,11 +230,25 @@ def _convolutions(comps, name):
     return sum(ins[2] == "convolution" for _, ins in _reached(comps, name))
 
 
+def _branch_convolutions(comps, name):
+    """[convolutions in the branches of each `conditional`] that `name`
+    runs (a nested loop's body not entered)."""
+    return [
+        sum(_convolutions(comps, b) for b in _branches(ins[3]))
+        for _, ins in _reached(comps, name)
+        if ins[2] == "conditional"
+    ]
+
+
 # convolutions of the compiled client step by loop level, (at least at
 # the step's own level, at most in the L-BFGS body, at most in the
-# Armijo body): read 25 / 10 / 3 for group 8 and 40 / 55 / 18 for group
-# 2 when PR 35 split the objective; 25 / 32 / 5 and 40 / 58 / 20 before,
-# when the compiler alone decided what left the loops
+# Armijo body), a conditional's branches counted with the body that
+# runs it: read 25 / 10 / 3 for group 8 and 40 / 55 / 18 for group 2
+# with the objective split by dependence on `x`; 25 / 32 / 5 and
+# 40 / 58 / 20 before, when the compiler alone decided what left the
+# loops. The re-evaluation's conditional holds 7 of group 8's 10 and 37
+# of group 2's 55 (its forward and backward pass); the program's
+# scratch reads 1,218,930,176 and 1,342,521,856 bytes
 _CONVOLUTIONS_BY_LEVEL = {
     8: (21, 12, 4, RESNET18_LARGEST_GROUP),  # layer4.1: the longest prefix
     2: (21, 56, 18, 73_984),  # layer1.1: stem and layer1.0 below it
@@ -328,8 +348,14 @@ def test_client_step_keeps_whole_vector_and_frozen_forward_out_of_the_loops(
     counts = tuple(
         _convolutions(comps, c) for c in (entry, lbfgs_body, armijo_body)
     )
-    print(f"group {gid}: convolutions by level {counts}, scratch "
+    # (c) the re-evaluation is a real conditional in the L-BFGS body,
+    # its predicate the block's: its model pass is a branch, skipped in
+    # an iteration in which every client stops
+    branches = _branch_convolutions(comps, lbfgs_body)
+    print(f"group {gid}: convolutions by level {counts}, in the L-BFGS "
+          f"body's conditionals {branches}, scratch "
           f"{compiled.memory_analysis().temp_size_in_bytes} bytes")
+    assert any(branches), branches
     assert (
         counts[0] >= step_least
         and 0 < counts[1] <= lbfgs_most
