@@ -367,12 +367,10 @@ class ExperimentConfig:
     # device profiling of the steady state: every round of the run's
     # SECOND outer loop (the first compiles; the only one if nloop == 1)
     # runs in a jax.profiler window of its own under this directory
-    # (`round-<nloop>-<group>/`, TPU + host timelines), and each fused
-    # round's window is reduced to device seconds by phase of the round
-    # program: `phases.json` here and the process-local `device_phase`
-    # series (obs/phases.py, docs/OBSERVABILITY.md §Phase scopes). Needs
-    # an empty compile cache: a cached executable keeps the metadata it
-    # was stored with, and a table without phase scopes is refused.
+    # (`round-<nloop>-<group>/`, TPU + host timelines), logged as a
+    # process-local `profile_capture` record; scripts/phase_table.py
+    # reads the windows by phase of the round program
+    # (docs/OBSERVABILITY.md §Phase scopes).
     profile_dir: str | None = None
 
     # --- observability (obs/, docs/OBSERVABILITY.md) ---

@@ -90,7 +90,6 @@ from federated_pytorch_test_tpu.obs import (
     memory_record,
     roofline_record,
 )
-from federated_pytorch_test_tpu.obs import phases as obs_phases
 from federated_pytorch_test_tpu.obs.sinks import jsonable
 from jax.sharding import NamedSharding, PartitionSpec
 
@@ -392,9 +391,7 @@ class Trainer:
         self.stats = jax.tree.map(lambda x: _put(x, csh), stats)
 
         # training-data placement: resident (default) or host-streaming
-        # when the dataset exceeds the HBM budget (see config;
-        # VERDICT round-1 weak #5 — the native PrefetchBatcher existed but
-        # the engine could only train device-resident data)
+        # when the dataset exceeds the HBM budget (see config)
         data_bytes = (
             self.fed.train_images.nbytes + self.fed.train_labels.nbytes
         )
@@ -421,9 +418,9 @@ class Trainer:
 
             self.shard_imgs = None
             self.shard_labels = None
-            # HOST-SHARDED streaming (round-4 VERDICT item 8): each
-            # process batches only the clients whose mesh devices it
-            # owns — the natural extension of the per-client batchers.
+            # HOST-SHARDED streaming: each process batches only the
+            # clients whose mesh devices it owns — the natural extension
+            # of the per-client batchers.
             # `_put`'s make_array_from_callback path then assembles the
             # global chunk with each process supplying its own columns;
             # streams are pure functions of (seed, batch, client), so
@@ -643,13 +640,8 @@ class Trainer:
         self._profile_captures = 0
         # `--profile-dir`: the outer loop whose rounds `run()` captures,
         # one window per round (set by `_run_impl`; driving `run_round`
-        # by hand captures nothing), the compiled round programs'
-        # {instruction: phase} tables (own metadata, inferred) and the
-        # per-group reductions that `phases.json` holds
+        # by hand captures nothing)
         self._profile_loop: Optional[int] = None
-        self._profile_compiles = False
-        self._phase_tables: Dict[int, tuple] = {}
-        self._phase_report: Dict[str, dict] = {}
         # storage_fault incident rising edge: detections + repairs the
         # store has surfaced that a previous round already reported
         self._storage_fault_seen = 0
@@ -2064,12 +2056,6 @@ class Trainer:
                 # the epoch / consensus programs would never be dispatched
                 compiled = self._lower_round(gid).compile()
                 self._stash_round_cost(gid, compiled)
-                if self.cfg.profile_dir:
-                    text = compiled.as_text()
-                    table = obs_phases.op_phase_table(text)
-                    self._phase_tables[gid] = (
-                        table, obs_phases.inferred_phases(text, table)
-                    )
                 return time.perf_counter() - t0
             epoch_fn, consensus_fn, init_fn = self._fns(gid)
             lstate, y, z, rho, extra = init_fn(self.flat)
@@ -2153,6 +2139,18 @@ class Trainer:
             y, z, rho, extra, masks, *ef_args, *budget_args,
             *corr_args, *eval_args,
         )
+
+    def round_program_text(self, gid: int) -> str:
+        """The optimized HLO text of group `gid`'s compiled fused round
+        program: the AOT lowering `compile_round` makes, compiled (with
+        the persistent compile cache, a cache load). Instruction names
+        are those a profiler trace of the round program shows, and each
+        instruction's `op_name` carries the `fedtpu.<phase>` scopes it
+        was traced under (obs/phases.py), so a trace can be joined with
+        it by name (chipbench/phase_join.py, scripts/phase_table.py). A
+        cached executable keeps the metadata of the build that stored it.
+        Only the round-init program executes."""
+        return self._lower_round(gid).compile().as_text()
 
     def _stash_round_cost(self, gid: int, compiled) -> None:
         """Record the AOT-compiled round program's exact XLA FLOP/byte
@@ -2243,18 +2241,16 @@ class Trainer:
         trajectories.
 
         This wrapper is the round's observability boundary (obs/): one
-        trace span covering the round, per-round `dispatch_count` /
-        `recompile_count` deltas, the `--diagnostics-every` cadence, the
-        health digest + `memory` record, the flight recorder's incident
-        dump, the profiler window of either profiler knob
-        (`_profile_window`), the `watch` status sidecar, and the
-        per-round sink flush. The `health` record is
-        logged BEFORE `dispatch_count`, which is therefore the round's
-        FINAL streamed record in both trainer paths — the flight ring's
-        segmentation boundary (obs/flight.py). An injected crash skips
-        the per-round counters (their round never completed; the resumed
-        run re-records it) but still flushes, so the crashed stream
-        holds everything the round logged.
+        trace span covering the round (`round`), the profiler window of
+        either profiler knob (`_profile_window`), and one span covering
+        everything the host does after it (`round_close`, `_close_round`):
+        the per-round sink flush, the `dispatch_count` / `recompile_count`
+        deltas, the `--diagnostics-every` cadence, the health digest +
+        `memory` record, the flight recorder's incident dump and the
+        `watch` status sidecar. An injected crash skips `round_close`
+        (its round never completed; the resumed run re-records it) but
+        still flushes, so the crashed stream holds everything the round
+        logged.
         """
         # one jax.profiler window around this round, if either profiler
         # knob asks for one; decided before the counter snapshots, so
@@ -2283,18 +2279,34 @@ class Trainer:
                         self._run_round_fused(nloop, gid)
                     else:
                         self._run_round_unfused(nloop, gid)
-        finally:
+        except BaseException:
             self.recorder.flush()
+            raise
+        # what the host does after the round until this call returns is
+        # one span (`fedtpu:round_close` on the profiler's clock): a
+        # device trace names the host's work between two round programs
+        with self.recorder.phase(
+            "round_close", record=False, nloop=nloop, group=gid
+        ):
+            self.recorder.flush()
+            self._close_round(nloop, gid, prof_dir, before, compiled_before)
+
+    def _close_round(
+        self, nloop: int, gid: int, prof_dir: Optional[str], before,
+        compiled_before: int,
+    ) -> None:
+        """`run_round`'s bookkeeping after a completed round: counters,
+        diagnostics, health, memory, incidents, the status sidecar. The
+        `health` record is logged BEFORE `dispatch_count`, which is
+        therefore the round's FINAL streamed record in both trainer paths
+        — the flight ring's segmentation boundary (obs/flight.py)."""
         if prof_dir is not None:
             # a capture is a fact about THIS process (a resumed run
             # re-arms from its own alerts): stream=False, like roofline
-            if self.cfg.profile_dir:
-                self._record_device_phase(prof_dir, nloop, gid)
-            else:
-                self.recorder.log(
-                    "profile_capture", {"dir": prof_dir}, stream=False,
-                    nloop=nloop, group=gid,
-                )
+            self.recorder.log(
+                "profile_capture", {"dir": prof_dir}, stream=False,
+                nloop=nloop, group=gid,
+            )
         self._rounds_done += 1
         # the diagnostics sample runs BEFORE the delta is taken, so its
         # dispatch (and first-use compile) land in THIS round's
@@ -2435,13 +2447,8 @@ class Trainer:
         armed it; this round is captured, bounded by the per-process
         budget — profiling that costs nothing until something is wrong.
         `--profile-dir DIR`: every round of `_profile_loop`, one window
-        each, so a window holds ONE round program's launch and
-        instruction names that repeat across groups' programs cannot
-        mix. A fused round's window is reduced afterwards
-        (`_record_device_phase`); its {instruction: phase} table is made
-        here, BEFORE the window opens, from one AOT lowering of the
-        round program (a compile-cache load) — nothing of this runs in
-        an unprofiled round."""
+        each, kept raw like the anomaly captures (scripts/phase_table.py
+        reads them by phase)."""
         cfg = self.cfg
         if self._profile_pending:
             self._profile_pending = False
@@ -2451,56 +2458,11 @@ class Trainer:
             root = cfg.profile_on_anomaly
         elif cfg.profile_dir and nloop == self._profile_loop:
             root = cfg.profile_dir
-            if self._fused_enabled() and gid not in self._phase_tables:
-                self.compile_round(gid)
         else:
             return None
         prof_dir = os.path.join(root, f"round-{nloop}-{gid}")
         os.makedirs(prof_dir, exist_ok=True)
         return prof_dir
-
-    def _record_device_phase(self, prof_dir: str, nloop: int, gid: int) -> None:
-        """Reduce one `--profile-dir` window to device seconds by phase
-        (obs/phases.py), log it as `device_phase` and rewrite
-        `<profile_dir>/phases.json` (per group: seconds and share by
-        phase, `unattributed`, `busy_s`, the round's wall, the costliest
-        instructions with their phase). An unfused round's window is
-        kept unreduced: its programs are many, and the join by
-        instruction name needs one program per window."""
-        rec = {
-            "dir": prof_dir,
-            "nloop": int(nloop),
-            # a run of one loop has no second loop to capture
-            "compilation_inside": self._profile_compiles,
-            "round_wall_s": next(
-                (
-                    r["value"]["seconds"]
-                    for r in reversed(self.recorder.series.get("step_time", []))
-                    if r["value"]["phase"] == "fused_round"
-                    and (r.get("nloop"), r.get("group")) == (nloop, gid)
-                ),
-                None,
-            ),
-        }
-        if gid in self._phase_tables:
-            t0 = time.perf_counter()
-            events = obs_phases.load_device_events(
-                obs_phases.find_xplane(prof_dir)
-            )
-            rec.update(
-                obs_phases.device_seconds_by_phase(
-                    events, *self._phase_tables[gid]
-                )
-            )
-            rec["reduce_s"] = time.perf_counter() - t0
-        self.recorder.log(
-            "device_phase", rec, stream=False, nloop=nloop, group=gid
-        )
-        self._phase_report[str(gid)] = rec
-        path = os.path.join(self.cfg.profile_dir, "phases.json")
-        with open(path + ".tmp", "w") as f:
-            json.dump({"groups": self._phase_report}, f, indent=1)
-        os.replace(path + ".tmp", path)
 
     def _incident_extra(self) -> dict:
         """The non-ring half of an incident bundle (obs/flight.py): the
@@ -3334,12 +3296,12 @@ class Trainer:
         """The full experiment (all Nloop outer loops).
 
         With `cfg.profile_dir` set, every round of the run's SECOND outer
-        loop (the first compiles; the only one if `nloop == 1`, and the
-        record then says compilation is inside) is captured in a
-        jax.profiler window of its own, `<profile_dir>/round-<nloop>-<gid>/`
-        (device + host timelines, viewable in TensorBoard/Perfetto), and
-        reduced to `<profile_dir>/phases.json`: device seconds by phase
-        of the round program (obs/phases.py, `run_round`).
+        loop (the first compiles; the only one if `nloop == 1`) is
+        captured in a jax.profiler window of its own,
+        `<profile_dir>/round-<nloop>-<gid>/` (device + host timelines,
+        viewable in TensorBoard/Perfetto) and logged as a
+        `profile_capture` record; `scripts/phase_table.py` reads the
+        windows by phase of the round program.
         `cfg.trace_out` is the complementary HOST-side trace: the loop
         nest's round/epoch/consensus/eval/compile spans as Chrome
         trace-event JSON (obs/trace.py), written even when the run dies on
@@ -3430,7 +3392,6 @@ class Trainer:
         # `--profile-dir` captures the second loop this process runs: the
         # first holds every compilation
         self._profile_loop = min(self._completed_nloops + 1, cfg.nloop - 1)
-        self._profile_compiles = self._profile_loop == self._completed_nloops
         for nloop in range(self._completed_nloops, cfg.nloop):
             self.run_loop(nloop)
             self._completed_nloops = nloop + 1

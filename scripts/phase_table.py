@@ -4,19 +4,19 @@
 
 Builds the cell's `ExperimentConfig` and data the way `chipbench/run.py`
 does (configuration file, traffic file, `--seed`), runs `Trainer.run()`
-for `--nloop` outer loops with `profile_dir` set — so every round of the
-second loop is captured in a profiler window of its own and reduced
-(engine/trainer.py `_record_device_phase`, obs/phases.py) — and prints
-the table of `phases.json` per group, each round's `fused_round` wall by
-loop (the captured loop against the others: what tracing costs when on)
-and the seconds each reduction took. `phases.json` is copied to
-`chiprun_out/phases/<workload>/`; the raw traces (tens of MB) stay in a
-temporary directory and go with it.
+for `--nloop` outer loops with `profile_dir` set, so every round of the
+second loop runs in a raw profiler window of its own (a
+`profile_capture` record each), and reads each window with the
+benchmark's join (chipbench/phase_join.py) against the round program's
+text (`Trainer.round_program_text`). Prints the table per group, each
+round's `fused_round` wall by loop (the captured loop against the
+others: what a window costs when on) and the seconds each join took.
+The tables are written to `chiprun_out/phases/<workload>/phases.json`;
+the raw traces (tens of MB) stay in a temporary directory and go with it.
 
-Run it against an EMPTY compile cache (`JAX_COMPILATION_CACHE_DIR` at a
-new directory): the cache keys executables without their metadata, so a
-round program cached by a build without the phase scopes is refused
-(`StaleMetadataError`), not reported.
+The compile cache keys executables without their metadata: a round
+program cached by a build whose scopes differ is refused, not reported.
+Point `JAX_COMPILATION_CACHE_DIR` at an empty directory when in doubt.
 
 The expected backend is a Python argument of `main`, as in
 `chipbench.run`: the command itself refuses anything but a TPU.
@@ -30,6 +30,7 @@ import os
 import shutil
 import sys
 import tempfile
+import time
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 if REPO not in sys.path:
@@ -47,7 +48,7 @@ def main(argv=None, expect_backend: str = "tpu") -> int:
                     help="another BENCHMARK.json (its files are found beside it)")
     args = ap.parse_args(argv)
 
-    from chipbench import spec
+    from chipbench import phase_join, spec, trace_reduce
     from chipbench.run import _sizes
 
     cell = spec.load_cell(args.workload, args.benchmark or spec.DEFAULT_BENCHMARK)
@@ -62,7 +63,7 @@ def main(argv=None, expect_backend: str = "tpu") -> int:
 
     from federated_pytorch_test_tpu.data import synthetic_cifar
     from federated_pytorch_test_tpu.engine import Trainer, get_preset
-    from federated_pytorch_test_tpu.obs.phases import PHASES, UNATTRIBUTED
+    from federated_pytorch_test_tpu.obs.phases import PHASES
     from federated_pytorch_test_tpu.utils import enable_compile_cache
 
     cache_dir = enable_compile_cache()
@@ -76,6 +77,7 @@ def main(argv=None, expect_backend: str = "tpu") -> int:
           flush=True)
 
     prof = tempfile.mkdtemp(prefix="phase_table_")
+    joins = {}
     try:
         cfg = get_preset(
             cell.traffic["preset"], seed=args.seed, nloop=args.nloop,
@@ -87,34 +89,52 @@ def main(argv=None, expect_backend: str = "tpu") -> int:
             n_train=n_train, n_test=n_test, seed=args.seed,
             **cell.traffic["data"].get("synthetic", {}),
         )
-        rec = Trainer(cfg, verbose=False, source=source).run()
-        out = os.path.join(REPO, "chiprun_out", "phases", cell.name)
-        os.makedirs(out, exist_ok=True)
-        shutil.copy(os.path.join(prof, "phases.json"), out)
+        tr = Trainer(cfg, verbose=False, source=source)
+        rec = tr.run()
+        for r in rec.series["profile_capture"]:
+            gid = r["group"]
+            t0 = time.perf_counter()
+            events = trace_reduce.load_xplane(
+                trace_reduce.find_xplane(r["value"]["dir"])
+            )
+            got = phase_join.join(events, tr.round_program_text, [gid])
+            joins[gid] = dict(got, nloop=r["nloop"], join_s=time.perf_counter() - t0)
     finally:
         shutil.rmtree(prof, ignore_errors=True)
+    out = os.path.join(REPO, "chiprun_out", "phases", cell.name)
+    os.makedirs(out, exist_ok=True)
+    with open(os.path.join(out, "phases.json"), "w") as f:
+        json.dump({"groups": joins}, f, indent=1)
 
     walls: dict = {}
     for r in rec.series["step_time"]:
         if r["value"]["phase"] == "fused_round":
             walls.setdefault(r["group"], {})[r["nloop"]] = r["value"]["seconds"]
-    for r in rec.series["device_phase"]:
-        v, gid = r["value"], r["group"]
-        print(f"\n## group {gid}, loop {v['nloop']}: module {v['module']} "
-              f"{v['module_s']:.6f} s, busy {v['busy_s']:.6f} s on "
-              f"{v['devices']} device(s), compilation_inside="
-              f"{v['compilation_inside']}, reduction {v['reduce_s']:.3f} s")
+    for gid, got in joins.items():
+        print(f"\n## group {gid}, loop {got['nloop']}: join {got['join_s']:.3f} s")
+        if got["refused"]:
+            print(f"refused: {got['refused']}")
+            continue
+        (v,) = got["rounds"]
+        print(f"module {v['launch']} {v['launch_s']:.6f} s, busy {v['busy_s']:.6f} s "
+              f"on {v['devices']} device(s), {100 * v['coverage']:.3f}% of it "
+              "found in the program text")
         print("| phase | seconds | share of busy |\n|---|---|---|")
-        for p in PHASES + (UNATTRIBUTED,):
-            s = v[UNATTRIBUTED] if p == UNATTRIBUTED else v["seconds"][p]
-            print(f"| {p} | {s:.6f} | {100 * v['share'][p]:.3f}% |")
+        for p in PHASES + (phase_join.UNATTRIBUTED,):
+            s = v["seconds"].get(p, 0.0)
+            print(f"| {p} | {s:.6f} | {100 * s / v['busy_s']:.3f}% |")
         print(f"placed by inference (users, container): {v['inferred_s']:.6f} s")
         for title in ("top", "unattributed_top"):
             print(f"{title} (self time; * = phase inferred):")
             for name, phase, s, inferred in v[title]:
                 print(f"  {s:10.6f} s  {phase + '*' * inferred:14s} {name}")
         print("fused_round wall by loop:", json.dumps(walls[gid]))
-    work = [(r["nloop"], r["group"], r["value"]) for r in rec.series["solver_work"]]
+    joined = [got["rounds"][0]["seconds"] for got in joins.values() if not got["refused"]]
+    if len(joined) > 1:  # what the benchmark's `device_ms_<phase>` average
+        print(f"\n## mean of {len(joined)} groups, ms a round program")
+        for p in PHASES + (phase_join.UNATTRIBUTED,):
+            print(f"| {p} | {1e3 * sum(s.get(p, 0.0) for s in joined) / len(joined):.3f} |")
+    work =[(r["nloop"], r["group"], r["value"]) for r in rec.series["solver_work"]]
     print("\nsolver_work by round:", json.dumps(work))
     return 0
 
