@@ -2591,10 +2591,13 @@ class Trainer:
         `func_evals` counts gradient evaluations (entry + re-evaluations),
         `ls_evals` the forward-only Armijo probes, `n_iter` the inner
         iterations, `grad_evals` the gradient evaluations the device ran
-        on the client's lane, kept or not (optim/lbfgs.py LBFGSState)."""
+        on the client's lane, kept or not, `hist_pushes` the pairs the
+        client's history ring took (optim/lbfgs.py LBFGSState)."""
         return {
             name: [int(v) for v in self._fetch(getattr(lstate, name))]
-            for name in ("n_iter", "func_evals", "ls_evals", "grad_evals")
+            for name in (
+                "n_iter", "func_evals", "ls_evals", "grad_evals", "hist_pushes"
+            )
         }
 
     def _run_round_unfused(self, nloop: int, gid: int) -> None:

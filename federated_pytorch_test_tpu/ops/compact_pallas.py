@@ -1,12 +1,13 @@
 """Fused Pallas kernels for the compact-representation L-BFGS direction.
 
-`optim/compact.py` computes -H·g (Byrd–Nocedal compact form) as a chain of
-XLA ops whose heavy terms each re-read the history buffers from HBM:
-`S Yᵀ`, `Sᵀg`, `Yᵀg`, `u @ Y`, `w @ S` — several history-sized HBM passes
-per direction, with N up to ~11M (ResNet18) and m = 10. The arithmetic is
-trivial next to the bandwidth, so fusing passes is the whole game (the
-reference's two-loop recursion, src/lbfgsnew.py:615-637, is even worse:
-2m sequentially-dependent BLAS1 passes).
+`optim/compact.py` computes -H·g (Byrd–Nocedal compact form) as XLA
+fusions over the history buffers: `S Yᵀ`, `Y Yᵀ`, `Sᵀg`, `Yᵀg` in one
+multiply-reduce, `w @ S − γ·(u @ Y)` in one elementwise pass, with N up to
+~11M (ResNet18) and m = 10. The arithmetic is trivial next to the
+bandwidth, so fusing passes is the whole game (the reference's two-loop
+recursion, src/lbfgsnew.py:615-637, is far worse: 2m
+sequentially-dependent BLAS1 passes). These kernels are the same two
+passes written by hand.
 
 The histories are `[m, R, 128]`, every pair laid out in lanes
 (optim/history.py), and the kernels read them as they lie: a grid step
@@ -221,10 +222,7 @@ def compact_direction_pallas(g, s_hist, y_hist, count, h_diag, oldest=0):
     sy, yy, p, q = fused_gram_projections(s32, y32, g32, count)
 
     valid = jnp.arange(m) < count
-    u, w, _, _ = compact_solves(
-        sy, p, q, valid, h_diag.astype(f32), lambda u: (yy @ u, None),
-        oldest,
-    )
+    u, w, _ = compact_solves(sy, yy, p, q, valid, h_diag.astype(f32), oldest)
 
     hg = fused_direction_assembly(s32, y32, g32, w, u, h_diag, count)
     return (-hg).astype(dt)

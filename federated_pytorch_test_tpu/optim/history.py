@@ -15,7 +15,7 @@ and in every row pushed, so they add nothing to any contraction. `R`
 follows from `N` alone; this module is the only place that spells the
 shape: `empty_history` makes a buffer (`history_of`, one from a stack of
 vectors), `to_lanes`/`from_lanes` carry a parameter vector in and out,
-`ring_push` writes a row.
+`ring_push` writes a row and says which.
 """
 
 from __future__ import annotations
@@ -69,7 +69,7 @@ def ring_push(
     s: jnp.ndarray,
     y: jnp.ndarray,
     push: jnp.ndarray,
-) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray, jnp.ndarray]:
+) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray, jnp.ndarray, jnp.ndarray]:
     """Where `push` holds, append (s, y), evicting the oldest pair when full.
 
     `s`, `y` are `[N]` vectors; the buffers are `[m, R, 128]`.
@@ -82,6 +82,10 @@ def ring_push(
     one scatter of a `[R, 128]` slab, under the client `vmap` of K slabs.
     A `lax.cond` or a `where` over the buffers would make `vmap`
     (per-client predicate) read and rewrite both of them whole.
+
+    Returns the buffers, count and oldest after the push, and the row
+    written (`m` where none was): what else the ring carries, the compact
+    direction's Gram, follows that row (`compact.carried_direction`).
     """
     m = s_hist.shape[0]
     row = jnp.where(push, (oldest + count) % m, m)  # == oldest when full
@@ -92,4 +96,5 @@ def ring_push(
         y_hist.at[row].set(to_lanes(y), mode="drop"),
         jnp.where(full, count, count + step),
         jnp.where(full, (oldest + step) % m, oldest),
+        row,
     )

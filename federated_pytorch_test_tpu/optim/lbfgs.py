@@ -26,8 +26,10 @@ Capability parity with the reference's `LBFGSNew` optimizer
   recursion and the compact form's triangular `R` need, is restored
   where it is cheap: the two-loop recursion walks rows
   `(oldest + i) % m`, the compact backends permute their `[m]`/`[m, m]`
-  contractions (`compact.compact_solves`). Rows `>= count` are invalid
-  and masked by select, so shapes stay static and a stale row cannot leak.
+  contractions (`compact.compact_solves`). The compact direction carries
+  `S Yᵀ` and `Y Yᵀ` with the ring, a row and a column per push
+  (`compact.carried_direction`). Rows `>= count` are invalid and masked
+  by select, so shapes stay static and a stale row cannot leak.
 * All of the reference's stochastic-mode machinery is preserved:
   trust-region damping `y += lm0 * s` (reference src/lbfgsnew.py:572-573),
   the online inter-batch gradient mean/variance estimate feeding the
@@ -57,7 +59,7 @@ import jax.numpy as jnp
 from jax import lax
 
 from federated_pytorch_test_tpu.obs.phases import scope, scoped
-from federated_pytorch_test_tpu.optim.compact import compact_direction
+from federated_pytorch_test_tpu.optim.compact import carried_direction
 from federated_pytorch_test_tpu.optim.history import (
     empty_history,
     from_lanes,
@@ -148,6 +150,11 @@ class LBFGSState(NamedTuple):
     # i32, the ring row holding the OLDEST pair; pair i (oldest first)
     # lives in row (hist_oldest + i) % m. 0 until the ring is full
     hist_oldest: jnp.ndarray
+    # [m, m] S Yᵀ and Y Yᵀ of the rings, in storage order (entry [i, j]
+    # pairs rows i and j), updated a row and a column per push
+    # (compact.carried_direction); None unless `direction='compact'`
+    hist_sy: Any
+    hist_yy: Any
     h_diag: jnp.ndarray  # f32, initial inverse-Hessian scale
     d: jnp.ndarray  # [N] last search direction
     t: jnp.ndarray  # f32, last step size
@@ -175,6 +182,9 @@ class LBFGSState(NamedTuple):
     # vmap it is at least `func_evals`, and the difference is the work a
     # sibling's need made this client do and throw away
     grad_evals: jnp.ndarray
+    # i32, cumulative pairs this client's ring took (`ring_push` with its
+    # predicate true): the rows the carried Gram was given
+    hist_pushes: jnp.ndarray
 
 
 class LBFGSAux(NamedTuple):
@@ -215,11 +225,14 @@ def lbfgs_init(x0: jnp.ndarray, config: LBFGSConfig) -> LBFGSState:
     m = config.history_size
     dt = x0.dtype
     z = jnp.zeros((n,), dt)
+    gram = jnp.zeros((m, m), dt) if config.direction == "compact" else None
     return LBFGSState(
         s_hist=empty_history(m, n, dt),
         y_hist=empty_history(m, n, dt),
         hist_count=jnp.int32(0),
         hist_oldest=jnp.int32(0),
+        hist_sy=gram,
+        hist_yy=gram,
         h_diag=jnp.asarray(1.0, dt),
         d=z,
         t=jnp.asarray(config.lr, dt),
@@ -231,6 +244,7 @@ def lbfgs_init(x0: jnp.ndarray, config: LBFGSConfig) -> LBFGSState:
         running_avg_sq=z,
         ls_evals=jnp.int32(0),
         grad_evals=jnp.int32(0),
+        hist_pushes=jnp.int32(0),
     )
 
 
@@ -291,6 +305,9 @@ class _Carry(NamedTuple):
     y_hist: jnp.ndarray
     hist_count: jnp.ndarray
     hist_oldest: jnp.ndarray
+    hist_sy: Any  # [m, m], or None: see LBFGSState
+    hist_yy: Any
+    hist_pushes: jnp.ndarray  # i32, cumulative
     h_diag: jnp.ndarray
     prev_grad: jnp.ndarray
     prev_loss: jnp.ndarray
@@ -440,8 +457,10 @@ def lbfgs_step(
     def cond(c: _Carry):
         return c.go
 
+    # 'compact' carries its Gram with the ring (`carried_direction`);
+    # the other two backends read the histories alone
     direction_fn = {
-        "compact": compact_direction,
+        "compact": carried_direction,
         "two_loop": _two_loop_direction,
         "pallas": _pallas_direction,
     }[config.direction]
@@ -495,13 +514,14 @@ def lbfgs_step(
         ravgsq = jnp.where(first_ever, jnp.zeros_like(ravgsq), ravgsq)
 
         accept = (ys > 1e-10 * ss) & (~batch_changed) & (~first_ever)
+        push = accept & ~frozen
 
         with scope("fedtpu.history"):
-            s_hist, y_hist, hist_count, hist_oldest = ring_push(
+            s_hist, y_hist, hist_count, hist_oldest, row = ring_push(
                 c.s_hist, c.y_hist,
                 jnp.where(first_ever, 0, c.hist_count),
                 jnp.where(first_ever, 0, c.hist_oldest),
-                s, y, accept & ~frozen,
+                s, y, push,
             )
         yy = jnp.dot(y, y)
         h_new = jnp.where(yy != 0.0, ys / jnp.where(yy != 0.0, yy, 1.0), c.h_diag)
@@ -515,15 +535,21 @@ def lbfgs_step(
 
         # a select, not a `lax.cond` with the histories among its
         # operands: under the client vmap a cond on a per-client
-        # predicate selects over every operand and result
+        # predicate selects over every operand and result. A reset
+        # (first_ever) keeps the carried Gram: its entries pair rows
+        # that stay, and rows >= count are masked in the solves
         with scope("fedtpu.direction"):
-            d = jnp.where(
-                first_ever,
-                -c.g,
-                direction_fn(
+            if direction_fn is carried_direction:
+                d_hist, hist_sy, hist_yy = carried_direction(
+                    c.g, s_hist, y_hist, hist_count, h_diag, hist_oldest,
+                    c.hist_sy, c.hist_yy, row, s, y,
+                )
+            else:
+                d_hist = direction_fn(
                     c.g, s_hist, y_hist, hist_count, h_diag, hist_oldest
-                ),
-            )
+                )
+                hist_sy, hist_yy = c.hist_sy, c.hist_yy
+            d = jnp.where(first_ever, -c.g, d_hist)
 
         prev_grad = c.g
         prev_loss = c.loss
@@ -610,10 +636,12 @@ def lbfgs_step(
             | (jnp.abs(loss - prev_loss) < tol_change)
         )
 
-        # The freeze goes over everything but the histories, `grad_evals`
-        # and `go` (None: no leaf). The histories froze in `ring_push` — a
-        # frozen client's row was rewritten with itself — where a select
-        # here would read and rewrite both whole, every iteration. The
+        # The freeze goes over everything but the histories, their Gram,
+        # `grad_evals` and `go` (None: no leaf). The histories froze in
+        # `ring_push` — a frozen client's row is `m`, out of bounds, and
+        # its write dropped — where a select here would read and rewrite
+        # both whole, every iteration; the Gram selects no entry for row
+        # `m`, and `hist_pushes` counted `push`, false for it. The
         # pass ran on a frozen client's lane too, and `grad_evals` counts
         # it. `go` is the block's, not the client's: a frozen client must
         # see it fall
@@ -628,6 +656,9 @@ def lbfgs_step(
             y_hist=None,
             hist_count=hist_count,
             hist_oldest=hist_oldest,
+            hist_sy=None,
+            hist_yy=None,
+            hist_pushes=c.hist_pushes + push.astype(jnp.int32),
             h_diag=h_diag,
             prev_grad=prev_grad,
             prev_loss=prev_loss,
@@ -647,11 +678,16 @@ def lbfgs_step(
         kept = jax.tree.map(
             lambda n, o: jnp.where(frozen, o, n),
             new,
-            c._replace(s_hist=None, y_hist=None, grad_evals=None, go=None),
+            c._replace(
+                s_hist=None, y_hist=None, hist_sy=None, hist_yy=None,
+                grad_evals=None, go=None,
+            ),
         )
         return kept._replace(
             s_hist=s_hist,
             y_hist=y_hist,
+            hist_sy=hist_sy,
+            hist_yy=hist_yy,
             grad_evals=c.grad_evals + ran.astype(jnp.int32),
             go=_any_client(active(kept.n_inner, kept.done)),
         )
@@ -679,6 +715,9 @@ def lbfgs_step(
         y_hist=_match_vma(state.y_hist, loss0),
         hist_count=state.hist_count + iz,
         hist_oldest=state.hist_oldest + iz,
+        hist_sy=jax.tree.map(lambda a: a + vz, state.hist_sy),
+        hist_yy=jax.tree.map(lambda a: a + vz, state.hist_yy),
+        hist_pushes=state.hist_pushes + iz,
         h_diag=state.h_diag + vz,
         prev_grad=state.prev_grad + vz,
         prev_loss=state.prev_loss + vz,
@@ -710,6 +749,8 @@ def lbfgs_step(
         y_hist=final.y_hist,
         hist_count=final.hist_count,
         hist_oldest=final.hist_oldest,
+        hist_sy=final.hist_sy,
+        hist_yy=final.hist_yy,
         h_diag=final.h_diag,
         d=final.d,
         t=final.t,
@@ -721,6 +762,7 @@ def lbfgs_step(
         running_avg_sq=final.running_avg_sq,
         ls_evals=state.ls_evals + final.ls_evals,
         grad_evals=state.grad_evals + final.grad_evals,
+        hist_pushes=final.hist_pushes,
     )
     aux = LBFGSAux(
         loss=loss0,

@@ -1,14 +1,16 @@
 """The L-BFGS history as a ring laid out in lanes (optim/history.py).
 
-Four things are held here, in the fast tier (tests/test_lbfgs.py is the
+Five things are held here, in the fast tier (tests/test_lbfgs.py is the
 heavy one): the ring gives the direction a plain chronological list of
 pairs gives, on every backend; the `[m, R, 128]` layout is invisible
-from outside (any `N`, lanes past it exactly zero); the program the
+from outside (any `N`, lanes past it exactly zero); the compact
+direction's `S Yᵀ` and `Y Yᵀ`, carried with the ring and updated a row
+and a column per push, are those of the ring as it lies; the program the
 client `vmap` lowers to touches the `[K, m, R, 128]` histories inside
 the L-BFGS loop with nothing but the direction's contractions and the
 one-row scatter, and makes no new history; and a client that is frozen
-keeps its ring bit for bit while the loop still ends as soon as every
-client of the block is done.
+keeps its ring and its Gram bit for bit while the loop still ends as
+soon as every client of the block is done.
 """
 
 import re
@@ -20,7 +22,10 @@ import pytest
 
 from federated_pytorch_test_tpu.ops import compact_direction_pallas
 from federated_pytorch_test_tpu.optim import LBFGSConfig, lbfgs_init, lbfgs_step
-from federated_pytorch_test_tpu.optim.compact import compact_direction
+from federated_pytorch_test_tpu.optim.compact import (
+    carried_direction,
+    compact_direction,
+)
 from federated_pytorch_test_tpu.optim.history import (
     empty_history,
     lane_rows,
@@ -91,7 +96,7 @@ def _drive(events, seed=0, n=N):
             continue
         s, y = _pair(rng, n)
         before = (np.asarray(s_hist), np.asarray(y_hist), int(count), int(oldest))
-        s_hist, y_hist, count, oldest = push(
+        s_hist, y_hist, count, oldest, _ = push(
             s_hist, y_hist, count, oldest, jnp.asarray(s), jnp.asarray(y),
             jnp.bool_(ev),
         )
@@ -137,6 +142,138 @@ def test_nan_in_invalid_row_cannot_reach_the_direction(backend):
     np.testing.assert_allclose(
         np.asarray(d), ref, rtol=1e-5, atol=1e-5 * np.abs(ref).max()
     )
+
+
+# ------------------------------------------------------- the carried Gram
+
+
+def _push_and_direct(s_hist, y_hist, count, oldest, sy, yy, s, y, push, g,
+                     h_diag):
+    """One iteration's history work as the solver does it: the push, then
+    the direction from the carried matrices, which take the pushed row."""
+    s_hist, y_hist, count, oldest, row = ring_push(
+        s_hist, y_hist, count, oldest, s, y, push
+    )
+    d, sy, yy = carried_direction(
+        g, s_hist, y_hist, count, h_diag, oldest, sy, yy, row, s, y
+    )
+    return s_hist, y_hist, count, oldest, sy, yy, d
+
+
+def _grams(s_hist, y_hist):
+    """`S Yᵀ` and `Y Yᵀ` of `[..., m, R, 128]` rings from scratch, float64."""
+    s = np.asarray(s_hist, np.float64)
+    y = np.asarray(y_hist, np.float64)
+    s, y = s.reshape(*s.shape[:-2], -1), y.reshape(*y.shape[:-2], -1)
+    return s @ np.swapaxes(y, -1, -2), y @ np.swapaxes(y, -1, -2)
+
+
+def _close(got, want, rtol=1e-5):
+    want = np.asarray(want)
+    np.testing.assert_allclose(
+        np.asarray(got), want, rtol=rtol, atol=rtol * np.abs(want).max()
+    )
+
+
+@pytest.mark.parametrize("scenario", sorted(SCENARIOS))
+def test_carried_gram_follows_the_ring(scenario):
+    # pushes, rejected pairs, wraps past m and a reset: after every event
+    # the carried matrices are the rings' own, all m x m entries (stale
+    # rows too: a reset keeps rows and matrices alike), a pair not pushed
+    # leaves them bit for bit, and the direction they give is the one
+    # `compact_direction` gives from scratch and the two-loop recursion's
+    rng = np.random.default_rng(7)
+    s_hist, y_hist = empty_history(M, N), empty_history(M, N)
+    count = oldest = jnp.int32(0)
+    sy = yy = jnp.zeros((M, M), jnp.float32)
+    h_diag = jnp.float32(0.37)
+    step = jax.jit(_push_and_direct)
+    for ev in SCENARIOS[scenario] + [False]:
+        if ev == "reset":
+            count = oldest = jnp.int32(0)
+            continue
+        s, y = _pair(rng)
+        g = jnp.asarray(rng.normal(size=N), jnp.float32)
+        s_hist, y_hist, count, oldest, sy2, yy2, d = step(
+            s_hist, y_hist, count, oldest, sy, yy, jnp.asarray(s),
+            jnp.asarray(y), jnp.bool_(ev), g, h_diag,
+        )
+        if not ev:
+            np.testing.assert_array_equal(np.asarray(sy2), np.asarray(sy))
+            np.testing.assert_array_equal(np.asarray(yy2), np.asarray(yy))
+        sy, yy = sy2, yy2
+        want_sy, want_yy = _grams(s_hist, y_hist)
+        _close(sy, want_sy)
+        _close(yy, want_yy)
+        args = (g, s_hist, y_hist, count, h_diag, oldest)
+        _close(d, compact_direction(*args))
+        _close(d, _two_loop_direction(*args))
+
+
+def test_carried_gram_under_the_client_vmap():
+    # three clients' rings filled by the vmapped push; then client 1's
+    # pair is not pushed: its matrices come back bit for bit, its
+    # siblings' take the new row and column
+    k = 3
+    rng = np.random.default_rng(8)
+    step = jax.jit(jax.vmap(_push_and_direct))
+    s_hist = jnp.stack([empty_history(M, N)] * k)
+    y_hist = jnp.stack([empty_history(M, N)] * k)
+    count = oldest = jnp.zeros((k,), jnp.int32)
+    sy = yy = jnp.zeros((k, M, M), jnp.float32)
+    h_diag = jnp.full((k,), 0.5, jnp.float32)
+    for push in [[True] * k] * (M + 1) + [[True, False, True]]:
+        pairs = [_pair(rng) for _ in range(k)]
+        s = jnp.asarray(np.stack([p[0] for p in pairs]))
+        y = jnp.asarray(np.stack([p[1] for p in pairs]))
+        g = jnp.asarray(rng.normal(size=(k, N)), jnp.float32)
+        before = (np.asarray(sy), np.asarray(yy))
+        s_hist, y_hist, count, oldest, sy, yy, d = step(
+            s_hist, y_hist, count, oldest, sy, yy, s, y, jnp.asarray(push),
+            g, h_diag,
+        )
+    np.testing.assert_array_equal(np.asarray(sy[1]), before[0][1])
+    np.testing.assert_array_equal(np.asarray(yy[1]), before[1][1])
+    for c in (0, 2):
+        assert not np.array_equal(np.asarray(sy[c]), before[0][c])
+    want_sy, want_yy = _grams(s_hist, y_hist)
+    _close(sy, want_sy)
+    _close(yy, want_yy)
+    _close(d, jax.vmap(compact_direction)(g, s_hist, y_hist, count, h_diag,
+                                          oldest))
+
+
+@pytest.mark.parametrize("backend", ["compact", "two_loop"])
+def test_step_counts_its_pushes_and_carries_the_gram(backend):
+    # `hist_pushes` counts the pairs the ring took: with room for every
+    # pair (no wrap) it is the ring's count after every step, and a batch
+    # step pushes at most max_iter - 1 (its first iteration updates the
+    # batch statistics instead). The carried Gram is the rings' own; the
+    # other backends carry none
+    rng = np.random.RandomState(4)
+    mm = rng.randn(12, 12)
+    a = jnp.asarray(mm @ mm.T + 12 * np.eye(12), jnp.float32)
+    b = jnp.asarray(rng.randn(12), jnp.float32)
+    cfg = LBFGSConfig(
+        max_iter=4, history_size=20, line_search=True, batch_mode=True,
+        direction=backend,
+    )
+    x = jnp.zeros((12,), jnp.float32)
+    state = lbfgs_init(x, cfg)
+    assert (state.hist_sy is None) == (backend != "compact")
+    step = jax.jit(lambda xx, ss: lbfgs_step(
+        lambda v: 0.5 * v @ (a @ v) - b @ v, xx, ss, cfg))
+    pushes = [0]
+    for _ in range(5):
+        x, state, _ = step(x, state)
+        pushes.append(int(state.hist_pushes))
+        assert pushes[-1] == int(state.hist_count)
+        assert 0 <= pushes[-1] - pushes[-2] <= cfg.max_iter - 1
+    assert pushes[-1] > 0
+    if backend == "compact":
+        want_sy, want_yy = _grams(state.s_hist, state.y_hist)
+        _close(state.hist_sy, want_sy)
+        _close(state.hist_yy, want_yy)
 
 
 # --------------------------------------------------------------- the lanes
@@ -376,11 +513,13 @@ def _equations(jaxpr):
 def test_only_row_writes_and_row_masks_make_a_history():
     # The layout must never be relaid: in the jaxpr of the vmapped step
     # (direction `compact`) the equations with a history-sized output are
-    # the two row scatters and `compact_direction`'s two validity selects
-    # (they fuse into their readers; the broadcasts are their constant
-    # operands) and nothing else — no reshape, transpose, pad,
-    # concatenate, slice or copy of a [K, m, R, 128] array. (The loop, its
-    # branches and calls hand the buffers on; they are descended into.)
+    # the two row scatters, the direction's two validity selects and the
+    # terms `w_i s_i - γ u_i y_i` of its assembly (they fuse into the one
+    # reduction over the rows that reads them, tests/test_tpu_compile.py;
+    # the broadcasts are their constant operands) and nothing else — no
+    # reshape, transpose, pad, concatenate, slice or copy of a
+    # [K, m, R, 128] array. (The loop, its branches and calls hand the
+    # buffers on; they are descended into.)
     k, m, n = 3, 4, 1030
     cfg = LBFGSConfig(
         max_iter=4, history_size=m, line_search=True, batch_mode=True
@@ -401,7 +540,7 @@ def test_only_row_writes_and_row_masks_make_a_history():
         and any(getattr(v.aval, "shape", None) == shape for v in eqn.outvars)
     ]
     assert sorted(p for p in made if p != "broadcast_in_dim") == [
-        "scatter", "scatter", "select_n", "select_n",
+        "mul", "mul", "scatter", "scatter", "select_n", "select_n", "sub",
     ], made
 
 
@@ -442,7 +581,8 @@ def test_frozen_clients_keep_their_ring_bitwise():
     poison = jnp.asarray([False, True, False])
     x2, state2, aux = step(x_in, centre, scale, poison, state1)
     for frozen in (1, 2):
-        for field in ("s_hist", "y_hist", "hist_count", "hist_oldest"):
+        for field in ("s_hist", "y_hist", "hist_count", "hist_oldest",
+                      "hist_sy", "hist_yy", "hist_pushes"):
             np.testing.assert_array_equal(
                 np.asarray(getattr(state2, field)[frozen]),
                 np.asarray(getattr(state1, field)[frozen]),
@@ -456,6 +596,10 @@ def test_frozen_clients_keep_their_ring_bitwise():
     assert not np.array_equal(
         np.asarray(state2.s_hist[0]), np.asarray(state1.s_hist[0])
     )
+    assert int(state2.hist_pushes[0]) > int(state1.hist_pushes[0])
+    want_sy, want_yy = _grams(state2.s_hist, state2.y_hist)
+    _close(state2.hist_sy, want_sy)
+    _close(state2.hist_yy, want_yy)
     assert np.isfinite(np.asarray(state2.s_hist)).all()
 
 

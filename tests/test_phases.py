@@ -97,10 +97,13 @@ def test_solver_work_once_per_round_equal_fused_and_unfused(admm_runs):
     steps = cfg.nadmm * cfg.nepoch * (240 // cfg.n_clients // cfg.batch)
     for r in work:
         v = r["value"]
-        assert set(v) == {"n_iter", "func_evals", "ls_evals", "grad_evals"}
+        assert set(v) == {
+            "n_iter", "func_evals", "ls_evals", "grad_evals", "hist_pushes"
+        }
         for k in range(cfg.n_clients):
             assert len(v["n_iter"]) == cfg.n_clients
             assert v["grad_evals"][k] >= v["func_evals"][k]
+            assert 0 < v["hist_pushes"][k] <= v["n_iter"][k]  # a pair an iteration
             assert v["func_evals"][k] >= v["n_iter"][k] >= steps
             assert v["n_iter"][k] <= steps * cfg.lbfgs_max_iter
             assert v["ls_evals"][k] >= v["n_iter"][k]  # a probe an iteration

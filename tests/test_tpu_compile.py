@@ -112,13 +112,16 @@ def _fused_root(text, line):
     return root.group(1)
 
 
-@pytest.mark.parametrize("direction", ["compact", "two_loop"])
-def test_compiled_step_never_relays_a_history(one_chip, direction):
-    # the vmapped step at K=6, m=10, N=4,720,640, as the benchmark's cell
-    # runs it in group 8's round: the only instructions that produce a
-    # [6, 10, 36880, 128] array are the two row writes, each a fusion
-    # whose root updates a slice of its own operand, in place
-    k, n = 6, RESNET18_LARGEST_GROUP
+_STEPS = {}
+
+
+def _compiled_step(sharding, direction, k=6, n=RESNET18_LARGEST_GROUP):
+    """The vmapped `lbfgs_step` at K=6, m=10, N=4,720,640, as the
+    benchmark's cell runs it in group 8's round, compiled once a
+    direction for the described chip."""
+    key = (sharding, direction, k, n)
+    if key in _STEPS:
+        return _STEPS[key]
     cfg = LBFGSConfig(
         max_iter=4, history_size=M, line_search=True, batch_mode=True,
         direction=direction,
@@ -128,7 +131,7 @@ def test_compiled_step_never_relays_a_history(one_chip, direction):
         return lbfgs_step(lambda xx: jnp.sum(a * (xx - 1.0) ** 2), x, state, cfg)
 
     def on_chip(s):
-        return jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one_chip)
+        return jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=sharding)
 
     x = on_chip(jax.ShapeDtypeStruct((k, n), jnp.float32))
     state = jax.tree.map(
@@ -139,11 +142,22 @@ def test_compiled_step_never_relays_a_history(one_chip, direction):
     )
     assert state.s_hist.shape == (k, M, 36880, 128)  # no padding at all
     with jax.default_matmul_precision("highest"):
-        compiled = (
+        _STEPS[key] = (
             jax.jit(jax.vmap(one), donate_argnums=(2,))
             .lower(x, x, state)
             .compile()
         )
+    return _STEPS[key]
+
+
+@pytest.mark.parametrize("direction", ["compact", "two_loop"])
+def test_compiled_step_never_relays_a_history(one_chip, direction):
+    # the vmapped step at K=6, m=10, N=4,720,640: the only instructions
+    # that produce a [6, 10, 36880, 128] array are the two row writes,
+    # each a fusion whose root updates a slice of its own operand, in
+    # place
+    k, n = 6, RESNET18_LARGEST_GROUP
+    compiled = _compiled_step(one_chip, direction)
     text = compiled.as_text()
     made = _top_level(text, f"f32[{k},{M},36880,128]")
     assert [op for _, op, _ in made] == ["fusion", "fusion"], [
@@ -155,6 +169,43 @@ def test_compiled_step_never_relays_a_history(one_chip, direction):
     # smaller than ONE history (it is the loop's [6, N] vectors)
     one_history = k * M * n * 4
     assert compiled.memory_analysis().temp_size_in_bytes < one_history
+
+
+def _history_reads(text, shape):
+    """{fusion: operands of type `shape`} of the fusions outside fused
+    computations that take one, the row writes (whose root updates a
+    slice of their operand in place) left out."""
+    params = {
+        m.group(1): m.group(2)
+        for m in re.finditer(r"^%?([\w.\-]+) \((.*)\) -> .*\{\s*$", text, re.M)
+    }
+    return {
+        name: params[callee].count(shape)
+        for comp, instructions in _computations(text)[0].items()
+        if "fused_computation" not in comp
+        for name, _, op, line in instructions
+        if op == "fusion"
+        for callee in re.findall(r"calls=%?([\w.\-]+)", line)
+        if shape in params[callee]
+        and _fused_root(text, line) != "dynamic-update-slice"
+    }
+
+
+def test_compact_step_reads_each_history_twice(one_chip):
+    # The compact direction carries `S Yᵀ` and `Y Yᵀ` with the ring
+    # (optim/compact.py): an iteration reads both histories in pass A
+    # (`Sᵀg`, `Yᵀg` and the pushed pair's row of both matrices, one
+    # multiply-reduce) and in pass B (the assembly, one fusion). So the
+    # fusions that take a [6, 10, 36880, 128] operand, the row writes
+    # left out, take it four times in all. With the Gram built from
+    # scratch every iteration and `(YᵀY)u` as `Y·(uᵀY)` they took it six
+    # times: the Gram S and Y, `Sᵀg` S, `u·Y` Y, `Yᵀg` with `Y·(uY)` Y,
+    # `w·S` S (PERF.md §6)
+    reads = _history_reads(
+        _compiled_step(one_chip, "compact").as_text(), f"f32[6,{M},36880,128]"
+    )
+    assert sum(reads.values()) == 4, reads
+    assert sorted(reads.values()) == [2, 2], reads
 
 
 @pytest.mark.parametrize("n", [RESNET18_LARGEST_GROUP, 1_000_003])
@@ -182,6 +233,30 @@ def test_pallas_direction_compiles_at_real_widths(one_chip, n, monkeypatch):
     # the kernels read the buffers as they are: nothing history-sized is
     # made on the way in
     assert not _top_level(text, f"f32[{k},{M},{lane_rows(n)},128]")
+
+
+def test_standalone_compact_direction_writes_no_product(one_chip):
+    # `compact_direction` makes `S Yᵀ`, `Y Yᵀ`, `Sᵀg`, `Yᵀg` from scratch
+    # in multiply-reduces the compiler fuses: at m = 10, N = 4,720,640 its
+    # scratch stays far under one [10, 36880, 128] history (a reduce of
+    # 220 products is not fused, and wrote 3.27 GB of them out)
+    from federated_pytorch_test_tpu.optim.compact import compact_direction
+
+    n = RESNET18_LARGEST_GROUP
+
+    def on_chip(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    hist = on_chip((M, lane_rows(n), 128))
+    i32 = on_chip((), jnp.int32)
+    with jax.default_matmul_precision("highest"):
+        compiled = (
+            jax.jit(compact_direction)
+            .lower(on_chip((n,)), hist, hist, i32, on_chip(()), i32)
+            .compile()
+        )
+    one_history = M * lane_rows(n) * 128 * 4
+    assert compiled.memory_analysis().temp_size_in_bytes < one_history / 10
 
 
 # ------------------------------------------------ the flagship's client step
