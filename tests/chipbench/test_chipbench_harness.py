@@ -6,6 +6,8 @@ pass 'cpu'; the command itself refuses anything but a TPU.
 """
 
 import collections
+import contextlib
+import fcntl
 import io
 import json
 import math
@@ -57,11 +59,60 @@ TINY_REFERENCE_FEDAVG = dict(TINY_REFERENCE, limits={
     if k not in ("final_loss_gap", "final_loss_gap_median", "final_primal_gap")})
 
 
+# the key under which a configuration or traffic file states its own cut
+# for the CPU: a configuration's `fields` (written over its own) and
+# `reference` (name and limits, in place of its own); a traffic file's
+# `overrides` (written over its own) and `data` (in place of its own).
+# `chipbench/run.py` reads neither file's block.
+TINY_KEY = "tiny"
+
+
+def _cut_config(cfg: dict, default: bool) -> dict:
+    """`cfg` at the CPU's size, by its own block; without one, by the
+    small CNN's cut where `default`, else as written. The counts filed
+    under `expect` are the full size's: a cut drops them."""
+    own = cfg.get(TINY_KEY) or (
+        {"fields": TINY_FIELDS, "reference": TINY_REFERENCE} if default else None)
+    if own is None:
+        return cfg
+    cfg["fields"].update(own.get("fields", {}))
+    cfg.pop("expect", None)
+    if "reference" in own:
+        cfg["reference"] = own["reference"]
+    return cfg
+
+
+def _cut_traffic(traffic: dict, default: bool) -> dict:
+    """`traffic` at the CPU's size, by its own block; without one, by
+    the default cut where `default`, else as written. A file with no
+    `overrides` of its own gets the cut's."""
+    own = traffic.get(TINY_KEY) or (
+        {"overrides": TINY_OVERRIDES, "data": TINY_DATA} if default else None)
+    if own is None:
+        return traffic
+    if "overrides" in own:
+        traffic["overrides"] = {**traffic.get("overrides", {}), **own["overrides"]}
+    if "data" in own:
+        traffic["data"] = own["data"]
+    return traffic
+
+
+def _rewrite(path: str, cut, default: bool) -> None:
+    with open(path) as f:
+        content = json.load(f)
+    with open(path, "w") as f:
+        json.dump(cut(content, default), f)
+
+
 def tiny_tree(tmp: str, extra=None) -> str:
-    """A benchmark tree under `tmp`: every file of the real one, each
-    configuration and traffic mix cut to the tiny size. `extra(bench,
-    root)` may ADD files and entries; nothing that exists is edited
-    after this copy is made. Returns the path of its BENCHMARK.json."""
+    """A benchmark tree under `tmp`: every file of the real one, and
+    whatever `extra(bench, root)` ADDS, files and entries (it edits
+    nothing that exists). Then each configuration and traffic mix is cut
+    to the CPU's size: by the block it states itself (`TINY_KEY`),
+    wherever it came from; a file of the real tree that states none by
+    the default cut (the small CNN, `TINY_*`); one that `extra` added and
+    that states none stays as written. Returns the path of its
+    BENCHMARK.json."""
     root = os.path.join(tmp, "tree")
     for sub in ("chipbench", os.path.join("tests", "chipbench", "reference")):
         shutil.copytree(
@@ -69,29 +120,37 @@ def tiny_tree(tmp: str, extra=None) -> str:
             ignore=shutil.ignore_patterns("__pycache__"),
         )
     bench = json.loads(json.dumps(BENCH))
-    for c in bench["configs"]:
-        path = os.path.join(root, c["file"])
-        with open(path) as f:
-            cfg = json.load(f)
-        cfg["fields"].update(TINY_FIELDS)
-        cfg.pop("expect", None)
-        cfg["reference"] = dict(TINY_REFERENCE)
-        with open(path, "w") as f:
-            json.dump(cfg, f)
-    for t in {w["traffic"] for w in bench["workloads"]}:
-        path = os.path.join(root, "chipbench", "traffic", t + ".json")
-        with open(path) as f:
-            traffic = json.load(f)
-        traffic["overrides"].update(TINY_OVERRIDES)
-        traffic["data"] = dict(TINY_DATA)
-        with open(path, "w") as f:
-            json.dump(traffic, f)
+    real_configs = {c["name"] for c in bench["configs"]}
+    real_traffic = {w["traffic"] for w in bench["workloads"]}
     if extra is not None:
         extra(bench, root)
+    for c in bench["configs"]:
+        _rewrite(os.path.join(root, c["file"]), _cut_config, c["name"] in real_configs)
+    for t in {w["traffic"] for w in bench["workloads"]}:
+        _rewrite(os.path.join(root, "chipbench", "traffic", t + ".json"), _cut_traffic,
+                 t in real_traffic)
     out = os.path.join(root, "BENCHMARK.json")
     with open(out, "w") as f:
         json.dump(bench, f)
     return out
+
+
+@contextlib.contextmanager
+def _one_traced_run_at_a_time(trace: int):
+    """A traced run writes its trace under `chiprun_out/chipbench/<cell>/`
+    of the repository and clears what it finds there: test processes that
+    trace the same cell at once would delete each other's trace."""
+    if not trace:
+        yield
+        return
+    lock = os.path.join(REPO, "chiprun_out", "chipbench", ".traced_run.lock")
+    os.makedirs(os.path.dirname(lock), exist_ok=True)
+    with open(lock, "a") as f:
+        fcntl.flock(f, fcntl.LOCK_EX)
+        try:
+            yield
+        finally:
+            fcntl.flock(f, fcntl.LOCK_UN)
 
 
 def run_cell(benchmark: str, workload: str, trace: int = 0, seed: int = 2**31 + 11,
@@ -101,7 +160,7 @@ def run_cell(benchmark: str, workload: str, trace: int = 0, seed: int = 2**31 + 
     from chipbench.run import main
 
     buf = io.StringIO()
-    with redirect_stdout(buf):
+    with _one_traced_run_at_a_time(trace), redirect_stdout(buf):
         rc = main(
             ["--workload", workload, "--seed", str(seed), "--seconds", "0.5",
              "--trace", str(trace), "--benchmark", benchmark],
@@ -145,16 +204,26 @@ def test_cell_result_line(tiny_results, name):
     assert not any(l.startswith("{") for l in lines[:-1])
 
 
+# per-layer metrics read from the trace's device planes (or, for
+# `peak_hbm_MiB`, the device's memory statistics), whatever their `source`
+# says: a CPU run has none to read
+DEVICE_SIDE = {"idle_unspanned_pct", "peak_hbm_MiB"}
+
+
 def test_traced_run_reports_layer_metrics(tiny):
     result, _ = run_cell(tiny, CELLS[-1], trace=1)
     assert set(result) == RESULT_KEYS | {"breakdown"}
     assert result["correct"] is True
-    declared = {m["name"] for m in BENCH["per_layer"]}
+    # the metrics declared for the cell run, under its own cut
+    declared = {m["name"]: m["source"] for m in BENCH["per_layer"]
+                if CELLS[-1] in m.get("workloads", [CELLS[-1]])}
     # trace-sourced readers find no device plane on the CPU and return
     # nothing; the harness leaves those metrics out of the line
-    host_side = {"programs_compiled", "host_gap_pct", "dispatches_per_round",
-                 "round_wall_max_ms", "fused_round_ms"}
-    assert host_side <= set(result["metrics"]) <= declared
+    host_side = {name for name, source in declared.items()
+                 if source in ("program_counter", "program_span")} - DEVICE_SIDE
+    assert {"programs_compiled", "host_gap_pct", "dispatches_per_round",
+            "round_wall_max_ms", "fused_round_ms", "history_pushes_per_step"} <= host_side
+    assert host_side <= set(result["metrics"]) <= set(declared)
     assert result["metrics"]["dispatches_per_round"]["value"] == 2.0
     assert {"busy_s", "window_s"} <= set(result["device"])
     assert set(result["breakdown"]) == {"device_ops", "idle_gaps"}
@@ -346,6 +415,144 @@ def test_a_maker_need_not_make_images(tmp_path):
     b = spec.load_function(cell.data_file, "make")(8, 4, 2**31 + 3, **params)
     assert a["train"].shape == (8, 12) and a["train"].dtype == np.int32
     assert a["train"].max() < 32 and a["test"].tobytes() == b["test"].tobytes()
+
+
+# ------------------------------------- a configuration's own cut for the CPU
+
+
+OWN_LIMITS = ("step0_loss_gap", "final_loss_gap", "final_accuracy_gap")
+SAYS_NO = ("def check(probe):\n    return {'ok': False, 'numbers': "
+           "{'gap': {'value': 2.0, 'limit': 1.0}}}\n")
+
+
+def _repo_mtimes() -> dict:
+    out = {}
+    for dirpath, _, files in os.walk(os.path.join(REPO, "chipbench")):
+        for fn in files:
+            if not fn.endswith(".pyc"):
+                p = os.path.join(dirpath, fn)
+                out[p] = os.path.getmtime(p)
+    return out
+
+
+def _add_own_cut_cell(reference: str):
+    """A configuration and a traffic mix as a later PR adds them: sizes
+    no CPU run holds, and under `TINY_KEY` each its own cut: the small CNN
+    on four clients at batch 8, data from a maker of its own, and a plain
+    reference of its own (`reference`) with three of the limits."""
+
+    def extra(bench: dict, root: str) -> None:
+        cb = os.path.join(root, "chipbench")
+        limits = {k: TINY_REFERENCE["limits"][k] for k in OWN_LIMITS}
+        with open(os.path.join(cb, "configs", "own-cut.json"), "w") as f:
+            json.dump({
+                "fields": {"model": "resnet18", "n_clients": 6, "batch": 32},
+                "expect": {"n_params": 11173962, "n_groups": 10},
+                "reference": {"name": "resnet18", "limits": {"step0_loss_gap": 0.02}},
+                TINY_KEY: {"fields": {"model": "net", "n_clients": 4, "batch": 8},
+                           "reference": {"name": reference, "limits": limits}},
+            }, f)
+        with open(os.path.join(cb, "traffic", "own-mix.json"), "w") as f:
+            json.dump({
+                "preset": "admm_resnet",
+                "overrides": {"nadmm": 3, "max_groups": 5, "eval_batch": 500},
+                "data": {"source": "synthetic_cifar", "steps_per_epoch": 520,
+                         "n_test": 10000},
+                TINY_KEY: {"overrides": {"nadmm": 2, "max_groups": 2, "eval_batch": 16},
+                           "data": {"source": "own_images", "steps_per_epoch": 2,
+                                    "n_test": 64, "synthetic": {"brightness": 120}}},
+            }, f)
+        with open(os.path.join(cb, "data", "own_images.py"), "w") as f:
+            f.write(EXTRA_MAKER)
+        shutil.copy(os.path.join(REPO, "tests", "chipbench", "reference", "net.py"),
+                    os.path.join(cb, "reference", "own_net.py"))
+        with open(os.path.join(cb, "reference", "says_no.py"), "w") as f:
+            f.write(SAYS_NO)
+        bench["configs"].append({
+            "name": "own-cut", "source": "test", "why": "test",
+            "file": "chipbench/configs/own-cut.json", "reduced": []})
+        bench["workloads"].append({
+            "name": "own-cut", "config": "own-cut", "traffic": "own-mix",
+            "chips": 1, "why": "test"})
+
+    return extra
+
+
+@pytest.mark.parametrize("reference,correct", [("own_net", True), ("says_no", False)])
+def test_a_configuration_states_its_own_cut(tmp_path, reference, correct):
+    """A configuration and a traffic file planted through the hook, each
+    with its own block for the CPU, are cut to what they state (not to the
+    small CNN's default), and the run goes through the cell's own maker
+    and its own reference, whose verdict is `correct`. No file of the
+    repository is touched."""
+    before = _repo_mtimes()
+    benchmark = tiny_tree(str(tmp_path), extra=_add_own_cut_cell(reference))
+    cell = spec.load_cell("own-cut", benchmark)
+    assert cell.config["fields"] == {"model": "net", "n_clients": 4, "batch": 8}
+    assert "expect" not in cell.config and TINY_KEY in cell.config  # run.py ignores it
+    assert cell.config["reference"]["name"] == reference
+    assert cell.traffic["overrides"] == {"nadmm": 2, "max_groups": 2, "eval_batch": 16}
+    assert cell.traffic["data"]["source"] == "own_images"
+    assert cell.data_file.endswith(os.path.join("data", "own_images.py"))
+    assert cell.reference_file.endswith(os.path.join("reference", reference + ".py"))
+
+    result, lines = run_cell(benchmark, "own-cut")
+    assert result["correct"] is correct, [l for l in lines if "checks=" in l]
+    assert any("data made by own_images: train=64 test=64" in l for l in lines)
+    assert any("K=4 batch=8 steps/epoch=2" in l for l in lines)
+    if correct:
+        assert set(result["compared"]) == set(OWN_LIMITS)
+        assert all(0 <= v <= limit for v, limit in result["compared"].values())
+    else:
+        assert result["compared"] == {"gap": [2.0, 1.0]}
+        assert "'reference': False" in [l for l in lines if "checks=" in l][-1]
+    assert _repo_mtimes() == before
+
+
+def _bare_mix(root: str, own_cut: bool) -> None:
+    """A traffic file with no `overrides` key, with or without a cut of
+    its own."""
+    mix = {"preset": "admm_resnet",
+           "data": {"source": "synthetic_cifar", "steps_per_epoch": 520, "n_test": 10000}}
+    if own_cut:
+        mix[TINY_KEY] = {"overrides": {"nadmm": 2, "max_groups": 1},
+                         "data": dict(TINY_DATA, n_test=30)}
+    with open(os.path.join(root, "chipbench", "traffic", "bare-mix.json"), "w") as f:
+        json.dump(mix, f)
+
+
+@pytest.mark.parametrize("own_cut", [True, False])
+def test_traffic_without_overrides_is_cut(tmp_path, monkeypatch, own_cut):
+    """A traffic file with no `overrides` of its own: planted through the
+    hook with its own cut, it is cut by that; in the real tree with none,
+    it gets the default cut. Neither raises."""
+    if own_cut:
+        def extra(bench, root):
+            _bare_mix(root, own_cut=True)
+            bench["workloads"].append({
+                "name": "bare-cell", "config": bench["configs"][0]["name"],
+                "traffic": "bare-mix", "chips": 1, "why": "test"})
+
+        benchmark = tiny_tree(str(tmp_path), extra=extra)
+        want = ({"nadmm": 2, "max_groups": 1}, dict(TINY_DATA, n_test=30))
+    else:
+        # a copy of the repository whose own tree holds the file
+        fake = str(tmp_path / "repo")
+        for sub in ("chipbench", os.path.join("tests", "chipbench", "reference")):
+            shutil.copytree(os.path.join(REPO, sub), os.path.join(fake, sub),
+                            ignore=shutil.ignore_patterns("__pycache__"))
+        _bare_mix(fake, own_cut=False)
+        bench = json.loads(json.dumps(BENCH))
+        bench["workloads"].append({
+            "name": "bare-cell", "config": bench["configs"][0]["name"],
+            "traffic": "bare-mix", "chips": 1, "why": "test"})
+        monkeypatch.setitem(globals(), "REPO", fake)
+        monkeypatch.setitem(globals(), "BENCH", bench)
+        benchmark = tiny_tree(str(tmp_path / "cut"))
+        want = (TINY_OVERRIDES, TINY_DATA)
+    cell = spec.load_cell("bare-cell", benchmark)
+    assert (cell.traffic["overrides"], cell.traffic["data"]) == want
+    assert cell.config["fields"]["model"] == "net"  # the real configuration's default cut
 
 
 @pytest.mark.parametrize("reference", [None, {}, {"name": "no_such_file"}])

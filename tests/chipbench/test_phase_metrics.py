@@ -316,10 +316,15 @@ def test_new_metrics_are_declared_for_the_cell():
     for name in NEW:
         m = declared[name]
         assert m["better"] == "lower" and m["moves"] == "train_samples_per_s"
-        assert m["workloads"] == ["resnet18-admm-f32"] and m["layer"] in layers
+        # for every cell: the scopes sit in code every model shares, and a
+        # cell the join cannot read leaves the metrics out of its line
+        assert "workloads" not in m and m["layer"] in layers
         assert (m["unit"], m["source"]) == (
             ("%", "program_span") if name == "idle_unspanned_pct" else ("ms", "device_trace"))
-    assert list(declared)[-len(NEW):] == list(NEW)  # appended, nothing moved
+    # declared together and in order; entries appended after them may follow
+    order = list(declared)
+    at = order.index(NEW[0])
+    assert order[at:at + len(NEW)] == list(NEW)
 
 
 def test_traced_cpu_run_leaves_the_device_metrics_out(tmp_path):
